@@ -6,9 +6,10 @@ wins all twelve shapes.  Same protocol here:
 
   1. candidates ranked by the napkin-math model in core/scheduler.plan()
      (predicted max(compute, memory) time / occupancy, VMEM-gated);
-  2. each surviving candidate is executed in interpret mode on a reduced
-     shape and must be BIT-IDENTICAL to the blocked oracle at its own
-     block_k (kernels/ref.gemm_blocked) — any accumulator-carry bug is an
+  2. each surviving candidate is executed (compiled on a TPU, in the
+     Pallas interpreter elsewhere) on a reduced shape and must be
+     BIT-IDENTICAL to the blocked oracle at its own block_k
+     (kernels/ref.gemm_blocked) — any accumulator-carry bug is an
      instant reject;
   3. one (block_n, block_k) pair is deployed uniformly across shapes
      (the paper: "it is not tuned against any one comparison").
@@ -48,7 +49,7 @@ def sweep(shapes, *, block_m: int = 128, num_cores: int = 1,
     """
     import jax.numpy as jnp
     from repro.kernels import ref
-    from repro.kernels.panel_gemm import panel_gemm
+    from repro.kernels.panel_gemm import gate_interpret, panel_gemm
 
     rng = np.random.default_rng(0)
     out = []
@@ -74,7 +75,7 @@ def sweep(shapes, *, block_m: int = 128, num_cores: int = 1,
                 w = jnp.asarray(rng.standard_normal((k_r, n_r)),
                                 dtype=jnp.float32)
                 y = panel_gemm(x, w, block_m=block_m, block_n=bn, block_k=bk,
-                               interpret=True)
+                               interpret=gate_interpret())
                 exact = bitexact.bit_identical(
                     np.asarray(y), np.asarray(ref.gemm_blocked(x, w, bk)))
             out.append(SweepResult(bn, bk, t, scheduler.vmem_bytes(

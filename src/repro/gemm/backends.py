@@ -6,7 +6,7 @@ outside that module could add a backend or scope a choice to one engine.
 Here backends are first-class registry entries:
 
   * ``xla``       — one shape-agnostic dot (the Accelerate-dispatch
-                    analogue and the CPU-runtime default).  Ignores the
+                    analogue and the process default).  Ignores the
                     plan's blocking (``needs_blocks=False``), so execute()
                     skips the block padding for it.
   * ``pallas``    — the compiled panel kernel (TPU deployment path).
@@ -98,7 +98,11 @@ def list_backends() -> list[str]:
 
 
 # ----------------------------------------------------------- default choice
-_FALLBACK_DEFAULT = "xla"    # CPU smoke tests / dry-runs; TPU deploys pallas
+# The process default on every device, the TPU included: the panel kernel
+# runs only where a caller names it (Engine(backend="pallas"),
+# launch/serve --backend pallas).  Which one a TPU should default to has
+# not been measured.
+_FALLBACK_DEFAULT = "xla"
 
 
 def default_backend() -> str:

@@ -484,9 +484,10 @@ def _bitexact_gate(bm: int, bn: int, bk: int, *,
                    epilogue: EpilogueSpec | None = None,
                    reduced_k_blocks: int = 2, seed: int = 0,
                    split_k: int = 1) -> bool:
-    """core/autotune's reject protocol for one block triple: interpret-mode
-    kernel on a reduced shape with a real K-carry must be BIT-IDENTICAL to
-    the blocked oracle.  With an epilogue the oracle is the UNFUSED
+    """core/autotune's reject protocol for one block triple: the kernel
+    (compiled on a TPU, interpreted elsewhere — ``gate_interpret``) on a
+    reduced shape with a real K-carry must be BIT-IDENTICAL to the
+    blocked oracle.  With an epilogue the oracle is the UNFUSED
     sequence — plain kernel to an fp32 accumulator, then the same jnp
     epilogue ops (``apply_epilogue``) under jit — so the gate covers
     every ``EpilogueSpec``, glu included.  ``split_k > 1`` gates the
@@ -499,6 +500,7 @@ def _bitexact_gate(bm: int, bn: int, bk: int, *,
         return _gate_memo[key]
     from repro.kernels import ref
     rng = np.random.default_rng(seed)
+    interpret = _kernel.gate_interpret()
     glu = epilogue is not None and epilogue.glu is not None
     m_r, k_r = bm, reduced_k_blocks * bk * split_k
     n_r = 2 * bn if glu else bn
@@ -509,7 +511,7 @@ def _bitexact_gate(bm: int, bn: int, bk: int, *,
             return _kernel.panel_gemm_splitk(
                 x, w, bias, res, split_k=split_k, block_m=bm, block_n=bn,
                 block_k=bk, epilogue=spec, out_dtype=out_dtype,
-                interpret=True)
+                interpret=interpret)
 
         def oracle_acc():
             return ref.gemm_splitk(x, w, bk, split_k,
@@ -518,7 +520,7 @@ def _bitexact_gate(bm: int, bn: int, bk: int, *,
         def run(bias=None, res=None, spec=None, out_dtype=None):
             return _kernel.panel_gemm(
                 x, w, bias, res, block_m=bm, block_n=bn, block_k=bk,
-                epilogue=spec, out_dtype=out_dtype, interpret=True)
+                epilogue=spec, out_dtype=out_dtype, interpret=interpret)
 
         def oracle_acc():
             return ref.gemm_blocked(x, w, bk, out_dtype=jnp.float32)

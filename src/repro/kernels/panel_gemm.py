@@ -42,7 +42,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 
 # Deployed block pair (the (Nc, Kc) analogue), fixed by the offline sweep
 # in core/autotune.py under the bit-exactness gate (winner over the twelve
@@ -61,6 +60,14 @@ DECODE_BLOCK_M = 8
 
 # v5e VMEM budget the blocks must respect (bytes); checked by vmem_bytes().
 VMEM_BUDGET = 16 * 1024 * 1024
+
+
+def gate_interpret() -> bool:
+    """Whether a bit-exactness gate runs its kernel in the Pallas
+    interpreter.  On a TPU the gate compiles the kernel the plan will
+    dispatch, so it attests what runs; elsewhere nothing can compile a
+    Mosaic kernel and the interpreter stands in."""
+    return jax.default_backend() != "tpu"
 
 
 # ------------------------------------------------------------- epilogue
@@ -441,7 +448,7 @@ def panel_gemm(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n_out), out_dtype),
         scratch_shapes=scratch,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -542,7 +549,7 @@ def panel_gemm_splitk(
                                lambda s, i, j, kk: (s, i, j)),
         out_shape=jax.ShapeDtypeStruct((split_k, m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),

@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 
 DEFAULT_CHUNK = 128
 
@@ -104,7 +103,7 @@ def ssd(x, a, b, c, *, chunk: int = DEFAULT_CHUNK,
         out_specs=pl.BlockSpec((1, chunk, p), lambda i, ci: (i, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

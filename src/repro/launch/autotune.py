@@ -32,6 +32,7 @@ import time
 from repro import gemm as gemm_api
 from repro import obs
 from repro.core import autotune
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model_zoo import PAPER_GEMM_SHAPES, PAPER_M
 
 
@@ -93,6 +94,7 @@ def main(argv=None):
                          "timeline (autotune_sweep spans with per-round "
                          "autotune_measure children)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     tracer = None
     if args.trace_out:

@@ -30,12 +30,15 @@ import jax
 
 from repro import gemm as gemm_api
 from repro.configs.base import SHAPES, TrainConfig
-from repro.launch.mesh import HW, make_production_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.models import model_zoo, transformer
 from repro.parallel import sharding as Sh
 from repro.roofline import analysis as R
 from repro.roofline.hlo_cost import HloCostModel
 from repro.runtime import train_loop
+
+# the dry-run models a v5e deployment (compile-only, no chip attached)
+HW = R.V5E
 
 
 def _lower_cell(cfg, shape, mesh, *, packed: bool = True,
@@ -172,8 +175,6 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, *,
 
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):     # jax 0.4.x returns [dict]
-        ca = ca[0] if ca else {}
     hlo = compiled.as_text()
 
     # Loop-aware walker (XLA's cost_analysis counts scan bodies once —
@@ -210,7 +211,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, *,
         flops_per_device=cost.flops,
         bytes_per_device=cost.hbm_bytes,
         collective=coll, chips=chips,
-        model_fl=R.model_flops(cfg, shape),
+        model_fl=R.model_flops(cfg, shape), hw=HW,
         dtype=("bf16" if cfg.compute_dtype == "bfloat16" else "fp32"))
     # Pallas-kernel-adjusted memory term: replace the XLA-materialized
     # attention / SSD block traffic (tagged via named_scope) with the

@@ -13,36 +13,19 @@ jax initialization).
 from __future__ import annotations
 
 import jax
-
-from repro import compat
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    auto = compat.axis_type_auto()
-    return compat.make_mesh(
-        shape, axes,
-        axis_types=auto and (auto,) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1):
     """Local mesh over whatever devices exist (smoke tests, examples)."""
     n = jax.device_count()
     assert n % model == 0, (n, model)
-    auto = compat.axis_type_auto()
-    return compat.make_mesh(
-        (n // model, model), ("data", "model"),
-        axis_types=auto and (auto,) * 2)
-
-
-# TPU v5e hardware model used by the roofline (single source of truth).
-HW = {
-    "name": "tpu-v5e",
-    "peak_flops_bf16": 197e12,      # per chip
-    "peak_flops_fp32": 98.5e12,
-    "hbm_bw": 819e9,                # bytes/s per chip
-    "ici_bw": 50e9,                 # bytes/s per link
-    "hbm_bytes": 16 * 2**30,
-    "chips_per_pod": 256,
-}
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

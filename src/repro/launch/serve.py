@@ -11,6 +11,12 @@ prefill admission of ``--prefill-chunk`` rows) and reports per-request
 latency percentiles: queue wait, time-to-first-token, and per-request
 decode tokens/s — the stats fields docs/serving.md describes.
 
+By default the model runs at ``model_zoo.reduced_config`` widths (two
+layers, ``d_model`` 64: a CPU smoke size).  ``--full-width`` serves the
+configuration's published widths and depth instead, on weights built
+straight into the packed layout (``model_zoo.build_packed``) so that
+only the packed tree ever sits in device memory.
+
 Observability (docs/observability.md): ``--trace-out PATH`` records the
 whole run — pack, plan resolution, warmup, every scheduler tick, prefix
 cache and fault events, plus the GEMM flight recorder — as a
@@ -23,11 +29,13 @@ from __future__ import annotations
 import argparse
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro import gemm as gemm_api
 from repro import obs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import model_zoo
 from repro.runtime.serve_loop import Engine
@@ -41,6 +49,9 @@ def _pct(stats, field):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=model_zoo.list_archs())
+    ap.add_argument("--full-width", action="store_true",
+                    help="serve the configuration's published widths and "
+                         "depth (default: the reduced smoke config)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=128)
     ap.add_argument("--max-new", type=int, default=32)
@@ -48,7 +59,8 @@ def main():
     ap.add_argument("--backend", default=None,
                     choices=gemm_api.list_backends(),
                     help="GEMM backend for this engine's plans "
-                         "(default: process default, xla on CPU)")
+                         "(default: the process default, xla; the panel "
+                         "kernel runs with --backend pallas)")
     ap.add_argument("--no-fusion", action="store_true",
                     help="disable horizontal QKV/gate-up fusion and the "
                          "fused epilogues (A/B escape hatch; default: "
@@ -116,6 +128,14 @@ def main():
                     help="write a metrics snapshot at exit: JSON to PATH "
                          "and Prometheus text beside it (PATH + '.prom')")
     args = ap.parse_args()
+    if args.full_width and args.compare_percall:
+        ap.error("--compare-percall builds a second, unpacked copy of the "
+                 "weights; at --full-width that copy does not fit beside "
+                 "the packed one")
+    if args.full_width and args.quant:
+        ap.error("--quant measures every quantized pack against its raw "
+                 "fp32 weight, which a --full-width build never holds")
+    enable_compile_cache()
 
     # obs activation happens BEFORE engine construction so pack /
     # plan-resolve / warmup spans and the jitted steps' GEMM manifests
@@ -131,9 +151,17 @@ def main():
         reg.add_collector(obs.gemm_collector)
         obs.set_metrics(reg)
 
-    cfg = model_zoo.reduced_config(model_zoo.get_config(args.arch))
+    cfg = model_zoo.get_config(args.arch)
+    if not args.full_width:
+        cfg = model_zoo.reduced_config(cfg)
     mesh = make_host_mesh()
-    params = model_zoo.build(cfg)
+    t0 = time.perf_counter()
+    params = (model_zoo.build_packed(cfg, fuse=not args.no_fusion)
+              if args.full_width else model_zoo.build(cfg))
+    print(f"{cfg.name}: {cfg.num_layers} layers, d_model {cfg.d_model}, "
+          f"vocab {cfg.vocab_size}, {cfg.param_dtype} params "
+          f"({'published widths' if args.full_width else 'reduced'}); "
+          f"weights built in {time.perf_counter() - t0:.2f}s")
     rng = np.random.default_rng(0)
     prompts = jnp.asarray(rng.integers(
         0, cfg.vocab_size, (args.batch, args.prompt_len)), jnp.int32)
@@ -289,9 +317,12 @@ def main():
         if gs.requested:
             print("  graceful shutdown: drained in-flight requests, "
                   "cancelled the queue")
-        if sstats.degraded:
-            print("  degraded: " + ", ".join(
-                f"{k} x{v}" for k, v in sorted(sstats.degraded.items())))
+        # the degradation ladder re-dispatches a failed step on the xla
+        # backend: nonzero counters mean part of the run did not run on
+        # the requested backend
+        print(f"  degradation ladder: {sstats.backend_fallbacks} backend "
+              f"fallbacks, {sstats.dispatch_retries} dispatch retries, "
+              f"degraded {dict(sorted(sstats.degraded.items())) or 0}")
         if args.watchdog_factor:
             print(f"  watchdog (factor {args.watchdog_factor:g}): "
                   f"{len(sstats.stragglers)} straggler ticks"
@@ -305,6 +336,11 @@ def main():
                   f"reused, {px.cow_forks} COW forks, "
                   f"{px.evicted_pages} pages evicted, "
                   f"{px.cached_pages} pages cached at end")
+
+    mem = jax.devices()[0].memory_stats()
+    if mem and "peak_bytes_in_use" in mem:
+        print(f"device peak memory: {mem['peak_bytes_in_use'] / 2**30:.2f} "
+              f"GiB ({jax.devices()[0].device_kind})")
 
     if store is not None:
         store.save()

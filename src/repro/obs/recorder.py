@@ -240,15 +240,20 @@ def on_traced(p, m: int) -> None:
 
 def _roofline_frac(rec: dict, wall_s: float) -> float | None:
     """Fraction of the analytic roofline bound achieved by this
-    dispatch (lazy import keeps obs free of repro deps at module
+    dispatch, against the peaks of the device it ran on; None ("not
+    measured") on a device the peaks table does not list, the CPU
+    among them (lazy import keeps obs free of repro deps at module
     level)."""
     try:
-        from repro.roofline import gemm_roofline
+        from repro.roofline import device_peaks, gemm_roofline
+        hw = device_peaks()
+        if hw is None:
+            return None
         db = rec.get("density_bucket", -1)
         # sparse packs: score against the occupied fraction the layout
         # implies (the bucket's midpoint), not the dense shape's work
         wd = 1.0 if db < 0 else max(0.05, 1.0 - (db + 0.5) / 10.0)
-        t_bound = gemm_roofline(rec["m"], rec["n"], rec["k"],
+        t_bound = gemm_roofline(rec["m"], rec["n"], rec["k"], hw=hw,
                                 weight_format=rec["weight_format"],
                                 weight_density=wd)
         if t_bound and t_bound > 0:

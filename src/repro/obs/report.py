@@ -83,11 +83,16 @@ def synthesize_gemm_events(trace: dict) -> list[dict]:
 
 
 def _frac(rec: dict, wall_s: float) -> float | None:
+    """Roofline share against the running device's peaks; None ("not
+    measured") where the peaks table has no entry for it."""
     try:
-        from repro.roofline import gemm_roofline
+        from repro.roofline import device_peaks, gemm_roofline
+        hw = device_peaks()
+        if hw is None:
+            return None
         db = rec.get("density_bucket", -1)
         wd = 1.0 if db < 0 else max(0.05, 1.0 - (db + 0.5) / 10.0)
-        bound = gemm_roofline(rec["m"], rec["n"], rec["k"],
+        bound = gemm_roofline(rec["m"], rec["n"], rec["k"], hw=hw,
                               weight_format=rec.get("weight_format",
                                                     "fp32"),
                               weight_density=wd)

@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
-
 
 def _ring(axis: str, size: int, fwd: bool = True):
     if fwd:
@@ -66,7 +64,7 @@ def ag_matmul(x, w, *, mesh: Mesh, axis: str = "model"):
                                       length=s)
         return acc.astype(x_blk.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, axis), P(None, axis)),   # x: K-shard, w: N-shard
         out_specs=P(None, axis),
@@ -110,7 +108,7 @@ def matmul_rs(x, w, *, mesh: Mesh, axis: str = "model"):
                             preferred_element_type=jnp.float32)
         return acc.astype(x_blk.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, axis), P(axis, None)),
         out_specs=P(None, axis),

@@ -338,7 +338,10 @@ def unpack_ternary_codes(packed: jax.Array) -> jax.Array:
     ``[..., K, N]``.  The ONE unpack definition shared by the kernel tile
     path, the xla dequant run, and the oracle, so all three see
     elementwise-identical values (exact small integers)."""
-    parts = [((packed >> (2 * i)) & 3).astype(jnp.float32) - 1.0
+    # widen before the shifts: Mosaic has no uint8 -> float32 cast, and
+    # the crumbs are exact small integers in any integer width
+    wide = packed.astype(jnp.int32)
+    parts = [((wide >> (2 * i)) & 3).astype(jnp.float32) - 1.0
              for i in range(4)]
     stacked = jnp.stack(parts, axis=-2)          # [..., K//4, 4, N]
     return stacked.reshape(*packed.shape[:-2], packed.shape[-2] * 4,

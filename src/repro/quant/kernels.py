@@ -31,12 +31,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 from repro.kernels.panel_gemm import (DECODE_BLOCK_M, DEFAULT_BLOCK_K,
                                       DEFAULT_BLOCK_M, DEFAULT_BLOCK_N,
                                       EpilogueSpec, _act_fn, _finish,
                                       apply_epilogue, apply_epilogue_glu,
-                                      splitk_combine)
+                                      gate_interpret, splitk_combine)
 from repro.quant import formats as F
 
 
@@ -52,6 +51,16 @@ def _dequant_tile(w_vals, s_vals, fmt: str) -> jax.Array:
     else:
         codes = w_vals.astype(jnp.float32)
     return codes * F.expand_scales(s_vals, codes.shape[-2])
+
+
+def _scale_slabs(scales, n_tiles: int, rows: int, n: int) -> jax.Array:
+    """``[K_pad // GROUP_K, N]`` scales as ``[n_tiles, rows, N]``: one
+    slab of ``rows`` scale rows per K tile.  A BlockSpec of
+    ``(None, rows, block_n)`` over it spans the whole second-to-last
+    dim, which Mosaic's (8, 128) block tiling accepts for any ``rows``;
+    a ``(rows, block_n)`` block of the 2-D array would not when
+    ``rows`` (``block_k // GROUP_K``) is not a multiple of 8."""
+    return scales.reshape(n_tiles, rows, n).astype(jnp.float32)
 
 
 def _quant_gemm_kernel(x_ref, w_ref, s_ref, *refs, nk: int, fmt: str,
@@ -177,23 +186,23 @@ def quant_panel_gemm(
     sbk = block_k // F.GROUP_K          # scale rows per K tile
     assert scales.shape[-2:] == (k // F.GROUP_K, n), (
         f"scales {scales.shape} vs expected ({k // F.GROUP_K},{n})")
-    s2 = scales.reshape(k // F.GROUP_K, n).astype(jnp.float32)
+    s3 = _scale_slabs(scales, nk, sbk, n)
     half_tiles = n_out // block_n
     x_spec = pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk))
     w_spec = pl.BlockSpec((wbk, block_n), lambda i, j, kk: (kk, j))
-    s_spec = pl.BlockSpec((sbk, block_n), lambda i, j, kk: (kk, j))
+    s_spec = pl.BlockSpec((None, sbk, block_n), lambda i, j, kk: (kk, 0, j))
     if glu:      # up panel + its scale slab: column-offset index maps
-        ops = [x, data, data, s2, s2]
+        ops = [x, data, data, s3, s3]
         in_specs = [
             x_spec, w_spec,
             pl.BlockSpec((wbk, block_n),
                          lambda i, j, kk: (kk, j + half_tiles)),
             s_spec,
-            pl.BlockSpec((sbk, block_n),
-                         lambda i, j, kk: (kk, j + half_tiles)),
+            pl.BlockSpec((None, sbk, block_n),
+                         lambda i, j, kk: (kk, 0, j + half_tiles)),
         ]
     else:
-        ops = [x, data, s2]
+        ops = [x, data, s3]
         in_specs = [x_spec, w_spec, s_spec]
     if spec is not None and spec.bias:
         b2 = bias.reshape(1, n).astype(jnp.float32)
@@ -227,7 +236,7 @@ def quant_panel_gemm(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n_out), out_dtype),
         scratch_shapes=scratch,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -414,7 +423,7 @@ def sparse_quant_panel_gemm(
         return z[:, :n_out].astype(out_dtype)
 
     gidx, occ_mat = _sparse_layout_arrays(sparse_layout)
-    s2 = scales.reshape(ns, n).astype(jnp.float32)
+    s3 = _scale_slabs(scales, ns, 1, n)
     half_tiles = n_out // block_n
     # the group-walk tables ride in as SCALAR-PREFETCH operands (index
     # maps may not capture array constants): every index map receives
@@ -424,20 +433,20 @@ def sparse_quant_panel_gemm(
                           lambda i, j, s, gidx, occ: (i, gidx[s]))
     w_spec = pl.BlockSpec((rpg, block_n),
                           lambda i, j, s, gidx, occ: (s, j))
-    s_spec = pl.BlockSpec((1, block_n),
-                          lambda i, j, s, gidx, occ: (s, j))
+    s_spec = pl.BlockSpec((None, 1, block_n),
+                          lambda i, j, s, gidx, occ: (s, 0, j))
     if glu:
-        ops = [x, data, data, s2, s2]
+        ops = [x, data, data, s3, s3]
         in_specs = [
             x_spec, w_spec,
             pl.BlockSpec((rpg, block_n),
                          lambda i, j, s, gidx, occ: (s, j + half_tiles)),
             s_spec,
-            pl.BlockSpec((1, block_n),
-                         lambda i, j, s, gidx, occ: (s, j + half_tiles)),
+            pl.BlockSpec((None, 1, block_n),
+                         lambda i, j, s, gidx, occ: (s, 0, j + half_tiles)),
         ]
     else:
-        ops = [x, data, s2]
+        ops = [x, data, s3]
         in_specs = [x_spec, w_spec, s_spec]
     if spec is not None and spec.bias:
         b2 = bias.reshape(1, n).astype(jnp.float32)
@@ -477,7 +486,7 @@ def sparse_quant_panel_gemm(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n_out), out_dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -590,7 +599,7 @@ def quant_panel_gemm_splitk(
                                           and spec.residual)
     assert scales.shape[-2:] == (k // F.GROUP_K, n), (
         f"scales {scales.shape} vs expected ({k // F.GROUP_K},{n})")
-    s2 = scales.reshape(k // F.GROUP_K, n).astype(jnp.float32)
+    s3 = _scale_slabs(scales, split_k * nks, sbk, n)
 
     partials = pl.pallas_call(
         functools.partial(_quant_splitk_kernel, nks=nks, fmt=fmt),
@@ -600,19 +609,19 @@ def quant_panel_gemm_splitk(
                          lambda s, i, j, kk: (i, s * nks + kk)),
             pl.BlockSpec((wbk, block_n),
                          lambda s, i, j, kk: (s * nks + kk, j)),
-            pl.BlockSpec((sbk, block_n),
-                         lambda s, i, j, kk: (s * nks + kk, j)),
+            pl.BlockSpec((None, sbk, block_n),
+                         lambda s, i, j, kk: (s * nks + kk, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, block_m, block_n),
                                lambda s, i, j, kk: (s, i, j)),
         out_shape=jax.ShapeDtypeStruct((split_k, m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
         interpret=interpret,
-    )(x, data, s2)
+    )(x, data, s3)
     acc = splitk_combine(partials)
     if spec is not None:
         acc = apply_epilogue(acc, spec, bias=bias, residual=residual)
@@ -628,10 +637,11 @@ def quant_gate(bm: int, bn: int, bk: int, fmt: str, *,
                reduced_k_blocks: int = 2, seed: int = 0,
                split_k: int = 1, sparse: bool = False) -> bool:
     """The autotune reject protocol for a quantized block triple: the
-    interpret-mode dequant-fused kernel on a reduced shape with a real
-    K-carry must be BIT-IDENTICAL to ``ref.gemm_blocked`` over the
-    dequantized panels (+ the jnp epilogue under jit).  ``split_k > 1``
-    gates the decode lane's split-K variant against ``ref.gemm_splitk``
+    dequant-fused kernel (compiled on a TPU, interpreted elsewhere —
+    ``gate_interpret``) on a reduced shape with a real K-carry must be
+    BIT-IDENTICAL to ``ref.gemm_blocked`` over the dequantized panels
+    (+ the jnp epilogue under jit).  ``split_k > 1`` gates the decode
+    lane's split-K variant against ``ref.gemm_splitk``
     over the same dequantized panels.  This attests the KERNEL (tiling,
     dequant placement, accumulation order); the format's numeric error
     vs fp32 is the error ledger's separate, tolerance-gated concern.
@@ -658,6 +668,7 @@ def quant_gate(bm: int, bn: int, bk: int, fmt: str, *,
     if key in _gate_memo:
         return _gate_memo[key]
     rng = np.random.default_rng(seed)
+    interpret = gate_interpret()
     glu = epilogue is not None and epilogue.glu is not None
     if sparse:
         kg_r = 8
@@ -681,11 +692,11 @@ def quant_gate(bm: int, bn: int, bk: int, fmt: str, *,
         y_s = sparse_quant_panel_gemm(
             x, sq.data, sq.scales, bias, res,
             sparse_layout=sq.sparse_layout, block_m=bm, block_n=bn,
-            epilogue=epilogue, interpret=True)
+            epilogue=epilogue, interpret=interpret)
         y_d = quant_panel_gemm(
             x, dq.data, dq.scales, bias, res, weight_format="ternary",
             block_m=bm, block_n=bn, block_k=F.GROUP_K,
-            epilogue=epilogue, interpret=True)
+            epilogue=epilogue, interpret=interpret)
         oracle = sparse_ref(x, sq, epilogue=epilogue, bias=bias,
                             residual=res)
         ok = (bitexact.bit_identical(np.asarray(y_s), np.asarray(y_d))
@@ -709,12 +720,12 @@ def quant_gate(bm: int, bn: int, bk: int, fmt: str, *,
         y = quant_panel_gemm_splitk(x, data, s, bias, res,
                                     weight_format=fmt, split_k=split_k,
                                     block_m=bm, block_n=bn, block_k=bk,
-                                    epilogue=epilogue, interpret=True)
+                                    epilogue=epilogue, interpret=interpret)
         acc = ref.gemm_splitk(x, deq, bk, split_k, out_dtype=jnp.float32)
     else:
         y = quant_panel_gemm(x, data, s, bias, res, weight_format=fmt,
                              block_m=bm, block_n=bn, block_k=bk,
-                             epilogue=epilogue, interpret=True)
+                             epilogue=epilogue, interpret=interpret)
         acc = ref.gemm_blocked(x, deq, bk, out_dtype=jnp.float32)
     if epilogue is None:
         oracle = acc

@@ -1,8 +1,9 @@
 """Roofline: 3-term analysis from compiled dry-run artifacts."""
 from repro.roofline.analysis import (
-    CollectiveOp, collective_seconds, gemm_roofline, model_flops,
-    parse_collectives, roofline_terms,
+    PEAKS, V5E, CollectiveOp, collective_seconds, device_peaks,
+    gemm_roofline, model_flops, parse_collectives, roofline_terms,
 )
 
-__all__ = ["CollectiveOp", "collective_seconds", "gemm_roofline",
-           "model_flops", "parse_collectives", "roofline_terms"]
+__all__ = ["PEAKS", "V5E", "CollectiveOp", "collective_seconds",
+           "device_peaks", "gemm_roofline", "model_flops",
+           "parse_collectives", "roofline_terms"]

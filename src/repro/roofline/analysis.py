@@ -32,7 +32,36 @@ from __future__ import annotations
 import dataclasses
 import re
 
-from repro.launch.mesh import HW
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e" (system architecture
+# table): 197 TFLOP/s bf16, 393 TOP/s int8, 16 GiB of HBM at 819 GB/s,
+# 1,600 Gbit/s of chip-to-chip interconnect (four ICI links, 50 GB/s
+# each).  No fp32 peak is published: the MXU multiplies bf16, so the
+# bf16 figure is the compute floor for every operand dtype, fp32 GEMMs
+# included.  A kind missing here has no roofline: callers report "not
+# measured" rather than borrow another device's peaks.
+PEAKS = {
+    "TPU v5 lite": {
+        "name": "TPU v5e",
+        "peak_flops_bf16": 197e12,
+        "peak_ops_int8": 393e12,
+        "hbm_bw": 819e9,                # bytes/s per chip
+        "hbm_bytes": 16 * 2**30,
+        "ici_bw": 50e9,                 # bytes/s per link
+        "source": "Google Cloud documentation, TPU v5e",
+    },
+}
+V5E = PEAKS["TPU v5 lite"]
+
+
+def device_peaks(device_kind: str | None = None) -> dict | None:
+    """Peaks of ``device_kind`` (default: the first device JAX sees);
+    None when the table has no entry for it — the CPU among them."""
+    if device_kind is None:
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    return PEAKS.get(device_kind)
+
 
 DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -155,14 +184,16 @@ def model_flops(cfg, shape) -> float:
 _FORMAT_BYTES = {"fp32": 4.0, "int8": 1.0, "ternary": 0.25}
 
 
-def gemm_roofline(m: int, n: int, k: int, *, weight_format: str = "fp32",
-                  act_bytes: int = 4, weight_density: float = 1.0,
-                  hw: dict = HW) -> float:
+def gemm_roofline(m: int, n: int, k: int, *, hw: dict,
+                  weight_format: str = "fp32", act_bytes: int = 4,
+                  weight_density: float = 1.0) -> float:
     """Analytic lower-bound seconds for ONE ``[m,k] @ [k,n]`` dispatch —
     the denominator of the flight recorder's ``roofline_frac``.
 
-    Two terms, take the max: compute (``2mnk`` over fp32 peak — the
-    GEMM accumulates in fp32 regardless of pack format) and memory (the
+    ``hw`` is one :data:`PEAKS` entry (:func:`device_peaks` of the
+    device that ran the dispatch).  Two terms, take the max: compute
+    (``2mnk`` over the bf16 peak — the MXU floor whatever the operand
+    dtype, so an fp32 GEMM's bound is optimistic) and memory (the
     operand/result traffic floor: activations + result at ``act_bytes``,
     weights at the pack format's bytes-per-element — the term decode's
     skinny-M dispatches live on, and why quantized decode beats fp32 at
@@ -181,16 +212,16 @@ def gemm_roofline(m: int, n: int, k: int, *, weight_format: str = "fp32",
     wb = _FORMAT_BYTES.get(weight_format, 4.0)
     bytes_moved = ((m * k + m * n) * act_bytes
                    + k * n * wb * weight_density)
-    t_compute = flops / hw["peak_flops_fp32"]
+    t_compute = flops / hw["peak_flops_bf16"]
     t_memory = bytes_moved / hw["hbm_bw"]
     return max(t_compute, t_memory)
 
 
 def roofline_terms(*, flops_per_device: float, bytes_per_device: float,
                    collective: dict, chips: int, model_fl: float,
-                   dtype: str = "bf16", hw: dict = HW) -> dict:
-    peak = (hw["peak_flops_bf16"] if dtype == "bf16"
-            else hw["peak_flops_fp32"])
+                   hw: dict, dtype: str = "bf16") -> dict:
+    # bf16 is the MXU's multiply width: fp32 takes the same floor
+    peak = hw["peak_flops_bf16"]
     t_c = flops_per_device / peak
     t_m = bytes_per_device / hw["hbm_bw"]
     t_x = collective["seconds"]

@@ -137,7 +137,20 @@ class Engine:
                              "packed=True")
 
         shard_fn = Sh.activation_sharder(mesh) if mesh is not None else None
-        if packed:
+        from repro.core.packing import PackedWeight
+        if packed and any(isinstance(leaf, PackedWeight) for leaf in
+                          jax.tree.leaves(params, is_leaf=lambda x:
+                                          isinstance(x, PackedWeight))):
+            # already packed at load (model_zoo.build_packed): place it
+            # as it is, donating the source so no second copy lingers
+            if quant is not None or block_n or block_k:
+                raise ValueError("params arrive packed: quant= and block "
+                                 "overrides are pack-time choices")
+            self.params = params
+            if mesh is not None:
+                self.params = jax.device_put(
+                    params, Sh.param_shardings(params, mesh), donate=True)
+        elif packed:
             # ---- model load: pack once (lever 2). Untimed by protocol.
             # The pack-time plan resolutions (pack_blocks per weight)
             # run under the engine's plan store, so a populated store

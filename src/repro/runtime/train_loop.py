@@ -23,7 +23,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.models import transformer
 from repro.optim import optimizers as O
 from repro.parallel import sharding as Sh
@@ -332,7 +331,7 @@ def _make_manual_dp_step(cfg, tc, mesh, opt, *, donate: bool = True,
 
     def step_fn(state: TrainState, batch: dict):
         params_c = _cast_for_compute(state.params, cfg.cdtype)
-        inner_sm = compat.shard_map(
+        inner_sm = jax.shard_map(
             inner, mesh=mesh,
             in_specs=(man_pspecs, batch_spec_for(batch)),
             out_specs=(man_pspecs, P(), P()),

@@ -95,10 +95,8 @@ def test_elastic_restore_with_shardings(tmp_path, tree):
     device, but the code path is the elastic one)."""
     d = str(tmp_path)
     save(d, 1, tree)
-    from repro import compat
-    auto = compat.axis_type_auto()
-    mesh = compat.make_mesh((1,), ("data",),
-                            axis_types=auto and (auto,))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     sh = jax.tree.map(
         lambda x: jax.NamedSharding(mesh, jax.sharding.PartitionSpec()),
         jax.eval_shape(lambda: tree))

@@ -21,11 +21,9 @@ def run_script(body: str):
     script = (
         "import jax, numpy as np, jax.numpy as jnp\n"
         "from jax.sharding import NamedSharding, PartitionSpec as P\n"
-        "from repro import compat\n"
         "from repro.parallel import collectives as C\n"
-        "auto = compat.axis_type_auto()\n"
-        "mesh = compat.make_mesh((2, 4), ('data', 'model'),\n"
-        "    axis_types=auto and (auto,) * 2)\n"
+        "mesh = jax.make_mesh((2, 4), ('data', 'model'),\n"
+        "    axis_types=(jax.sharding.AxisType.Auto,) * 2)\n"
         + body)
     r = subprocess.run([sys.executable, "-c", script], env=env,
                        capture_output=True, text=True, timeout=300)

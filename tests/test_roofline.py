@@ -138,8 +138,21 @@ def test_roofline_terms_dominance():
     t = R.roofline_terms(flops_per_device=197e12 * 0.1,   # 0.1 s compute
                          bytes_per_device=819e9 * 0.2,    # 0.2 s memory
                          collective=coll, chips=256,
-                         model_fl=1e15, dtype="bf16")
+                         model_fl=1e15, dtype="bf16", hw=R.V5E)
     assert t["dominant"] == "collective"
     assert t["bound_s"] == pytest.approx(0.5)
     assert t["compute_s"] == pytest.approx(0.1)
     assert t["memory_s"] == pytest.approx(0.2)
+
+
+def test_device_peaks_keyed_by_device_kind():
+    v5e = R.device_peaks("TPU v5 lite")
+    assert v5e is R.V5E
+    assert (v5e["peak_flops_bf16"], v5e["peak_ops_int8"], v5e["hbm_bw"]) \
+        == (197e12, 393e12, 819e9)
+    assert "peak_flops_fp32" not in v5e        # no published figure
+    # a kind the table does not list has no roofline, the CPU included
+    assert R.device_peaks("cpu") is None
+    import jax
+    if jax.devices()[0].platform == "cpu":
+        assert R.device_peaks() is None

@@ -265,10 +265,10 @@ def test_vmem_budget_sparse_monotone_and_group_pinned():
 
 
 def test_roofline_scales_with_density():
-    from repro.roofline import gemm_roofline
-    t1 = gemm_roofline(128, 4096, 4096, weight_format="ternary")
+    from repro.roofline import V5E, gemm_roofline
+    t1 = gemm_roofline(128, 4096, 4096, weight_format="ternary", hw=V5E)
     t3 = gemm_roofline(128, 4096, 4096, weight_format="ternary",
-                       weight_density=0.3)
+                       weight_density=0.3, hw=V5E)
     assert t3 < t1
 
 

@@ -1,0 +1,193 @@
+"""The serving path's Pallas kernels compile for a TPU v5e.
+
+Interpret mode cannot see what the chip's compiler (Mosaic) refuses:
+block shapes off the (8, 128) tiling, casts it does not lower, VMEM
+over budget.  These tests compile each kernel at stablelm-3b's published
+widths, at the blocks the dispatch policy resolves for them, against a
+described ``v5e:2x2`` topology — no chip needed — and check that the
+compiled program holds the kernel (``tpu_custom_call``).
+
+The topology is described inside a module fixture, never at import:
+only one process at a time may load the TPU compiler library, so a
+worker that imports this file must not touch it unless it runs these
+tests.  Keep every such compile in this one file.
+"""
+from __future__ import annotations
+
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import gemm
+from repro.models import model_zoo
+from repro.quant import formats as F
+from repro.quant import kernels as QK
+
+ARCH = "stablelm-3b"
+PREFILL_M = 128          # one prefill chunk's rows
+DECODE_M = 4             # a 4-slot decode tick
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler library here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip can be written to the persistent
+    # cache but never read back; keep these compiles out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _packs(quant=None):
+    """stablelm-3b's packed tree, as shapes (nothing allocated)."""
+    cfg = model_zoo.get_config(ARCH)
+    return cfg, jax.eval_shape(
+        lambda p: model_zoo.pack_for_inference(cfg, p, quant=quant),
+        model_zoo.abstract_params(cfg))
+
+
+def _on_chip(tree, sharding):
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree)
+
+
+def _layer_pack(pw, sharding):
+    """One layer's 2-D slice of a stacked [L, K, N] pack (what the scan
+    body hands the kernel)."""
+    kw = {"data": jax.ShapeDtypeStruct(pw.data.shape[-2:], pw.data.dtype,
+                                       sharding=sharding)}
+    if getattr(pw, "scales", None) is not None:
+        kw["scales"] = jax.ShapeDtypeStruct(
+            pw.scales.shape[-2:], pw.scales.dtype, sharding=sharding)
+    return dataclasses.replace(pw, **kw)
+
+
+def _compile_execute(p, pw, sharding, *, residual=False):
+    """Compile ``gemm.execute`` of plan ``p`` — the serving dispatch —
+    for the described chip; returns the compiled program text."""
+    cdt = model_zoo.get_config(ARCH).cdtype
+    args = [jax.ShapeDtypeStruct((p.m, p.k), cdt, sharding=sharding), pw]
+    if residual:
+        args.append(jax.ShapeDtypeStruct((p.m, p.n_out), cdt,
+                                         sharding=sharding))
+
+    def f(x, w, r=None):
+        return gemm.execute(p, x, w, residual=r)
+
+    return jax.jit(f).lower(*args).compile().as_text()
+
+
+# (weight, rows, epilogue) — every GEMM a stablelm-3b serving step runs
+_GLU = gemm.EpilogueSpec(glu="silu")
+_RES = gemm.EpilogueSpec(residual=True)
+SERVING_GEMMS = {
+    "wqkv": ("attn/wqkv", PREFILL_M, None),
+    "wo_residual": ("attn/wo", PREFILL_M, _RES),
+    "glu_gate_up": ("ffn/w_gate_up", PREFILL_M, _GLU),
+    "w_down_residual": ("ffn/w_down", PREFILL_M, _RES),
+    "lm_head": ("lm_head", 1, None),       # the chunk's one logit row
+    "decode_wqkv": ("attn/wqkv", DECODE_M, None),
+    "decode_glu_gate_up": ("ffn/w_gate_up", DECODE_M, _GLU),
+}
+
+
+def _pack_at(packed, where, sharding):
+    if where == "lm_head":
+        return _on_chip(packed["lm_head"], sharding)
+    group, name = where.split("/")
+    return _layer_pack(packed["layers"][group][name], sharding)
+
+
+@pytest.mark.parametrize("case", sorted(SERVING_GEMMS))
+def test_fp32_serving_gemm_compiles(one_chip, case):
+    where, m, spec = SERVING_GEMMS[case]
+    _, packed = _packs()
+    pw = _pack_at(packed, where, one_chip)
+    p = gemm.plan_for_packed(m, pw, backend="pallas", epilogue=spec,
+                             decode=m == DECODE_M)
+    text = _compile_execute(p, pw, one_chip,
+                            residual=spec is not None and spec.residual)
+    assert "tpu_custom_call" in text, p.describe()
+
+
+def test_splitk_decode_plan_compiles(one_chip):
+    """The decode lane's split-K kernel at the wo pack's blocks (the
+    policy picks split_k=1 at these widths, so the split is explicit:
+    K = 2560 cuts into five whole 512-deep slices)."""
+    _, packed = _packs()
+    pw = _layer_pack(packed["layers"]["attn"]["wo"], one_chip)
+    p = gemm.plan(8, pw.n, pw.k, backend="pallas", decode=True,
+                  block_n=pw.block_n, block_k=pw.block_k,
+                  pack="prepacked", split_k=5)
+    assert p.split_k == 5 and p.decode
+    assert "tpu_custom_call" in _compile_execute(p, pw, one_chip)
+
+
+@pytest.mark.parametrize("fmt", ["int8", "ternary"])
+@pytest.mark.parametrize("case", ["wqkv", "glu_gate_up", "decode_wqkv"])
+def test_quant_serving_gemm_compiles(one_chip, fmt, case):
+    where, m, spec = SERVING_GEMMS[case]
+    _, packed = _packs(quant=fmt)
+    pw = _pack_at(packed, where, one_chip)
+    assert isinstance(pw, F.QuantizedPackedWeight) and pw.fmt == fmt
+    p = gemm.plan_for_packed(m, pw, backend="pallas", epilogue=spec,
+                             decode=m == DECODE_M)
+    assert "tpu_custom_call" in _compile_execute(p, pw, one_chip)
+
+
+@pytest.mark.parametrize("fmt", ["int8", "ternary"])
+def test_quant_splitk_compiles(one_chip, fmt):
+    _, packed = _packs(quant=fmt)
+    pw = _layer_pack(packed["layers"]["attn"]["wo"], one_chip)
+    split = pw.k_pad // pw.block_k
+    p = gemm.plan(8, pw.n, pw.k, backend="pallas", decode=True,
+                  block_n=pw.block_n, block_k=pw.block_k, pack="prepacked",
+                  weight_format=fmt, split_k=split)
+    assert p.split_k == split > 1
+    assert "tpu_custom_call" in _compile_execute(p, pw, one_chip)
+
+
+def test_sparse_ternary_gemm_compiles(one_chip):
+    """The sparse-ternary group walk at the fused QKV's widths, half of
+    its K groups occupied (a density-bucket-5 pack)."""
+    cfg = model_zoo.get_config(ARCH)
+    k, n = cfg.d_model, 3 * cfg.num_heads * cfg.head_dim
+    p = gemm.plan(PREFILL_M, n, k, backend="pallas",
+                  weight_format="ternary", density_bucket=5)
+    kg = k // F.GROUP_K
+    occupied = tuple(range(0, kg, 2))
+    n_pad = -(-n // p.block_n) * p.block_n
+    layout = (kg, occupied,
+              (sum(1 << g for g in occupied),) * (n_pad // p.block_n),
+              p.block_n)
+
+    def f(x, codes, scales):
+        return QK.sparse_quant_panel_gemm(
+            x, codes, scales, sparse_layout=layout, block_m=p.block_m,
+            block_n=p.block_n)
+
+    text = jax.jit(f).lower(
+        jax.ShapeDtypeStruct((PREFILL_M, k), cfg.cdtype, sharding=one_chip),
+        jax.ShapeDtypeStruct((len(occupied) * F.GROUP_K // 4, n_pad),
+                             jnp.uint8, sharding=one_chip),
+        jax.ShapeDtypeStruct((len(occupied), n_pad), jnp.float32,
+                             sharding=one_chip),
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
