@@ -211,8 +211,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, *,
         flops_per_device=cost.flops,
         bytes_per_device=cost.hbm_bytes,
         collective=coll, chips=chips,
-        model_fl=R.model_flops(cfg, shape), hw=HW,
-        dtype=("bf16" if cfg.compute_dtype == "bfloat16" else "fp32"))
+        model_fl=R.model_flops(cfg, shape), hw=HW)
     # Pallas-kernel-adjusted memory term: replace the XLA-materialized
     # attention / SSD block traffic (tagged via named_scope) with the
     # kernels' analytic HBM traffic (kernels/flash_attention.py,

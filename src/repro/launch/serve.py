@@ -155,8 +155,19 @@ def main():
     if not args.full_width:
         cfg = model_zoo.reduced_config(cfg)
     mesh = make_host_mesh()
+    # the store is loaded first: a --full-width build packs at load and
+    # resolves its pack blocks under it
+    store = (gemm_api.PlanStore.load(args.plan_store)
+             if args.plan_store else None)
+    if store is not None:
+        info = store.info()
+        print(f"plan store {args.plan_store}: {info.entries} entries "
+              f"loaded ({info.autotuned} measured-autotuned)"
+              + (f"  [invalidated: {store.invalidated}]"
+                 if store.invalidated else ""))
     t0 = time.perf_counter()
-    params = (model_zoo.build_packed(cfg, fuse=not args.no_fusion)
+    params = (model_zoo.build_packed(cfg, fuse=not args.no_fusion,
+                                     plan_store=store)
               if args.full_width else model_zoo.build(cfg))
     print(f"{cfg.name}: {cfg.num_layers} layers, d_model {cfg.d_model}, "
           f"vocab {cfg.vocab_size}, {cfg.param_dtype} params "
@@ -169,14 +180,6 @@ def main():
         prompts = jnp.asarray(rng.standard_normal(
             (args.batch, args.prompt_len, cfg.d_model)), cfg.cdtype)
 
-    store = (gemm_api.PlanStore.load(args.plan_store)
-             if args.plan_store else None)
-    if store is not None:
-        info = store.info()
-        print(f"plan store {args.plan_store}: {info.entries} entries "
-              f"loaded ({info.autotuned} measured-autotuned)"
-              + (f"  [invalidated: {store.invalidated}]"
-                 if store.invalidated else ""))
     t0 = time.perf_counter()
     eng = Engine(cfg, params, mesh=mesh, max_len=args.max_len, packed=True,
                  backend=args.backend, fuse=not args.no_fusion,

@@ -145,7 +145,8 @@ def build(cfg: ModelConfig, seed: int = 0):
     return transformer.init_params(cfg, jax.random.key(seed))
 
 
-def build_packed(cfg: ModelConfig, seed: int = 0, *, fuse: bool = True):
+def build_packed(cfg: ModelConfig, seed: int = 0, *, fuse: bool = True,
+                 plan_store=None):
     """``pack_for_inference(cfg, build(cfg, seed), fuse=fuse)`` compiled
     as one program, built so that only the packed tree ever sits in
     device memory.
@@ -155,18 +156,24 @@ def build_packed(cfg: ModelConfig, seed: int = 0, *, fuse: bool = True):
     therefore exists only as a temporary of the program that packs it.
     Largest leaves go first, while the device is emptiest.  At
     stablelm-3b's widths the fp32 tree is 10.4 GiB, and raw plus packed
-    would not fit a 16 GiB chip."""
+    would not fit a 16 GiB chip.
+
+    ``plan_store`` (a ``gemm.PlanStore``) scopes the pack-time plan
+    resolutions as ``Engine(plan_store=)`` does for its own pack, so a
+    populated store hands back its pack blocks."""
     def packed_tree():
         return pack_for_inference(
             cfg, transformer.init_params(cfg, jax.random.key(seed)),
             fuse=fuse)
 
-    abstract, treedef = jax.tree.flatten(jax.eval_shape(packed_tree))
-    leaves = [None] * len(abstract)
-    for i in sorted(range(len(abstract)),
-                    key=lambda i: -abstract[i].size
-                    * abstract[i].dtype.itemsize):
-        leaves[i] = jax.jit(lambda i=i: jax.tree.leaves(packed_tree())[i])()
+    with gemm_api.use_plan_store(plan_store):
+        abstract, treedef = jax.tree.flatten(jax.eval_shape(packed_tree))
+        leaves = [None] * len(abstract)
+        for i in sorted(range(len(abstract)),
+                        key=lambda i: -abstract[i].size
+                        * abstract[i].dtype.itemsize):
+            leaves[i] = jax.jit(
+                lambda i=i: jax.tree.leaves(packed_tree())[i])()
     return jax.tree.unflatten(treedef, leaves)
 
 

@@ -219,7 +219,7 @@ def gemm_roofline(m: int, n: int, k: int, *, hw: dict,
 
 def roofline_terms(*, flops_per_device: float, bytes_per_device: float,
                    collective: dict, chips: int, model_fl: float,
-                   hw: dict, dtype: str = "bf16") -> dict:
+                   hw: dict) -> dict:
     # bf16 is the MXU's multiply width: fp32 takes the same floor
     peak = hw["peak_flops_bf16"]
     t_c = flops_per_device / peak
@@ -244,5 +244,4 @@ def roofline_terms(*, flops_per_device: float, bytes_per_device: float,
         "mfu_upper_bound": (model_fl / (chips * peak)) / bound
         if bound > 0 else 0.0,
         "chips": chips,
-        "dtype": dtype,
     }

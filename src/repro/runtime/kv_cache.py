@@ -195,6 +195,10 @@ class PagedKVCache:
         """Pages registered by a prefix cache (live or idle)."""
         return len(self._cached)
 
+    def is_cached(self, p: int) -> bool:
+        """True if page ``p`` is registered by a prefix cache."""
+        return int(p) in self._cached
+
     def reclaimable_count(self, exclude=()) -> int:
         """Cached pages with refcount 0 — what the evictor could return
         to the free list under pressure.  ``exclude`` discounts pages a
@@ -344,13 +348,17 @@ class PagedKVCache:
         self.uncache(list(self._cached))
 
     # -------------------------------------------------- device shipping
+    # Both ship a host COPY: on the CPU backend ``jnp.asarray`` may alias
+    # a numpy buffer without copying, and the scheduler mutates
+    # ``page_table``/``lens`` in place right after an asynchronous
+    # dispatch, so an aliased step could read the next tick's values.
     def table_device(self, slots=None) -> jnp.ndarray:
         t = self.page_table if slots is None else self.page_table[slots]
-        return jnp.asarray(t)
+        return jnp.asarray(np.array(t, copy=True))
 
     def lens_device(self, slots=None) -> jnp.ndarray:
         l = self.lens if slots is None else self.lens[slots]
-        return jnp.asarray(l)
+        return jnp.asarray(np.array(l, copy=True))
 
     # ---------------------------------------------------- invariants
     def check_no_aliasing(self) -> None:

@@ -225,6 +225,11 @@ class PrefixCache:
                         raise ValueError(
                             f"insert: slot {slot} has no page for prompt "
                             f"run {j} — prompt not fully prefilled?")
+                    if self.pool.is_cached(page):
+                        # the page already indexes another run, so it
+                        # does not hold this prompt's KV: index nothing
+                        # from here on (one page, one trie node)
+                        break
                     child = _Node(run=run, page=page, parent=node)
                     node.children[run] = child
                     self.pool.mark_cached([page])

@@ -88,6 +88,23 @@ def test_build_packed_matches_pack_of_build(fuse):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_build_packed_resolves_pack_blocks_under_its_plan_store(tmp_path):
+    from repro import gemm
+    cfg = model_zoo.reduced_config(model_zoo.get_config("stablelm-3b"))
+    store = gemm.PlanStore(tmp_path / "plans.json")
+    gemm.plan_cache_clear()
+    try:
+        model_zoo.build_packed(cfg, plan_store=store)
+        cold = store.info()
+        assert cold.misses > 0 and cold.entries == cold.misses
+        gemm.plan_cache_clear()
+        model_zoo.build_packed(cfg, plan_store=store)
+        warm = store.info()
+        assert warm.hits == cold.misses and warm.misses == cold.misses
+    finally:
+        gemm.plan_cache_clear()
+
+
 def test_engine_serves_prepacked_params():
     cfg = model_zoo.reduced_config(model_zoo.get_config("stablelm-3b"))
     prompts = jnp.asarray(

@@ -163,6 +163,32 @@ def test_free_returns_pages_and_resets():
     pool.check_no_aliasing()
 
 
+def _aligned(a):
+    """``a`` copied into a 64-byte-aligned buffer: JAX's CPU client
+    aliases such a host array instead of copying it."""
+    buf = np.empty(a.nbytes + 64, np.uint8)
+    off = -buf.ctypes.data % 64
+    out = buf[off:off + a.nbytes].view(a.dtype).reshape(a.shape)
+    out[...] = a
+    return out
+
+
+def test_shipped_table_and_lens_do_not_alias_host_state():
+    """The scheduler changes ``page_table``/``lens`` in place right
+    after an asynchronous dispatch; what a step was handed must not
+    change with them."""
+    pool = _pool()
+    pool.page_table, pool.lens = _aligned(pool.page_table), _aligned(pool.lens)
+    pool.alloc(0, 4)
+    pool.lens[0] = 4
+    want_table, want_lens = pool.page_table.copy(), pool.lens.copy()
+    table, lens = pool.table_device(), pool.lens_device()
+    pool.lens[0] += 1
+    pool.page_table[0, 0] = KV.PAGE_FREE
+    np.testing.assert_array_equal(np.asarray(table), want_table)
+    np.testing.assert_array_equal(np.asarray(lens), want_lens)
+
+
 def test_aliasing_detected():
     pool = _pool()
     pool.alloc(0, 4)

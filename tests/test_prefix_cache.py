@@ -110,6 +110,22 @@ def test_insert_is_idempotent_and_keeps_first_page():
     assert cache.stats.inserted_pages == 2
 
 
+def test_insert_never_indexes_a_page_twice():
+    """A slot whose pages already index one prompt cannot index them
+    again under another prompt's runs: the pages hold the first
+    prompt's KV, and one page in two trie nodes would break the
+    reclaimable count."""
+    pool = _pool()
+    cache = PrefixCache(pool)
+    assert _complete(pool, cache, 0, _prompt(1, 2, 3, 4, 5, 6, 7, 8)) == 2
+    assert _complete(pool, cache, 0, _prompt(9, 9, 9, 9, 8, 8, 8, 8)) == 0
+    pages = [n.page for n in cache._walk()]
+    assert len(pages) == len(set(pages)) == 2
+    pool.free(0)
+    assert pool.reclaimable_count() == 2
+    assert cache.lookup(_prompt(9, 9, 9, 9, 8, 8, 8, 8, 7)).tokens == 0
+
+
 def test_partial_final_page_never_indexed():
     pool = _pool()
     cache = PrefixCache(pool)

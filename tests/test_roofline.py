@@ -138,7 +138,7 @@ def test_roofline_terms_dominance():
     t = R.roofline_terms(flops_per_device=197e12 * 0.1,   # 0.1 s compute
                          bytes_per_device=819e9 * 0.2,    # 0.2 s memory
                          collective=coll, chips=256,
-                         model_fl=1e15, dtype="bf16", hw=R.V5E)
+                         model_fl=1e15, hw=R.V5E)
     assert t["dominant"] == "collective"
     assert t["bound_s"] == pytest.approx(0.5)
     assert t["compute_s"] == pytest.approx(0.1)
