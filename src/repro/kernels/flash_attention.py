@@ -121,6 +121,7 @@ def flash_attention(
             _flash_kernel, nkv=nkv, block_q=block_q, block_kv=block_kv,
             kv_len=skv, q_offset=skv - sq, causal=causal, window=window,
             softcap=softcap, scale=scale),
+        name="flash_attention",
         grid=(bh, sq_p // block_q, nkv),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),

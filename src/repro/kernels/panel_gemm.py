@@ -443,6 +443,7 @@ def panel_gemm(
 
     return pl.pallas_call(
         kernel,
+        name="panel_gemm_glu" if glu else "panel_gemm",
         grid=(m // block_m, n_out // block_n, nk),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
@@ -538,6 +539,7 @@ def panel_gemm_splitk(
 
     partials = pl.pallas_call(
         functools.partial(_splitk_kernel, nks=nks),
+        name="panel_gemm_splitk",
         grid=(split_k, m // block_m, n // block_n, nks),
         in_specs=[
             pl.BlockSpec((block_m, block_k),

@@ -93,6 +93,7 @@ def ssd(x, a, b, c, *, chunk: int = DEFAULT_CHUNK,
 
     return pl.pallas_call(
         functools.partial(_ssd_kernel, nc=nc),
+        name="ssd",
         grid=(bh, nc),
         in_specs=[
             pl.BlockSpec((1, chunk, p), lambda i, ci: (i, ci, 0)),

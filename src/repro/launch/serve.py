@@ -23,6 +23,11 @@ cache and fault events, plus the GEMM flight recorder — as a
 Chrome-trace JSON loadable at ui.perfetto.dev and summarizable with
 ``repro.launch.trace_report``; ``--metrics-out PATH`` writes the unified
 metrics registry's snapshot (JSON, plus Prometheus text at PATH.prom).
+``--profile-dir DIR`` instead runs the JAX profiler over the whole run
+with the span tracer's profiler sink: the scheduler's spans land on the
+profiler's host plane, on the device trace's clock, and the profile
+(``DIR/plugins/profile/<time>/*.xplane.pb``) loads in TensorBoard or
+Perfetto, or reads with ``jax.profiler.ProfileData``.
 """
 from __future__ import annotations
 
@@ -39,6 +44,15 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import model_zoo
 from repro.runtime.serve_loop import Engine
+
+
+def _profile_options():
+    """Device ops and the program's spans; no Python function tracing,
+    which on a busy serving loop writes millions of events."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    return opts
 
 
 def _pct(stats, field):
@@ -127,7 +141,15 @@ def main():
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="write a metrics snapshot at exit: JSON to PATH "
                          "and Prometheus text beside it (PATH + '.prom')")
+    ap.add_argument("--profile-dir", default=None, metavar="DIR",
+                    help="run the JAX profiler over the run, with the "
+                         "program's spans on the device trace's clock "
+                         "(span tracer's profiler sink); writes the "
+                         "profile under DIR")
     args = ap.parse_args()
+    if args.profile_dir and args.trace_out:
+        ap.error("--profile-dir and --trace-out are two sinks of one "
+                 "span tracer; choose one")
     if args.full_width and args.compare_percall:
         ap.error("--compare-percall builds a second, unpacked copy of the "
                  "weights; at --full-width that copy does not fit beside "
@@ -150,6 +172,11 @@ def main():
         reg = obs.MetricsRegistry()
         reg.add_collector(obs.gemm_collector)
         obs.set_metrics(reg)
+    if args.profile_dir:
+        tracer = obs.Tracer(sink="profiler")
+        obs.set_tracer(tracer)
+        jax.profiler.start_trace(args.profile_dir,
+                                 profiler_options=_profile_options())
 
     cfg = model_zoo.get_config(args.arch)
     if not args.full_width:
@@ -350,7 +377,12 @@ def main():
         print(f"plan store saved -> {store.path} "
               f"({store.info().entries} entries)")
 
-    if tracer is not None:
+    if args.profile_dir:
+        jax.profiler.stop_trace()
+        obs.set_tracer(None)
+        print(f"profile written under {args.profile_dir} (device ops and "
+              f"the program's spans on one clock)")
+    elif tracer is not None:
         obs.set_tracer(None)
         obs.set_recorder(None)
         tracer.export_chrome_trace(args.trace_out, recorder=rec)

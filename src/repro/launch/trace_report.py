@@ -7,11 +7,13 @@ dispatch count, lever mix, split-K settings, median achieved GFLOPS and
 median fraction-of-roofline — the §4 table shape, produced from live
 serving traffic instead of a dedicated benchmark run.
 
-``apportioned`` counts samples whose duration is share-attributed from
-a tick span via the step's GEMM manifest rather than directly measured
-(the jitted serving path — see docs/observability.md); rows where it
-equals ``dispatches`` carry no wall-clock measurement of their own and
-their GFLOPS column derives entirely from the apportionment.
+``apportioned`` counts dispatches placed under a tick span by the
+step's GEMM manifest, with no timing of their own (the jitted serving
+path — see docs/observability.md).  The GFLOPS and roofline medians
+read measured records only, the fenced eager dispatches
+(``--trace-fence``), and print "—" for a shape that has none; a jitted
+GEMM's device time is in a profiler trace (``launch/serve
+--profile-dir``).
 
 Also prints a span census (event counts and total self time by span
 name) with ``--spans``, and writes the table as JSON with ``--json``.
@@ -63,8 +65,8 @@ def main(argv=None):
           f"records, {len(mani)} step manifests "
           f"({sum(len(v) for v in mani.values())} manifest plans)")
     print()
-    print("per-shape GEMM characterization "
-          "(medians; apportioned = share-attributed, not measured):")
+    print("per-shape GEMM characterization (medians of fenced eager "
+          "records; apportioned = jitted, not timed):")
     print(_report.format_table(rows))
 
     if args.spans:

@@ -54,17 +54,21 @@ def blocked_attention(
     g = h // hkv
     chunk = min(chunk, t)
     pad = (-t) % chunk
-    if pad:
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        if k_pos is not None:
-            k_pos = jnp.pad(k_pos, ((0, 0), (0, pad)), constant_values=-1)
     tp = t + pad
     nc = tp // chunk
-    if k_pos is None:
-        kv_len = t if kv_len is None else kv_len
-        k_pos = jnp.broadcast_to(jnp.arange(tp)[None], (b, tp))
-        k_pos = jnp.where(k_pos < kv_len, k_pos, -1)
+    # the keys' view padded to whole blocks, and its position mask: the
+    # KV path's ``kv_view`` scope, not attention's
+    with jax.named_scope("kv_view"):
+        if pad:
+            k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
+            v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+            if k_pos is not None:
+                k_pos = jnp.pad(k_pos, ((0, 0), (0, pad)),
+                                constant_values=-1)
+        if k_pos is None:
+            kv_len = t if kv_len is None else kv_len
+            k_pos = jnp.broadcast_to(jnp.arange(tp)[None], (b, tp))
+            k_pos = jnp.where(k_pos < kv_len, k_pos, -1)
 
     q_off = jnp.asarray(q_offset, jnp.int32)
     if q_off.ndim == 0:
@@ -191,8 +195,9 @@ def _update_paged_cache(cache, k, v, page_size):
     k_d = KV.paged_gather(pk, pt, page_size)
     v_d = KV.paged_gather(pv, pt, page_size)
     t_view = k_d.shape[1]
-    k_pos = jnp.arange(t_view, dtype=jnp.int32)[None, :]
-    k_pos = jnp.where(k_pos < lens[:, None] + s, k_pos, -1)
+    with jax.named_scope("kv_view"):
+        k_pos = jnp.arange(t_view, dtype=jnp.int32)[None, :]
+        k_pos = jnp.where(k_pos < lens[:, None] + s, k_pos, -1)
     return {"pages_k": pk, "pages_v": pv}, k_d, v_d, k_pos, None, lens
 
 

@@ -267,12 +267,17 @@ def forward(cfg, params, inputs, *, cache=None, mode: str = "train",
     # alternative stacks a fresh copy of the whole cache every layer —
     # XLA materializes the ys buffer per iteration (+2 × cache bytes of
     # HBM traffic per layer, the dominant decode term; §Perf C3).
+    # The layer's slice and its write-back carry the KV path's device
+    # scopes (``kv_view`` / ``kv_write``), so a profile attributes their
+    # ops to the cache by ``op_name``.
     def body(carry, xs):
         x, aux, cl = carry
         lp, w_l, li = xs
-        c_l = (None if cl is None else
-               jax.tree.map(lambda buf: jax.lax.dynamic_index_in_dim(
-                   buf, li, 0, keepdims=False), cl))
+        c_l = None
+        if cl is not None:
+            with jax.named_scope("kv_view"):
+                c_l = jax.tree.map(lambda buf: jax.lax.dynamic_index_in_dim(
+                    buf, li, 0, keepdims=False), cl)
         if c_l is not None and paged_extra is not None:
             c_l = {**c_l, **paged_extra}
         x, new_c, a = _layer_forward(
@@ -280,9 +285,10 @@ def forward(cfg, params, inputs, *, cache=None, mode: str = "train",
             cache_index=cache_index, mode=mode, shard_fn=shard,
             page_size=page_size)
         if new_c is not None:
-            cl = jax.tree.map(
-                lambda buf, new: jax.lax.dynamic_update_index_in_dim(
-                    buf, new.astype(buf.dtype), li, 0), cl, new_c)
+            with jax.named_scope("kv_write"):
+                cl = jax.tree.map(
+                    lambda buf, new: jax.lax.dynamic_update_index_in_dim(
+                        buf, new.astype(buf.dtype), li, 0), cl, new_c)
         x = shard(x, "batch", "seq", "d_model")
         return (x, aux + a, cl), None
 
@@ -309,16 +315,18 @@ def forward(cfg, params, inputs, *, cache=None, mode: str = "train",
     logits = None
     if logits_mode != "none":
         from repro.core.packing import PackedWeight
-        if isinstance(head, PackedWeight) and cfg.logit_softcap:
-            # packed LM head: the tanh softcap runs on the fp32
-            # accumulator inside the GEMM's store step — the full-vocab
-            # logits tensor is written to HBM exactly once, capped
-            logits = L.linear(x, head, softcap=cfg.logit_softcap,
-                              out_dtype=jnp.float32)
-        else:
-            logits = L.linear(x, head)
-            logits = L.softcap(logits.astype(jnp.float32),
-                               cfg.logit_softcap)
+        with jax.named_scope("lm_head"):
+            if isinstance(head, PackedWeight) and cfg.logit_softcap:
+                # packed LM head: the tanh softcap runs on the fp32
+                # accumulator inside the GEMM's store step — the
+                # full-vocab logits tensor is written to HBM exactly
+                # once, capped
+                logits = L.linear(x, head, softcap=cfg.logit_softcap,
+                                  out_dtype=jnp.float32)
+            else:
+                logits = L.linear(x, head)
+                logits = L.softcap(logits.astype(jnp.float32),
+                                   cfg.logit_softcap)
         logits = shard(logits, "batch", "seq", "vocab")
 
     new_cache = None

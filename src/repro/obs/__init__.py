@@ -20,8 +20,10 @@ zero-cost no-ops when inactive):
   and achieved GFLOPS with fraction-of-roofline.  Jitted dispatches
   register trace-time *manifests* instead of fabricated timings.
 * **Span tracing** (``obs.spans``) — nestable ``span()`` scopes through
-  plan resolve, pack, autotune, serving ticks, prefix-cache and fault
-  events; exported as Chrome-trace JSON for ``ui.perfetto.dev``.
+  plan resolve, pack, autotune, the scheduler's steps and phases,
+  prefix-cache and fault events; exported as Chrome-trace JSON for
+  ``ui.perfetto.dev``, or forwarded to the JAX profiler's trace on the
+  device's clock (``Tracer(sink="profiler")``).
 * **Metrics** (``obs.metrics``) — counters/gauges/fixed-bucket
   histograms unifying ``ServeStats`` / ``PrefixCacheStats`` /
   ``StoreInfo`` / ``plan_cache_info`` behind Prometheus-text and JSON

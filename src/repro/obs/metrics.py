@@ -324,6 +324,19 @@ def publish_serve_stats(stats, reg: "MetricsRegistry | None" = None) -> None:
     h_d = reg.histogram("serve_decode_tick_ms")
     for v in stats.decode_tick_ms:
         h_d.observe(v)
+    # per scheduler step: wall time, and the host's part of it (wall
+    # minus the fences' wait on the device); uploads are a count
+    g("serve_steps").set(len(stats.steps))
+    g("serve_uploads").set(stats.uploads)
+    h_s = reg.histogram("serve_step_ms")
+    h_h = reg.histogram("serve_step_host_ms")
+    for r in stats.steps:
+        h_s.observe(r.wall_ms)
+        h_h.observe(r.wall_ms - r.fence_ms)
+    h_i = reg.histogram("serve_itl_ms")
+    for req in stats.requests:
+        for gap in req.itl_s:
+            h_i.observe(gap * 1e3)
     if stats.prefix is not None:
         publish_prefix_stats(stats.prefix, reg)
 

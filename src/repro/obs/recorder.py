@@ -195,9 +195,11 @@ class FlightRecorder:
             self.total += 1
         if spans._ANY and wall_s is not None:
             tr = spans.active_tracer()
-            if tr is not None:
+            if tr is not None and tr.sink == "memory":
                 # eager dispatches get real (measured) spans; traced
-                # ones get apportioned children at export time
+                # ones get plan-only children at export time.  The
+                # profiler sink keeps no events: there the device trace
+                # holds each GEMM's real time
                 tr._push({"name": "gemm_dispatch", "ph": "X",
                           "ts": tr._now_us() - wall_s * 1e6,
                           "dur": wall_s * 1e6, "pid": 1,

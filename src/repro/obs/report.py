@@ -11,17 +11,19 @@ Two jobs, both operating on the exported trace object (the JSON
   plans each compiled step dispatches, registered at trace time) and
   each scheduler tick's measured span.  So for every tick span carrying
   a ``step=<key>`` attribute we emit one child span per manifest plan,
-  with the tick's duration *apportioned by the plans' ``t_pred``
-  share* and each child explicitly flagged ``"apportioned": true`` —
-  honest attribution, visually useful in Perfetto, never mistakable
-  for a measurement.  Eager dispatches (warmup, direct execute) get
-  real measured spans from the recorder and are flagged
-  ``apportioned: false``.
+  laid out across the tick in proportion to the plans' ``t_pred`` and
+  flagged ``"apportioned": true``.  They carry the plan, lever and
+  dispatch-count fields only: a duration split by a model is no
+  measurement, so no GFLOPS or roofline share is derived from it (a
+  device profile gives each GEMM's real time).  Eager dispatches
+  (warmup, direct execute) get real measured spans from the recorder,
+  flagged ``apportioned: false``.
 
 * :func:`per_shape_table` — the paper's shape-resolved characterization
   from live traffic: per (m, n, k, format), the dispatch count, lever
-  mix, median achieved GFLOPS and median fraction-of-roofline.
-  Surfaced by the ``launch/trace_report`` CLI.
+  mix, and the median achieved GFLOPS and fraction-of-roofline of the
+  shape's fenced eager records (None where it has none).  Surfaced by
+  the ``launch/trace_report`` CLI.
 """
 from __future__ import annotations
 
@@ -70,37 +72,11 @@ def synthesize_gemm_events(trace: dict) -> list[dict]:
                 args = dict(r)
                 args["apportioned"] = True
                 args["step"] = step
-                wall_s = d * 1e-6
-                if wall_s > 0:
-                    args["gflops"] = (2.0 * r["m"] * r["n"] * r["k"]
-                                      / wall_s / 1e9)
-                    args["roofline_frac"] = _frac(r, wall_s)
                 out.append({"name": "gemm_dispatch", "ph": "X", "ts": ts,
                             "dur": d, "pid": 1,
                             "tid": ev.get("tid", 1), "args": args})
                 ts += d
     return out
-
-
-def _frac(rec: dict, wall_s: float) -> float | None:
-    """Roofline share against the running device's peaks; None ("not
-    measured") where the peaks table has no entry for it."""
-    try:
-        from repro.roofline import device_peaks, gemm_roofline
-        hw = device_peaks()
-        if hw is None:
-            return None
-        db = rec.get("density_bucket", -1)
-        wd = 1.0 if db < 0 else max(0.05, 1.0 - (db + 0.5) / 10.0)
-        bound = gemm_roofline(rec["m"], rec["n"], rec["k"], hw=hw,
-                              weight_format=rec.get("weight_format",
-                                                    "fp32"),
-                              weight_density=wd)
-        if bound and bound > 0:
-            return min(1.0, bound / wall_s)
-    except Exception:
-        pass
-    return None
 
 
 def gemm_events(trace: dict) -> list[dict]:
@@ -122,9 +98,11 @@ def _median(vals: list[float]) -> float | None:
 def per_shape_table(trace: dict) -> list[dict]:
     """The paper-style shape-resolved characterization from a trace:
     one row per (m, n, k, weight_format) with dispatch count, lever
-    mix, median GFLOPS and median roofline fraction.  ``apportioned``
-    counts how many of the shape's samples are share-attributed rather
-    than measured (0 = all real timings).  ``sparse`` lists the
+    mix, median GFLOPS and median roofline fraction.  The medians read
+    measured records only — fenced eager dispatches — and are None for
+    a shape that has none.  ``apportioned`` counts how many of the
+    shape's dispatches are jitted ones placed by the manifest, with no
+    timing of their own (0 = all measured).  ``sparse`` lists the
     density buckets seen for the shape (``dense`` or ``d<bucket>`` —
     the sparse-ternary arm's zero-group-fraction decile)."""
     groups: dict[tuple, dict] = {}
@@ -141,10 +119,11 @@ def per_shape_table(trace: dict) -> list[dict]:
             g["apportioned"] += 1
         lv = a.get("lever", "?")
         g["levers"][lv] = g["levers"].get(lv, 0) + 1
-        if a.get("gflops") is not None:
-            g["gflops"].append(a["gflops"])
-        if a.get("roofline_frac") is not None:
-            g["frac"].append(a["roofline_frac"])
+        if a.get("fenced") and not a.get("apportioned"):
+            if a.get("gflops") is not None:
+                g["gflops"].append(a["gflops"])
+            if a.get("roofline_frac") is not None:
+                g["frac"].append(a["roofline_frac"])
         g["split_k"].add(a.get("split_k", 1))
         g["epilogues"].add(a.get("epilogue", "none"))
         g["buckets"].add(a.get("density_bucket", -1))
@@ -182,7 +161,7 @@ def format_table(rows: list[dict]) -> str:
         for name, w in cols:
             v = r[name]
             if v is None:
-                v = "-"
+                v = "—"                      # not measured
             elif isinstance(v, float):
                 v = f"{v:.3f}"
             cells.append(str(v).rjust(w))

@@ -13,6 +13,18 @@ a process default (:func:`use_tracer` / :func:`set_tracer` /
 inactive path is a single integer check — instrumented call sites cost
 nothing measurable when tracing is off (the table12_obs overhead gate).
 
+Two sinks, one span API.  The default ``sink="memory"`` keeps events in
+``Tracer.events`` on ``time.perf_counter``.  ``sink="profiler"``
+forwards each span to ``jax.profiler.TraceAnnotation`` instead (entered
+at begin, exited at end, the span's attributes as its metadata) and
+keeps nothing in memory: inside a ``jax.profiler`` trace the spans land
+on the profiler's host plane, on the same clock as the device's ``XLA
+Ops`` and ``XLA Modules`` lines, so an idle gap on the device can be
+read against what the host was doing.  Every profiler-sink annotation
+carries the metadata key ``span_id`` (unique per tracer), which the
+runtime's own host events never carry: that key is how a trace reader
+tells the program's spans from the runtime's.
+
 Async-dispatch caveat (docs/observability.md): a span around a jitted
 call measures *dispatch* unless the caller fences.  Spans themselves
 never fence — the scheduler's tick timer (obs/timing) owns the fence
@@ -85,7 +97,7 @@ def no_tracer():
 class _SpanHandle:
     """Live handle yielded by :func:`span`: ``set(k=v)`` attaches
     attributes that are only known once the work ran."""
-    __slots__ = ("tracer", "name", "t0", "args", "sid", "tid")
+    __slots__ = ("tracer", "name", "t0", "args", "sid", "tid", "ann")
 
     def __init__(self, tracer, name, t0, args, sid, tid):
         self.tracer = tracer
@@ -94,9 +106,12 @@ class _SpanHandle:
         self.args = args
         self.sid = sid
         self.tid = tid
+        self.ann = None            # the open TraceAnnotation (profiler sink)
 
     def set(self, **kw):
         self.args.update(kw)
+        if self.ann is not None:
+            self.ann.set_metadata(**kw)
 
 
 class _NoopHandle:
@@ -109,6 +124,9 @@ class _NoopHandle:
 _NOOP = _NoopHandle()
 
 
+SINKS = ("memory", "profiler")
+
+
 class Tracer:
     """Collects span/instant events; thread-safe appends; exported via
     :func:`export_chrome_trace` (or :meth:`chrome_trace` for the dict).
@@ -116,9 +134,21 @@ class Tracer:
     ``max_events`` bounds memory on long serves: beyond it the OLDEST
     events are dropped (``dropped`` counts them) — the exported window
     is the most recent activity, matching the flight-recorder
-    discipline."""
+    discipline.
 
-    def __init__(self, *, max_events: int = 200_000):
+    ``sink="profiler"`` forwards every span and instant to
+    ``jax.profiler.TraceAnnotation`` and appends nothing to ``events``
+    (module docstring); outside a profiler trace the annotations cost a
+    no-op ``TraceMe`` each."""
+
+    def __init__(self, *, max_events: int = 200_000, sink: str = "memory"):
+        if sink not in SINKS:
+            raise ValueError(f"sink={sink!r}: expected one of {SINKS}")
+        self.sink = sink
+        self._annotation = None
+        if sink == "profiler":
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
         self.t0 = time.perf_counter()
         self.events: list[dict] = []
         self.max_events = max_events
@@ -140,7 +170,12 @@ class Tracer:
                 del self.events[:cut]
 
     def instant(self, name: str, **args):
-        """A zero-duration marker event (faults, degradations, evictions)."""
+        """A zero-duration marker event (faults, degradations, evictions);
+        a zero-length annotation under the profiler sink."""
+        if self._annotation is not None:
+            with self._annotation(name, span_id=next(self._ids), **args):
+                pass
+            return
         self._push({"name": name, "ph": "i", "s": "t",
                     "ts": self._now_us(), "pid": 1,
                     "tid": threading.get_ident() % 100_000,
@@ -150,6 +185,9 @@ class Tracer:
         tid = threading.get_ident() % 100_000
         h = _SpanHandle(self, name, self._now_us(), dict(args),
                         next(self._ids), tid)
+        if self._annotation is not None:
+            h.ann = self._annotation(name, span_id=h.sid, **args)
+            h.ann.__enter__()
         stack = getattr(_STATE, "spans", None)
         if stack is None:
             stack = _STATE.spans = []
@@ -160,6 +198,9 @@ class Tracer:
         stack = getattr(_STATE, "spans", None)
         if stack and stack[-1] is h:
             stack.pop()
+        if h.ann is not None:
+            h.ann.__exit__(None, None, None)
+            return
         self._push({"name": h.name, "ph": "X", "ts": h.t0,
                     "dur": self._now_us() - h.t0, "pid": 1, "tid": h.tid,
                     "args": h.args, "id": h.sid})

@@ -33,15 +33,18 @@ class FencedTimer:
     means the number is dispatch time).  ``synced`` counts the host
     syncs the fence actually performed — the scheduler adds it to its
     ``host_syncs`` accounting so the fence's cost is visible, never
-    silent."""
+    silent — and ``fence_s`` is the wall time spent inside them (the
+    host waiting on the device)."""
 
-    __slots__ = ("fence_enabled", "fenced", "synced", "elapsed_s", "_t0")
+    __slots__ = ("fence_enabled", "fenced", "synced", "elapsed_s",
+                 "fence_s", "_t0")
 
     def __init__(self, *, fence: bool = False):
         self.fence_enabled = fence
         self.fenced = False
         self.synced = 0
         self.elapsed_s = 0.0
+        self.fence_s = 0.0
         self._t0 = 0.0
 
     def __enter__(self) -> "FencedTimer":
@@ -54,7 +57,9 @@ class FencedTimer:
         over unconditionally and let the timer own the decision)."""
         if self.fence_enabled:
             import jax
+            t0 = time.perf_counter()
             jax.block_until_ready(values)
+            self.fence_s += time.perf_counter() - t0
             self.fenced = True
             self.synced += 1
 

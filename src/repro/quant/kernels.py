@@ -231,6 +231,7 @@ def quant_panel_gemm(
 
     return pl.pallas_call(
         kernel,
+        name="quant_panel_gemm_glu" if glu else "quant_panel_gemm",
         grid=(m // block_m, n_out // block_n, nk),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
@@ -484,6 +485,8 @@ def sparse_quant_panel_gemm(
     )
     return pl.pallas_call(
         kernel,
+        name=("sparse_quant_panel_gemm_glu" if glu
+              else "sparse_quant_panel_gemm"),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n_out), out_dtype),
         compiler_params=pltpu.CompilerParams(
@@ -603,6 +606,7 @@ def quant_panel_gemm_splitk(
 
     partials = pl.pallas_call(
         functools.partial(_quant_splitk_kernel, nks=nks, fmt=fmt),
+        name="quant_panel_gemm_splitk",
         grid=(split_k, m // block_m, n // block_n, nks),
         in_specs=[
             pl.BlockSpec((block_m, block_k),

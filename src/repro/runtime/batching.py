@@ -51,6 +51,7 @@ import collections
 import dataclasses
 import enum
 import time
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -118,7 +119,14 @@ class SchedulerStallError(RuntimeError):
 # ------------------------------------------------------------------ stats
 @dataclasses.dataclass
 class RequestStats:
-    """Per-request serving latency record (all seconds / tokens)."""
+    """Per-request serving latency record (all seconds / tokens).
+
+    ``emit_s`` holds each emitted token's time since enqueue on the
+    scheduler's clock (the first entry is the TTFT); ``itl_s`` are the
+    gaps between consecutive tokens.  A megastep drain of D ticks emits
+    D tokens at one time, so its inner gaps read 0.  Under
+    ``sync_per_step`` a token's time falls after the device finished
+    the step that made it; under async dispatch, after the dispatch."""
     rid: int
     prompt_len: int
     new_tokens: int
@@ -126,6 +134,22 @@ class RequestStats:
     ttft_s: float                # enqueue -> first token emitted
     total_s: float               # enqueue -> finished
     decode_tps: float            # new_tokens over first-token -> finish
+    emit_s: list = dataclasses.field(default_factory=list)
+
+    @property
+    def itl_s(self) -> list:
+        return [b - a for a, b in zip(self.emit_s, self.emit_s[1:])]
+
+
+class StepRecord(NamedTuple):
+    """One scheduler ``step()``: its start on ``time.perf_counter`` (s),
+    its wall time, the part of it spent in fences (the host waiting on
+    the device; 0 without ``sync_per_step``), and the host-to-device
+    uploads it made (page tables, lengths, chunks, masks)."""
+    t0: float
+    wall_ms: float
+    fence_ms: float
+    uploads: int
 
 
 @dataclasses.dataclass
@@ -157,7 +181,11 @@ class ServeStats:
     dispatch duration (a megastep drain of D ticks contributes D
     entries of drain/D — under ``sync_per_step`` these are exact
     device times, under async they are dispatch times); query p50/p99
-    via :meth:`phase_percentile`.  ``decode_dispatches`` counts device
+    via :meth:`phase_percentile`.  ``steps`` holds a
+    :class:`StepRecord` per scheduler ``step()``: its wall time
+    (``step_ms``) beside the time its fences waited, so a step's host
+    time is the difference, and the uploads it made.
+    ``decode_dispatches`` counts device
     decode calls (``decode_ticks / decode_dispatches`` ~= the realized
     megastep depth), ``host_syncs`` counts the host-blocking
     synchronization points the run actually paid (every
@@ -190,6 +218,7 @@ class ServeStats:
     requests: list[RequestStats] = dataclasses.field(default_factory=list)
     prefill_tick_ms: list = dataclasses.field(default_factory=list)
     decode_tick_ms: list = dataclasses.field(default_factory=list)
+    steps: list[StepRecord] = dataclasses.field(default_factory=list)
     decode_dispatches: int = 0
     host_syncs: int = 0
     megastep_depth: int = 1
@@ -217,6 +246,14 @@ class ServeStats:
     @property
     def decode_ticks(self) -> int:
         return len(self.decode_tick_ms)
+
+    @property
+    def step_ms(self) -> list:
+        return [r.wall_ms for r in self.steps]
+
+    @property
+    def uploads(self) -> int:
+        return sum(r.uploads for r in self.steps)
 
     @property
     def completed(self) -> int:
@@ -288,6 +325,7 @@ class _Request:
     ttft_budget_s: float | None = None
     total_budget_s: float | None = None
     cancel: bool = False
+    emitted_at: list = dataclasses.field(default_factory=list)
 
 
 class _Slot:
@@ -436,6 +474,9 @@ class ContinuousBatchingScheduler:
         self._next_rid = 0
         self._admit_seq = 0
         self._ticks = 0
+        # the running step's fence seconds and uploads (StepRecord)
+        self._step_fence_s = 0.0
+        self._step_uploads = 0
         # device-side run state: last emitted token per slot, and the
         # per-step [slots] token history (materialized at run end)
         self._last = jnp.zeros((batch_slots,), jnp.int32)
@@ -577,7 +618,8 @@ class ContinuousBatchingScheduler:
                 queue_wait_s=req.t_admit - req.t_enqueue,
                 ttft_s=req.t_first - req.t_enqueue,
                 total_s=now - req.t_enqueue,
-                decode_tps=req.max_new / max(now - req.t_first, 1e-9)))
+                decode_tps=req.max_new / max(now - req.t_first, 1e-9),
+                emit_s=[t - req.t_enqueue for t in req.emitted_at]))
             self.trace.append(("finish", req.rid, i))
         else:
             self.trace.append(("evict", req.rid, i, state.value))
@@ -733,39 +775,45 @@ class ContinuousBatchingScheduler:
                  if sl.request is not None and not sl.prefill_done]
         if not cands:
             return False
-        _, i = min(cands)                  # earliest admitted first
-        sl = self.slots[i]
-        req = sl.request
-        start = sl.n_prefilled
-        # chunk-tail bucketing: the last chunk of a prompt — and the
-        # whole divergent remainder after a prefix hit — dispatches at
-        # the smallest gemm.bucket_m width that holds it instead of the
-        # full admission width, so a 3-token divergent tail does not
-        # pay a chunk-wide GEMM.  The width set is the bucket ladder
-        # <= chunk, which Engine.warmup_plans pre-resolves.
-        rem = len(req.tokens) - start
-        width = self.chunk if rem >= self.chunk else gemm_api.bucket_m(rem)
-        end = min(start + width, len(req.tokens))
-        final = end == len(req.tokens)
-        try:
-            self.kv.alloc(i, end)
-        except Exception as e:
-            # allocator fault (real OOM past the reservation, or
-            # injected): quarantine this request only
-            self._release_slot(i, RequestState.FAILED,
-                               error=f"page allocation failed: {e}",
-                               error_type=type(e).__name__)
-            return True
-        chunk = np.zeros((1, width), np.int32)
-        chunk[0, :end - start] = req.tokens[start:end]
+        with _spans.span("prefill_prep"):
+            _, i = min(cands)              # earliest admitted first
+            sl = self.slots[i]
+            req = sl.request
+            start = sl.n_prefilled
+            # chunk-tail bucketing: the last chunk of a prompt — and the
+            # whole divergent remainder after a prefix hit — dispatches
+            # at the smallest gemm.bucket_m width that holds it instead
+            # of the full admission width, so a 3-token divergent tail
+            # does not pay a chunk-wide GEMM.  The width set is the
+            # bucket ladder <= chunk, which Engine.warmup_plans
+            # pre-resolves.
+            rem = len(req.tokens) - start
+            width = (self.chunk if rem >= self.chunk
+                     else gemm_api.bucket_m(rem))
+            end = min(start + width, len(req.tokens))
+            final = end == len(req.tokens)
+            try:
+                self.kv.alloc(i, end)
+            except Exception as e:
+                # allocator fault (real OOM past the reservation, or
+                # injected): quarantine this request only
+                self._release_slot(i, RequestState.FAILED,
+                                   error=f"page allocation failed: {e}",
+                                   error_type=type(e).__name__)
+                return True
+            chunk = np.zeros((1, width), np.int32)
+            chunk[0, :end - start] = req.tokens[start:end]
+            # every input is shipped here, once: a retry or a fallback
+            # dispatch re-sends the same arrays
+            inputs = (self.kv.table_device([i]), self.kv.lens_device([i]),
+                      jnp.asarray(chunk),
+                      jnp.asarray(end - start - 1, jnp.int32))
+            self._step_uploads += len(inputs)
 
         def dispatch(fb):
             kw = {"fallback": True} if fb else {}
             return self.engine.prefill_chunk(
-                self.kv.pages, self.kv.table_device([i]),
-                self.kv.lens_device([i]), jnp.asarray(chunk),
-                jnp.asarray(end - start - 1, jnp.int32),
-                page_size=self.page_size, **kw)
+                self.kv.pages, *inputs, page_size=self.page_size, **kw)
 
         # tick timing through the obs fenced timer: under sync_per_step
         # the fence closes the clock AFTER the device finishes (real
@@ -778,87 +826,95 @@ class ContinuousBatchingScheduler:
                          rid=req.rid, slot=i, tokens=end - start,
                          fenced=self.sync_per_step), timer:
             try:
-                tok, pages = self._guarded("prefill_dispatch", dispatch,
-                                           rid=req.rid)
+                with _spans.span("dispatch"):
+                    tok, pages = self._guarded("prefill_dispatch",
+                                               dispatch, rid=req.rid)
             except Exception as e:
                 self._release_slot(i, RequestState.FAILED,
                                    error=f"prefill dispatch failed: {e}",
                                    error_type=type(e).__name__)
                 return True
             self.kv.pages = pages
-            timer.fence(tok)
-        self.stats.host_syncs += timer.synced
-        dt = timer.elapsed_s
-        self.stats.prefill_s += dt
-        self.stats.prefill_tick_ms.append(dt * 1e3)
-        self.stats.prefill_tokens += end - start
-        self.kv.lens[i] = end
-        sl.n_prefilled = end
-        self.trace.append(("prefill", req.rid, i, start, end))
-        if final:
-            if self.prefix is not None:
-                # prompt fully prefilled: its full pages are immutable
-                # from here (decode writes land strictly past the
-                # prompt) — index them BEFORE _emit can free the slot.
-                # An index error only loses future hits: degrade.
-                try:
-                    self.prefix.insert(i, req.tokens)
-                except Exception as e:
-                    self._degrade("prefix_insert", e)
-            # first token stays on device — it feeds the slot's decode
-            # steps through the last-token row, no host sync needed
-            self._last = self._last.at[i].set(tok)
-            sl.first_tok = tok
-            req.t_first = self._clock()
-            self._emit(i)
-        if self.check_invariants:
-            self.kv.check_no_aliasing()
+            with _spans.span("fence"):
+                timer.fence(tok)
+        with _spans.span("prefill_post"):
+            self._step_fence_s += timer.fence_s
+            self.stats.host_syncs += timer.synced
+            dt = timer.elapsed_s
+            self.stats.prefill_s += dt
+            self.stats.prefill_tick_ms.append(dt * 1e3)
+            self.stats.prefill_tokens += end - start
+            self.kv.lens[i] = end
+            sl.n_prefilled = end
+            self.trace.append(("prefill", req.rid, i, start, end))
+            if final:
+                if self.prefix is not None:
+                    # prompt fully prefilled: its full pages are
+                    # immutable from here (decode writes land strictly
+                    # past the prompt) — index them BEFORE _emit can
+                    # free the slot.  An index error only loses future
+                    # hits: degrade.
+                    try:
+                        self.prefix.insert(i, req.tokens)
+                    except Exception as e:
+                        self._degrade("prefix_insert", e)
+                # first token stays on device — it feeds the slot's
+                # decode steps through the last-token row, no host sync
+                self._last = self._last.at[i].set(tok)
+                sl.first_tok = tok
+                self._emit(i)
+            if self.check_invariants:
+                self.kv.check_no_aliasing()
         return True
 
     def _decode_step(self) -> bool:
         dec = [i for i, sl in enumerate(self.slots) if sl.prefill_done]
         if not dec:
             return False
-        # realized megastep depth: never let a slot over-generate — the
-        # shallowest remaining budget among decoding slots caps the
-        # drain, so a request finishes exactly at its max_new and the
-        # trace/exactly-once invariants hold at every depth
-        d = 1
-        if self.megastep_depth > 1:
-            d = min(self.megastep_depth,
-                    min(self.slots[i].request.max_new
-                        - self.slots[i].n_emitted for i in dec))
-        # per-slot page growth, individually guarded: an allocator fault
-        # growing one slot evicts that request only; the rest decode on
-        ok = []
-        for i in dec:
-            try:
-                self.kv.alloc(i, int(self.kv.lens[i]) + d)
-            except Exception as e:
-                self._release_slot(i, RequestState.FAILED,
-                                   error=f"page allocation failed: {e}",
-                                   error_type=type(e).__name__)
-                continue
-            ok.append(i)
-        if not ok:
-            return True                    # work happened: quarantines
-        mask = np.zeros((self.batch_slots,), bool)
-        for i in ok:
-            mask[i] = True
-        rids = tuple(self.slots[i].request.rid for i in ok)
+        with _spans.span("decode_prep"):
+            # realized megastep depth: never let a slot over-generate —
+            # the shallowest remaining budget among decoding slots caps
+            # the drain, so a request finishes exactly at its max_new
+            # and the trace/exactly-once invariants hold at every depth
+            d = 1
+            if self.megastep_depth > 1:
+                d = min(self.megastep_depth,
+                        min(self.slots[i].request.max_new
+                            - self.slots[i].n_emitted for i in dec))
+            # per-slot page growth, individually guarded: an allocator
+            # fault growing one slot evicts that request only; the rest
+            # decode on
+            ok = []
+            for i in dec:
+                try:
+                    self.kv.alloc(i, int(self.kv.lens[i]) + d)
+                except Exception as e:
+                    self._release_slot(i, RequestState.FAILED,
+                                       error=f"page allocation failed: {e}",
+                                       error_type=type(e).__name__)
+                    continue
+                ok.append(i)
+            if not ok:
+                return True                # work happened: quarantines
+            mask = np.zeros((self.batch_slots,), bool)
+            for i in ok:
+                mask[i] = True
+            rids = tuple(self.slots[i].request.rid for i in ok)
+            inputs = (self.kv.table_device(), self.kv.lens_device(),
+                      jnp.asarray(mask))
+            # a megastep also ships its tick count (Engine.decode_megastep)
+            self._step_uploads += len(inputs) + (d > 1)
 
         def dispatch(fb):
             kw = {"fallback": True} if fb else {}
             if d > 1:
                 last, hist, pages = self.engine.decode_megastep(
-                    self.kv.pages, self.kv.table_device(),
-                    self.kv.lens_device(), jnp.asarray(mask), self._last,
-                    d, page_size=self.page_size,
+                    self.kv.pages, *inputs, self._last, d,
+                    page_size=self.page_size,
                     max_depth=self.megastep_depth, **kw)
                 return last, [hist[t] for t in range(d)], pages
             last, pages = self.engine.decode_step(
-                self.kv.pages, self.kv.table_device(),
-                self.kv.lens_device(), jnp.asarray(mask), self._last,
+                self.kv.pages, *inputs, self._last,
                 page_size=self.page_size, **kw)
             return last, [last], pages
 
@@ -869,8 +925,9 @@ class ContinuousBatchingScheduler:
         with _spans.span("decode_tick", step="decode_step", ticks=d,
                          slots=len(ok), fenced=self.sync_per_step), timer:
             try:
-                last, ticks, pages = self._guarded("decode_dispatch",
-                                                   dispatch, rids=rids)
+                with _spans.span("dispatch"):
+                    last, ticks, pages = self._guarded(
+                        "decode_dispatch", dispatch, rids=rids)
             except Exception as e:
                 # single-victim attribution when the error names a rid
                 # (an injected poison request, or any error carrying
@@ -887,28 +944,36 @@ class ContinuousBatchingScheduler:
             self._last = last
             self.kv.pages = pages
             self.stats.decode_dispatches += 1
-            timer.fence(self._last)
-        self.stats.host_syncs += timer.synced
-        dt = timer.elapsed_s
-        self.stats.decode_s += dt
-        self.stats.decode_tick_ms.extend([dt * 1e3 / d] * d)
-        for tok_row in ticks:
-            step_idx = len(self._history)
-            self._history.append(tok_row)
-            self.trace.append(("decode", rids))
-            for i in ok:
-                self.kv.lens[i] += 1
-                self.slots[i].steps.append(step_idx)
-                self._emit(i)
-        if self.check_invariants:
-            self.kv.check_no_aliasing()
+            with _spans.span("fence"):
+                timer.fence(self._last)
+        with _spans.span("decode_post"):
+            self._step_fence_s += timer.fence_s
+            self.stats.host_syncs += timer.synced
+            dt = timer.elapsed_s
+            self.stats.decode_s += dt
+            self.stats.decode_tick_ms.extend([dt * 1e3 / d] * d)
+            for tok_row in ticks:
+                step_idx = len(self._history)
+                self._history.append(tok_row)
+                self.trace.append(("decode", rids))
+                for i in ok:
+                    self.kv.lens[i] += 1
+                    self.slots[i].steps.append(step_idx)
+                    self._emit(i)
+            if self.check_invariants:
+                self.kv.check_no_aliasing()
         return True
 
     def _emit(self, i: int):
         sl = self.slots[i]
+        req = sl.request
+        now = self._clock()
+        if req.t_first is None:
+            req.t_first = now
+        req.emitted_at.append(now)
         sl.n_emitted += 1
         self.stats.decode_tokens += 1
-        if sl.n_emitted == sl.request.max_new:
+        if sl.n_emitted == req.max_new:
             self._release_slot(i, RequestState.DONE)
 
     def _materialize(self):
@@ -934,19 +999,35 @@ class ContinuousBatchingScheduler:
     def step(self) -> bool:
         """One scheduler tick: enforce deadlines/cancellations, admit,
         one prefill chunk, one decode step.  Returns False once no work
-        remains."""
+        remains.
+
+        Spans (when a tracer is active): ``sched_step`` around the tick,
+        with children ``deadlines``, ``admit``, then per phase
+        ``<phase>_prep`` (slot choice, page allocation, host inputs and
+        their uploads), the dispatch span (``prefill_chunk`` /
+        ``decode_tick``, with children ``dispatch`` — the jitted call
+        until it returns — and ``fence``) and ``<phase>_post``
+        (bookkeeping and token emission)."""
         t0 = time.perf_counter()
         self._ticks += 1
-        # chaos point: delay specs model stragglers (the watchdog must
-        # flag them); error specs model scheduler-internal failures
-        # (the run()-level try/finally must still release every page)
-        faults.maybe_fire("slow_tick", tick=self._ticks)
-        self._enforce_deadlines()
-        self._admit()
-        did_p = self._prefill_step()
-        did_d = self._decode_step()
+        self._step_fence_s, self._step_uploads = 0.0, 0
+        with _spans.span("sched_step", tick=self._ticks):
+            # chaos point: delay specs model stragglers (the watchdog
+            # must flag them); error specs model scheduler-internal
+            # failures (the run()-level try/finally must still release
+            # every page)
+            faults.maybe_fire("slow_tick", tick=self._ticks)
+            with _spans.span("deadlines"):
+                self._enforce_deadlines()
+            with _spans.span("admit"):
+                self._admit()
+            did_p = self._prefill_step()
+            did_d = self._decode_step()
+        wall = time.perf_counter() - t0
+        self.stats.steps.append(StepRecord(
+            t0, wall * 1e3, self._step_fence_s * 1e3, self._step_uploads))
         if self.watchdog is not None:
-            self.watchdog.record(time.perf_counter() - t0)
+            self.watchdog.record(wall)
         return did_p or did_d or bool(self.queue)
 
     def run(self, requests, max_new_tokens, *,

@@ -47,6 +47,7 @@ from __future__ import annotations
 
 import collections
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -103,15 +104,16 @@ def paged_gather(pages: jnp.ndarray, page_table: jnp.ndarray,
     ids (-1 = unmapped).  Returns [B, n_view * page_size, *feat]; the
     contents of unmapped pages are arbitrary (physical page 0) — callers
     mask them by position, exactly as the dense cache masks its
-    zero-initialized tail.
+    zero-initialized tail.  Its ops carry the device scope ``kv_view``.
     """
     p_phys = pages.shape[0]
     feat = pages.shape[2:]
     b, n_view = page_table.shape
-    flat = pages.reshape(p_phys * page_size, *feat)
-    base = jnp.where(page_table >= 0, page_table, 0) * page_size
-    idx = base[:, :, None] + jnp.arange(page_size)[None, None, :]
-    return flat[idx.reshape(b, n_view * page_size)]
+    with jax.named_scope("kv_view"):
+        flat = pages.reshape(p_phys * page_size, *feat)
+        base = jnp.where(page_table >= 0, page_table, 0) * page_size
+        idx = base[:, :, None] + jnp.arange(page_size)[None, None, :]
+        return flat[idx.reshape(b, n_view * page_size)]
 
 
 def paged_update(pages: jnp.ndarray, new: jnp.ndarray,
@@ -124,25 +126,26 @@ def paged_update(pages: jnp.ndarray, new: jnp.ndarray,
     admitted but not decoding this step, or idle) write nothing.  Writes
     through unmapped table entries (-1) or past the table end are
     dropped, so chunk padding rows and masked slots can never touch a
-    freed or foreign page.
+    freed or foreign page.  Its ops carry the device scope ``kv_write``.
     """
     p_phys = pages.shape[0]
     feat = pages.shape[2:]
     b, s = new.shape[0], new.shape[1]
     n_view = page_table.shape[1]
-    pos = lens[:, None] + jnp.arange(s)[None, :]              # [B, s]
-    page_idx = pos // page_size
-    phys = jnp.take_along_axis(page_table,
-                               jnp.clip(page_idx, 0, n_view - 1), axis=1)
-    valid = (phys >= 0) & (page_idx < n_view)
-    if write_mask is not None:
-        valid &= write_mask[:, None]
-    oob = p_phys * page_size                                  # drop sentinel
-    flat_idx = jnp.where(valid, phys * page_size + pos % page_size, oob)
-    flat = pages.reshape(p_phys * page_size, *feat)
-    flat = flat.at[flat_idx.reshape(b * s)].set(
-        new.reshape(b * s, *feat).astype(flat.dtype), mode="drop")
-    return flat.reshape(pages.shape)
+    with jax.named_scope("kv_write"):
+        pos = lens[:, None] + jnp.arange(s)[None, :]          # [B, s]
+        page_idx = pos // page_size
+        phys = jnp.take_along_axis(page_table,
+                                   jnp.clip(page_idx, 0, n_view - 1), axis=1)
+        valid = (phys >= 0) & (page_idx < n_view)
+        if write_mask is not None:
+            valid &= write_mask[:, None]
+        oob = p_phys * page_size                              # drop sentinel
+        flat_idx = jnp.where(valid, phys * page_size + pos % page_size, oob)
+        flat = pages.reshape(p_phys * page_size, *feat)
+        flat = flat.at[flat_idx.reshape(b * s)].set(
+            new.reshape(b * s, *feat).astype(flat.dtype), mode="drop")
+        return flat.reshape(pages.shape)
 
 
 # ------------------------------------------------------ host-side pool

@@ -119,3 +119,37 @@ def test_engine_serves_prepacked_params():
     np.testing.assert_array_equal(np.asarray(out_pre), np.asarray(out_raw))
     with pytest.raises(ValueError, match="pack-time"):
         serve_loop.Engine(cfg, eng.params, packed=True, quant="int8")
+
+
+def test_serve_profile_dir_puts_the_schedulers_spans_in_the_profile(
+        monkeypatch, tmp_path, capsys):
+    import glob
+    from repro import obs
+    from repro.launch import serve
+    # the persistent cache would outlive this test in the worker process
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: None)
+    monkeypatch.setattr(sys, "argv", [
+        "serve", "--arch", "stablelm-3b", "--batch", "1", "--prompt-len",
+        "8", "--max-new", "2", "--max-len", "32", "--requests", "2",
+        "--batch-slots", "2", "--prefill-chunk", "8",
+        "--profile-dir", str(tmp_path)])
+    serve.main()
+    assert obs.active_tracer() is None             # uninstalled at exit
+    path = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    pd = jax.profiler.ProfileData.from_file(path)
+    names = {e.name for plane in pd.planes if plane.name.startswith("/host")
+             for line in plane.lines for e in line.events
+             if "span_id" in dict(e.stats)}
+    assert {"sched_step", "prefill_chunk", "decode_tick", "fence"} <= names
+    assert "profile written" in capsys.readouterr().out
+
+
+def test_serve_refuses_two_span_sinks(monkeypatch, tmp_path):
+    from repro.launch import serve
+    monkeypatch.setattr(sys, "argv", [
+        "serve", "--arch", "stablelm-3b", "--profile-dir", str(tmp_path),
+        "--trace-out", str(tmp_path / "t.json")])
+    with pytest.raises(SystemExit) as e:
+        serve.main()
+    assert e.value.code == 2

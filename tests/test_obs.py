@@ -9,7 +9,7 @@ Four contracts:
   GEMM path (the strict <=3% gate lives in benchmarks/table12_obs.py).
 * **Schema** — an exported trace is valid Chrome-trace JSON
   (``validate_chrome_trace`` finds nothing) and its synthesized
-  ``gemm_dispatch`` spans carry plan key, lever and GFLOPS.
+  ``gemm_dispatch`` spans carry plan key and lever, and no GFLOPS.
 * **Determinism** — two identical seeded serve runs publish
   byte-identical metrics snapshots once wall-clock-valued metrics
   (``_ms`` / ``_seconds`` names) are excluded.
@@ -130,7 +130,9 @@ def test_exported_trace_is_valid_and_carries_gemm_spans(engine, tmp_path):
     for a in gemms[:10]:
         assert a["plan"] and a["lever"] and a["m"] > 0
         assert a["apportioned"] is True
-        assert a["gflops"] > 0
+        # a duration split by the plans' predicted shares is no
+        # measurement: no rate or roofline share is derived from it
+        assert "gflops" not in a and "roofline_frac" not in a
     # tick spans carry the step attr linking them to their manifest
     # (plan_resolve spans only appear when plans were not already
     # cached by an earlier run — not asserted here)
@@ -305,3 +307,164 @@ def test_gemm_roofline_bound_monotone_in_format():
     t8 = gemm_roofline(256, 1024, 1024, weight_format="int8", hw=V5E)
     t2 = gemm_roofline(256, 1024, 1024, weight_format="ternary", hw=V5E)
     assert t32 > 0 and t32 >= t8 >= t2    # fewer weight bytes, lower bound
+
+
+# ------------------------------------------------ profiler sink (spans
+# on the device trace's clock)
+SCHED_NESTING = {
+    # span -> the span it nests in directly
+    "deadlines": "sched_step", "admit": "sched_step",
+    "prefill_prep": "sched_step", "prefill_chunk": "sched_step",
+    "prefill_post": "sched_step", "decode_prep": "sched_step",
+    "decode_tick": "sched_step", "decode_post": "sched_step",
+}
+PHASE_CHILDREN = ("dispatch", "fence")    # of prefill_chunk / decode_tick
+
+
+def _profiled_serve(eng, reqs, tmp_path, tracer):
+    import glob
+    import os
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with obs.use_tracer(tracer):
+            outs, _ = eng.serve(reqs, batch_slots=2, max_new_tokens=MNS,
+                                prefill_chunk=CHUNK, page_size=PAGE,
+                                sync_per_step=True)
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    pd = jax.profiler.ProfileData.from_file(path)
+    spans = []
+    for plane in pd.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    stats = dict(e.stats)
+                    if "span_id" in stats:
+                        spans.append((e.name, e.start_ns, e.end_ns, stats))
+    return outs, spans
+
+
+def _parent(spans, child):
+    """The shortest span that contains ``child`` (its direct parent)."""
+    _, a, b, _ = child
+    cover = [s for s in spans if s is not child and s[1] <= a
+             and b <= s[2] and (s[2] - s[1]) >= (b - a)]
+    return min(cover, key=lambda s: s[2] - s[1])[0] if cover else None
+
+
+def test_profiler_sink_writes_scheduler_spans_in_their_nesting(engine,
+                                                               tmp_path):
+    cfg, eng = engine
+    reqs = _requests(cfg, LENS, seed=3)
+    tracer = obs.Tracer(sink="profiler")
+    _, spans = _profiled_serve(eng, reqs, tmp_path, tracer)
+    assert tracer.events == []                 # the sink keeps nothing
+    names = {s[0] for s in spans}
+    assert {"sched_step", *SCHED_NESTING, *PHASE_CHILDREN} <= names
+    assert len({s[3]["span_id"] for s in spans}) == len(spans)
+    for s in spans:
+        if s[0] in SCHED_NESTING:
+            assert _parent(spans, s) == SCHED_NESTING[s[0]], s[:3]
+        elif s[0] in PHASE_CHILDREN:
+            assert _parent(spans, s) in ("prefill_chunk", "decode_tick")
+        elif s[0] == "sched_step":
+            assert _parent(spans, s) is None
+    chunk = next(s for s in spans if s[0] == "prefill_chunk")
+    assert {"step", "rid", "slot", "tokens", "fenced"} <= set(chunk[3])
+
+
+def test_served_tokens_identical_with_profiler_sink_on_and_off(engine,
+                                                               tmp_path):
+    cfg, eng = engine
+    reqs = _requests(cfg, LENS, seed=4)
+    off, _ = eng.serve(reqs, batch_slots=2, max_new_tokens=MNS,
+                       prefill_chunk=CHUNK, page_size=PAGE,
+                       sync_per_step=True)
+    on, spans = _profiled_serve(eng, reqs, tmp_path,
+                                obs.Tracer(sink="profiler"))
+    assert spans
+    for a, b in zip(off, on):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_memory_sink_keeps_the_scheduler_spans_and_no_tracer_records_none(
+        engine):
+    cfg, eng = engine
+    reqs = _requests(cfg, LENS[:2], seed=5)
+    idle = obs.Tracer()                        # made, never installed
+    assert obs_spans._ANY == 0
+    eng.serve(reqs, batch_slots=2, max_new_tokens=MNS[:2],
+              prefill_chunk=CHUNK, page_size=PAGE)
+    assert idle.events == [] and obs.current_span() is None
+    tr = obs.Tracer()
+    with obs.use_tracer(tr):
+        eng.serve(reqs, batch_slots=2, max_new_tokens=MNS[:2],
+                  prefill_chunk=CHUNK, page_size=PAGE)
+    evs = [ev for ev in tr.events if ev["ph"] == "X"]
+    names = {ev["name"] for ev in evs}
+    assert {"sched_step", *SCHED_NESTING, *PHASE_CHILDREN} <= names
+    steps = [ev for ev in evs if ev["name"] == "sched_step"]
+    for ev in evs:
+        if ev["name"] in SCHED_NESTING:     # inside some sched_step
+            assert any(s["ts"] <= ev["ts"] and
+                       ev["ts"] + ev["dur"] <= s["ts"] + s["dur"]
+                       for s in steps), ev["name"]
+    with pytest.raises(ValueError):
+        obs.Tracer(sink="file")
+
+
+def test_serve_stats_record_steps_uploads_and_token_times(engine):
+    cfg, eng = engine
+    reqs = _requests(cfg, LENS, seed=6)
+    reg = obs.MetricsRegistry()
+    with obs.use_metrics(reg):
+        outs, stats = eng.serve(reqs, batch_slots=2, max_new_tokens=MNS,
+                                prefill_chunk=CHUNK, page_size=PAGE,
+                                sync_per_step=True)
+    n_prefill, n_decode = len(stats.prefill_tick_ms), stats.decode_dispatches
+    assert stats.steps and stats.step_ms == [r.wall_ms for r in stats.steps]
+    # a prefill ships table, lengths, chunk and row index; a decode
+    # ships table, lengths and the write mask
+    assert stats.uploads == 4 * n_prefill + 3 * n_decode
+    t0s = [r.t0 for r in stats.steps]
+    assert t0s == sorted(t0s)
+    for r in stats.steps:
+        assert 0 <= r.fence_ms <= r.wall_ms
+    assert sum(r.fence_ms for r in stats.steps) > 0
+    for req in stats.requests:
+        assert len(req.emit_s) == req.new_tokens
+        assert req.emit_s[0] == pytest.approx(req.ttft_s)
+        assert len(req.itl_s) == req.new_tokens - 1
+        assert all(g >= 0 for g in req.itl_s)
+    snap = reg.snapshot()
+    assert snap["serve_uploads"]["series"]["_"] == stats.uploads
+    assert snap["serve_steps"]["series"]["_"] == len(stats.steps)
+    assert snap["serve_step_ms"]["series"]["_"]["count"] == len(stats.steps)
+    assert snap["serve_itl_ms"]["series"]["_"]["count"] == \
+        sum(m - 1 for m in MNS)
+
+
+def test_trace_report_medians_read_measured_records_only():
+    rec = {"plan": "p", "m": 8, "n": 64, "k": 64, "lever": "prepack",
+           "weight_format": "fp32"}
+    trace = {"traceEvents": [
+        {"name": "gemm_dispatch", "ph": "X", "ts": 0, "dur": 5,
+         "args": dict(rec, apportioned=True)},
+        {"name": "gemm_dispatch", "ph": "X", "ts": 5, "dur": 5,
+         "args": dict(rec, m=16, fenced=False, wall_ms=1.0)},
+        {"name": "gemm_dispatch", "ph": "X", "ts": 10, "dur": 5,
+         "args": dict(rec, m=16, fenced=True, gflops=3.0,
+                      roofline_frac=0.5)},
+    ]}
+    rows = {r["m"]: r for r in obs.per_shape_table(trace)}
+    assert rows[8]["median_gflops"] is None and rows[8]["apportioned"] == 1
+    assert rows[16]["median_gflops"] == 3.0
+    assert rows[16]["median_roofline_frac"] == 0.5
+    table = obs.format_table(obs.per_shape_table(trace))
+    assert "—" in table.splitlines()[2]         # m=8: not measured

@@ -70,3 +70,13 @@ def test_state_carries_across_chunks():
 
 def test_vmem_budget():
     assert K.vmem_bytes(128, 64, 128) < 16 * 2 ** 20
+
+
+def test_ssd_kernel_carries_its_name():
+    """The kernel's ``name`` names it in the lowered program (interpret
+    mode: Mosaic has no ``cumsum``, so it does not lower for the chip)."""
+    import jax
+    x, a, b, c = _mk(1, 64, 16, 16)
+    text = jax.jit(lambda *z: K.ssd(*z, chunk=32, interpret=True)).lower(
+        x, a, b, c).as_text(debug_info=True)
+    assert '"ssd/pallas_call"' in text

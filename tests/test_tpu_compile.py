@@ -23,6 +23,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import gemm
+from repro.kernels.flash_attention import flash_attention
 from repro.models import model_zoo
 from repro.quant import formats as F
 from repro.quant import kernels as QK
@@ -191,3 +192,87 @@ def test_sparse_ternary_gemm_compiles(one_chip):
                              sharding=one_chip),
     ).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def _lowered(f, *shapes, sharding):
+    return jax.jit(f).lower(*_on_chip(list(shapes), sharding)).as_text()
+
+
+def _execute_text(p, pw, sharding, residual=False):
+    """Lowered text of ``gemm.execute`` of plan ``p`` on the chip."""
+    cdt = model_zoo.get_config(ARCH).cdtype
+    args = [jax.ShapeDtypeStruct((p.m, p.k), cdt, sharding=sharding), pw]
+    if residual:
+        args.append(jax.ShapeDtypeStruct((p.m, p.n_out), cdt,
+                                         sharding=sharding))
+
+    def f(x, w, r=None):
+        return gemm.execute(p, x, w, residual=r)
+
+    return jax.jit(f).lower(*args).as_text()
+
+
+def _serving_kernel_text(case, sharding, quant=None):
+    where, m, spec = SERVING_GEMMS[case]
+    _, packed = _packs(quant=quant)
+    pw = _pack_at(packed, where, sharding)
+    p = gemm.plan_for_packed(m, pw, backend="pallas", epilogue=spec,
+                             decode=m == DECODE_M)
+    return _execute_text(p, pw, sharding,
+                         residual=spec is not None and spec.residual)
+
+
+def _splitk_kernel_text(sharding, quant=None):
+    """The wo pack's decode GEMM split along K (as the split-K compile
+    tests above split it)."""
+    _, packed = _packs(quant=quant)
+    pw = _layer_pack(packed["layers"]["attn"]["wo"], sharding)
+    split = 5 if quant is None else pw.k_pad // pw.block_k
+    p = gemm.plan(8, pw.n, pw.k, backend="pallas", decode=True,
+                  block_n=pw.block_n, block_k=pw.block_k, pack="prepacked",
+                  weight_format=quant or "fp32", split_k=split)
+    return _execute_text(p, pw, sharding)
+
+
+KERNEL_NAMES = {
+    "panel_gemm": lambda s: _serving_kernel_text("wqkv", s),
+    "panel_gemm_glu": lambda s: _serving_kernel_text("glu_gate_up", s),
+    "panel_gemm_splitk": lambda s: _splitk_kernel_text(s),
+    "quant_panel_gemm": lambda s: _serving_kernel_text(
+        "wqkv", s, quant="int8"),
+    "quant_panel_gemm_glu": lambda s: _serving_kernel_text(
+        "glu_gate_up", s, quant="int8"),
+    "quant_panel_gemm_splitk": lambda s: _splitk_kernel_text(
+        s, quant="int8"),
+    "flash_attention": lambda s: _lowered(
+        lambda q, k, v: flash_attention(q, k, v),
+        *[jax.ShapeDtypeStruct((4, 256, 128), jnp.bfloat16)] * 3,
+        sharding=s),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNEL_NAMES))
+def test_every_pallas_kernel_carries_its_name(one_chip, name):
+    """Each ``pallas_call`` names its kernel, so a device profile names
+    it too; the name rides in the lowered custom call.  (The SSD kernel
+    does not lower for the chip — Mosaic has no ``cumsum`` — and is
+    checked in interpret mode, tests/test_kernels.py.)"""
+    text = KERNEL_NAMES[name](one_chip)
+    assert f'kernel_name = "{name}"' in text
+
+
+def test_sparse_kernel_carries_its_name(one_chip):
+    cfg = model_zoo.get_config(ARCH)
+    k, n = cfg.d_model, 512
+    kg = k // F.GROUP_K
+    occupied = tuple(range(0, kg, 2))
+    layout = (kg, occupied, (sum(1 << g for g in occupied),) * 2, 256)
+    text = _lowered(
+        lambda x, codes, scales: QK.sparse_quant_panel_gemm(
+            x, codes, scales, sparse_layout=layout, block_m=PREFILL_M,
+            block_n=256),
+        jax.ShapeDtypeStruct((PREFILL_M, k), cfg.cdtype),
+        jax.ShapeDtypeStruct((len(occupied) * F.GROUP_K // 4, n), jnp.uint8),
+        jax.ShapeDtypeStruct((len(occupied), n), jnp.float32),
+        sharding=one_chip)
+    assert 'kernel_name = "sparse_quant_panel_gemm"' in text
