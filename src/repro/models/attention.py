@@ -19,6 +19,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.models import flash_vjp as _fv
 
@@ -179,23 +180,35 @@ def _update_ring_cache(cache, k, v, cache_index, s):
 
 def _update_paged_cache(cache, k, v, page_size):
     """Continuous-batching paged cache (runtime/kv_cache): scatter the new
-    tokens of every slot at its own length, then gather the logical-order
-    dense view.  The gathered view has the same length and chunk layout as
-    the dense ``[B, max_len]`` cache, and masked positions contribute
-    exact zeros, so attention here is bit-identical to the dense path —
-    the serving parity gate (tests/test_serving.py) rests on this."""
+    tokens of every slot at its own length into the stacked pool at layer
+    ``cache['layer']``, then gather the logical-order dense view from it;
+    both index the pool in place.  The gathered view has the same length
+    and chunk layout as the dense ``[B, max_len]`` cache, and masked
+    positions contribute exact zeros, so attention here is bit-identical
+    to the dense path — the serving parity gate (tests/test_serving.py)
+    rests on this."""
     from repro.runtime import kv_cache as KV
-    pt, lens = cache["page_table"], cache["lens"]
+    pt, lens, li = cache["page_table"], cache["lens"], cache["layer"]
     wm = cache.get("write_mask")
     s = k.shape[1]
-    pk = KV.paged_update(cache["pages_k"], k, pt, lens, page_size,
+    pk = KV.paged_update(cache["pages_k"], k, pt, lens, page_size, li,
                          write_mask=wm)
-    pv = KV.paged_update(cache["pages_v"], v, pt, lens, page_size,
+    pv = KV.paged_update(cache["pages_v"], v, pt, lens, page_size, li,
                          write_mask=wm)
-    k_d = KV.paged_gather(pk, pt, page_size)
-    v_d = KV.paged_gather(pv, pt, page_size)
+    k_d = KV.paged_gather(pk, pt, page_size, li)
+    v_d = KV.paged_gather(pv, pt, page_size, li)
     t_view = k_d.shape[1]
     with jax.named_scope("kv_view"):
+        if k_d.ndim != k.ndim:
+            # a pool of narrow heads holds a token's heads as one row
+            # (kv_cache.leaf_specs_for).  Pinned row-major, the view is
+            # relaid out once, here; left free, XLA transposes it to make
+            # the reshape free and copies every attention block back.
+            rows = Layout(major_to_minor=tuple(range(k.ndim)))
+            k_d = with_layout_constraint(
+                k_d.reshape(k_d.shape[:2] + k.shape[2:]), rows)
+            v_d = with_layout_constraint(
+                v_d.reshape(v_d.shape[:2] + v.shape[2:]), rows)
         k_pos = jnp.arange(t_view, dtype=jnp.int32)[None, :]
         k_pos = jnp.where(k_pos < lens[:, None] + s, k_pos, -1)
     return {"pages_k": pk, "pages_v": pv}, k_d, v_d, k_pos, None, lens
@@ -204,8 +217,9 @@ def _update_paged_cache(cache, k, v, page_size):
 def gqa_attention(p, cfg, x, *, positions, window=None, cache=None,
                   cache_index=None, page_size=None):
     """GQA attention.  cache: dict(k=[B,T,Hkv,D], v=..., pos=... for ring)
-    updated at cache_index, or a paged-cache view (pages_k/pages_v +
-    page_table/lens, per-slot offsets).  Returns (out, new_cache)."""
+    updated at cache_index, or a paged cache (the stacked pages_k/pages_v
+    pools + page_table/lens and the scan's layer index, per-slot
+    offsets).  Returns (out, new_cache)."""
     from repro.models import layers as L
     b, s, _ = x.shape
     h, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim

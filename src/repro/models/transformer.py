@@ -124,7 +124,7 @@ def _layer_forward(cfg, lp, x, *, window_l, positions, cache_l, cache_index,
 
     attn_cache = None
     if cache_l is not None and "pages_k" in cache_l:
-        attn_cache = cache_l          # paged view incl. page_table/lens
+        attn_cache = cache_l          # stacked pools + page_table/lens/layer
     elif cache_l is not None and "k" in cache_l:
         attn_cache = {k: cache_l[k] for k in ("k", "v", "pos")
                       if k in cache_l}
@@ -262,29 +262,33 @@ def forward(cfg, params, inputs, *, cache=None, mode: str = "train",
         paged_extra = {k: cache[k] for k in
                        ("page_table", "lens", "write_mask") if k in cache}
 
-    # Cache rides the scan CARRY and is updated in place per layer
-    # (dynamic_update_index on the stacked buffers).  The xs/ys
-    # alternative stacks a fresh copy of the whole cache every layer —
+    # Cache rides the scan CARRY and is updated in place per layer.  The
+    # xs/ys alternative stacks a fresh copy of the whole cache every layer —
     # XLA materializes the ys buffer per iteration (+2 × cache bytes of
     # HBM traffic per layer, the dominant decode term; §Perf C3).
-    # The layer's slice and its write-back carry the KV path's device
-    # scopes (``kv_view`` / ``kv_write``), so a profile attributes their
-    # ops to the cache by ``op_name``.
+    # A dense or ring cache takes the layer's slice and writes it back
+    # (dynamic_update_index on the stacked buffers, under the KV scopes
+    # ``kv_view`` / ``kv_write``).  A paged cache hands the stacked pools
+    # and the layer index down instead: the KV path scatters into and
+    # gathers from the pool at layer ``li`` in place (runtime/kv_cache),
+    # so no layer's whole pool is sliced, copied or written back.
     def body(carry, xs):
         x, aux, cl = carry
         lp, w_l, li = xs
         c_l = None
-        if cl is not None:
+        if cl is not None and paged_extra is not None:
+            c_l = {**cl, **paged_extra, "layer": li}
+        elif cl is not None:
             with jax.named_scope("kv_view"):
                 c_l = jax.tree.map(lambda buf: jax.lax.dynamic_index_in_dim(
                     buf, li, 0, keepdims=False), cl)
-        if c_l is not None and paged_extra is not None:
-            c_l = {**c_l, **paged_extra}
         x, new_c, a = _layer_forward(
             cfg, lp, x, window_l=w_l, positions=positions, cache_l=c_l,
             cache_index=cache_index, mode=mode, shard_fn=shard,
             page_size=page_size)
-        if new_c is not None:
+        if new_c is not None and paged_extra is not None:
+            cl = new_c
+        elif new_c is not None:
             with jax.named_scope("kv_write"):
                 cl = jax.tree.map(
                     lambda buf, new: jax.lax.dynamic_update_index_in_dim(

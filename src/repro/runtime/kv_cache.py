@@ -7,7 +7,8 @@ would force the refill path to re-zero (or worse, re-allocate) a full
 row per admitted request.  Instead the cache is paged, vLLM-style:
 
   * the device holds one physical pool per cached tensor,
-    ``[layers, num_pages, page_size, *feat]``;
+    ``[layers, num_pages, page_size, *feat]``, which the serving steps
+    read and write in place at the layer scan's index;
   * each slot owns an ordered list of page ids — its *page table* row —
     mapping logical token position ``p`` to physical location
     ``(table[p // page_size], p % page_size)``;
@@ -54,6 +55,7 @@ import numpy as np
 from repro.runtime import faults as _faults
 
 PAGE_FREE = -1
+_LANES = 128          # a TPU vector register's lanes: the minor-dim tile
 
 
 class OutOfPagesError(RuntimeError):
@@ -84,6 +86,16 @@ def leaf_specs_for(cfg) -> dict:
     and SSM state are per-slot *constant-size* state with no paging win,
     and MLA's latent cache is a straightforward extension left until an
     MLA arch enters the serving matrix.
+
+    The feature dims are the pool's minor dims, and a TPU keeps an array
+    row-major only when its minor dim fills whole 128-lane tiles.  With
+    such heads (128, 256, ...) a token's K (or V) is ``[num_kv_heads,
+    head_dim]``, the shape attention reads.  Narrower heads (stablelm's
+    80) are merged into one row of ``num_kv_heads * head_dim``: kept as
+    ``[..., num_kv_heads, 80]`` the TPU would lay the pool out with the
+    page dim minor, and every in-place scatter and gather would first
+    copy the whole stacked pool to a row-major layout and back.  Either
+    way the flat rows the serving steps index are a free reshape.
     """
     if cfg.attention_kind != "gqa" or cfg.resolved_cache_kind != "full":
         raise NotImplementedError(
@@ -91,48 +103,64 @@ def leaf_specs_for(cfg) -> dict:
             f"attention_kind={cfg.attention_kind!r} / "
             f"cache={cfg.resolved_cache_kind!r}")
     dt = jnp.dtype(cfg.cache_dtype)
-    feat = (cfg.num_kv_heads, cfg.head_dim)
+    hkv, hd = cfg.num_kv_heads, cfg.head_dim
+    feat = (hkv, hd) if hd % _LANES == 0 else (hkv * hd,)
     return {"pages_k": (feat, dt), "pages_v": (feat, dt)}
 
 
 # ------------------------------------------------------- device-side ops
-def paged_gather(pages: jnp.ndarray, page_table: jnp.ndarray,
-                 page_size: int) -> jnp.ndarray:
-    """Dense logical view of a paged pool.
+# Both ops take the stacked pool ``[L, P, page_size, *feat]`` and the
+# layer scan's index, and address the pool as flat rows
+# ``[L·P·page_size, *feat]`` (a merge of major dims only), so the serving
+# steps read and write the pool in place: no layer's pool is sliced out,
+# copied or written back.
+def _layer_rows(pages: jnp.ndarray, page_size: int, layer):
+    """The stacked pool as flat rows, and the first row of ``layer``."""
+    return (pages.reshape(-1, *pages.shape[3:]),
+            layer * (pages.shape[1] * page_size))
 
-    pages: [P, page_size, *feat]; page_table: [B, n_view] int32 physical
-    ids (-1 = unmapped).  Returns [B, n_view * page_size, *feat]; the
-    contents of unmapped pages are arbitrary (physical page 0) — callers
-    mask them by position, exactly as the dense cache masks its
-    zero-initialized tail.  Its ops carry the device scope ``kv_view``.
+
+def paged_gather(pages: jnp.ndarray, page_table: jnp.ndarray,
+                 page_size: int, layer) -> jnp.ndarray:
+    """Dense logical view of layer ``layer`` of a paged pool.
+
+    pages: the stacked pool [L, P, page_size, *feat]; layer: int32
+    scalar (traced in the layer scan); page_table: [B, n_view] int32
+    physical ids (-1 = unmapped).  Returns [B, n_view * page_size, *feat],
+    gathered straight from ``pages``; the contents of unmapped pages are
+    arbitrary (physical page 0) — callers mask them by position, exactly
+    as the dense cache masks its zero-initialized tail.  Its ops carry
+    the device scope ``kv_view``.
     """
-    p_phys = pages.shape[0]
-    feat = pages.shape[2:]
     b, n_view = page_table.shape
     with jax.named_scope("kv_view"):
-        flat = pages.reshape(p_phys * page_size, *feat)
-        base = jnp.where(page_table >= 0, page_table, 0) * page_size
+        flat, row0 = _layer_rows(pages, page_size, layer)
+        base = row0 + jnp.where(page_table >= 0, page_table, 0) * page_size
         idx = base[:, :, None] + jnp.arange(page_size)[None, None, :]
         return flat[idx.reshape(b, n_view * page_size)]
 
 
 def paged_update(pages: jnp.ndarray, new: jnp.ndarray,
                  page_table: jnp.ndarray, lens: jnp.ndarray,
-                 page_size: int, write_mask=None) -> jnp.ndarray:
-    """Scatter ``new[b, i]`` to logical position ``lens[b] + i`` of slot b.
+                 page_size: int, layer, write_mask=None) -> jnp.ndarray:
+    """Scatter ``new[b, i]`` to logical position ``lens[b] + i`` of slot b,
+    in layer ``layer`` of the pool.
 
-    pages: [P, page_size, *feat]; new: [B, s, *feat]; lens: [B] int32;
+    pages: the stacked pool [L, P, page_size, *feat]; layer: int32
+    scalar (traced in the layer scan); new: [B, s, ...], a token's
+    ``prod(feat)`` values, cast to the pool's dtype; lens: [B] int32;
     write_mask: optional [B] bool — rows with False (slots that are
     admitted but not decoding this step, or idle) write nothing.  Writes
     through unmapped table entries (-1) or past the table end are
     dropped, so chunk padding rows and masked slots can never touch a
-    freed or foreign page.  Its ops carry the device scope ``kv_write``.
+    freed or foreign page.  Returns the pool in its shape; under a
+    donated buffer the scatter updates it in place.  Its ops carry the
+    device scope ``kv_write``.
     """
-    p_phys = pages.shape[0]
-    feat = pages.shape[2:]
     b, s = new.shape[0], new.shape[1]
     n_view = page_table.shape[1]
     with jax.named_scope("kv_write"):
+        flat, row0 = _layer_rows(pages, page_size, layer)
         pos = lens[:, None] + jnp.arange(s)[None, :]          # [B, s]
         page_idx = pos // page_size
         phys = jnp.take_along_axis(page_table,
@@ -140,11 +168,12 @@ def paged_update(pages: jnp.ndarray, new: jnp.ndarray,
         valid = (phys >= 0) & (page_idx < n_view)
         if write_mask is not None:
             valid &= write_mask[:, None]
-        oob = p_phys * page_size                              # drop sentinel
-        flat_idx = jnp.where(valid, phys * page_size + pos % page_size, oob)
-        flat = pages.reshape(p_phys * page_size, *feat)
+        oob = flat.shape[0]                                   # drop sentinel
+        flat_idx = jnp.where(valid, row0 + phys * page_size + pos % page_size,
+                             oob)
         flat = flat.at[flat_idx.reshape(b * s)].set(
-            new.reshape(b * s, *feat).astype(flat.dtype), mode="drop")
+            new.reshape(b * s, *flat.shape[1:]).astype(flat.dtype),
+            mode="drop")
         return flat.reshape(pages.shape)
 
 

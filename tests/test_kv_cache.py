@@ -1,7 +1,11 @@
 """Paged KV cache in isolation: alloc/free/refill round-trips must equal
 a dense [B, max_len] cache on random decode traces (including the wrap
-case where a long-running slot outlives several refilled neighbors), and
-the allocator must never alias or leak a page."""
+case where a long-running slot outlives several refilled neighbors), the
+allocator must never alias or leak a page, and the serving steps' in-place
+update and gather of the stacked pool must equal slicing out a layer,
+updating and gathering it, and writing it back."""
+import dataclasses
+
 import numpy as np
 import pytest
 try:
@@ -9,10 +13,13 @@ try:
 except ImportError:      # dev extra (requirements-dev.txt)
     from _hypothesis_stub import given, settings, st
 
+import jax
 import jax.numpy as jnp
 
+import _kv_guard
 from repro.models import model_zoo
 from repro.runtime import kv_cache as KV
+from repro.runtime.serve_loop import Engine
 
 PAGE = 4
 MAX_LEN = 16
@@ -33,16 +40,15 @@ def _write(pool, slot, n_new, rng, dense):
     pool.alloc(slot, start + n_new)
     vals = rng.standard_normal((1, n_new, *FEAT)).astype(np.float32)
     pool.pages["pages_k"] = KV.paged_update(
-        pool.pages["pages_k"][0], jnp.asarray(vals),
-        pool.table_device([slot]), pool.lens_device([slot]),
-        PAGE)[None]
+        pool.pages["pages_k"], jnp.asarray(vals),
+        pool.table_device([slot]), pool.lens_device([slot]), PAGE, 0)
     dense[slot, start:start + n_new] = vals[0]
     pool.lens[slot] = start + n_new
 
 
 def _check_equal(pool, dense):
-    view = np.asarray(KV.paged_gather(pool.pages["pages_k"][0],
-                                      pool.table_device(), PAGE))
+    view = np.asarray(KV.paged_gather(pool.pages["pages_k"],
+                                      pool.table_device(), PAGE, 0))
     for b in range(pool.num_slots):
         n = int(pool.lens[b])
         np.testing.assert_array_equal(view[b, :n], dense[b, :n])
@@ -113,9 +119,8 @@ def test_write_mask_drops_rows():
     pool.alloc(0, 5)                     # room for the unmasked write
     vals = rng.standard_normal((2, 1, *FEAT)).astype(np.float32)
     pool.pages["pages_k"] = KV.paged_update(
-        pool.pages["pages_k"][0], jnp.asarray(vals), pool.table_device(),
-        pool.lens_device(), PAGE,
-        write_mask=jnp.asarray([True, False]))[None]
+        pool.pages["pages_k"], jnp.asarray(vals), pool.table_device(),
+        pool.lens_device(), PAGE, 0, write_mask=jnp.asarray([True, False]))
     dense[0, 4] = vals[0, 0]             # row 1 masked: writes nothing
     pool.lens[0] += 1
     _check_equal(pool, dense)
@@ -132,8 +137,8 @@ def test_unmapped_writes_dropped():
     # slot 1 has NO pages mapped; its write must vanish, not land in
     # someone else's page
     pool.pages["pages_k"] = KV.paged_update(
-        pool.pages["pages_k"][0], jnp.asarray(vals),
-        pool.table_device([1]), pool.lens_device([1]), PAGE)[None]
+        pool.pages["pages_k"], jnp.asarray(vals),
+        pool.table_device([1]), pool.lens_device([1]), PAGE, 0)
     _check_equal(pool, dense)
 
 
@@ -334,3 +339,167 @@ def test_allocator_invariants_property(ops, num_pages):
         pool.check_no_aliasing()
         for b in range(4):
             assert pool.held(b) >= KV.pages_for(lens[b], PAGE)
+
+
+# ------------------------------------------- the stacked pool, in place
+LAYERS = 3
+
+
+def _sliced_reference(pool, layer, new, table, lens, mask):
+    """Slice layer ``layer`` out of the stacked pool, write ``new`` into
+    it token by token, gather the slots' view, write the layer back: the
+    plain numpy form of what the serving steps did before they indexed
+    the pool in place."""
+    pool = np.array(pool)
+    lay = pool[layer]
+    vals = np.asarray(jnp.asarray(new).astype(pool.dtype))
+    n_view = table.shape[1]
+    for b in range(new.shape[0]):
+        if not mask[b]:
+            continue
+        for i in range(new.shape[1]):
+            pos = int(lens[b]) + i
+            page = pos // PAGE
+            if page < n_view and table[b, page] >= 0:
+                lay[table[b, page], pos % PAGE] = vals[b, i]
+    rows = np.where(table >= 0, table, 0)
+    view = lay[rows].reshape(table.shape[0], n_view * PAGE, *lay.shape[2:])
+    pool[layer] = lay
+    return pool, view
+
+
+@jax.jit
+def _in_place_step(pages, new, table, lens, mask, layer):
+    pages = KV.paged_update(pages, new, table, lens, PAGE, layer,
+                            write_mask=mask)
+    return pages, KV.paged_gather(pages, table, PAGE, layer)
+
+
+def _in_place(pool, layer, new, table, lens, mask):
+    """The serving steps' form: scatter and gather at ``layer`` of the
+    stacked pool (a traced index, as the layer scan hands it down)."""
+    return _in_place_step(pool, jnp.asarray(new), jnp.asarray(table),
+                          jnp.asarray(lens), jnp.asarray(mask),
+                          jnp.int32(layer))
+
+
+@pytest.mark.parametrize("feat", [FEAT, (6,)], ids=["heads", "merged"])
+@pytest.mark.parametrize("seed", range(4))
+def test_in_place_layer_update_matches_slice_and_write_back(seed, feat):
+    """Random alloc/free/refill traces, with prefix pages shared by
+    ``install`` and copied by ``fork``, unmapped (-1) entries, chunk rows
+    past the table end and masked rows: the view and every layer of the
+    pool match the slice-update-gather-write-back reference bit for bit,
+    at layer 0, at the last layer and between."""
+    rng = np.random.default_rng(100 + seed)
+    slots = 3
+    pool = KV.PagedKVCache(
+        num_layers=LAYERS, num_slots=slots, max_len=MAX_LEN, page_size=PAGE,
+        leaf_specs={"pages_k": (feat, jnp.bfloat16)}, num_pages=10)
+    pool.pages["pages_k"] = jnp.asarray(rng.standard_normal(
+        (LAYERS, pool.num_pages, PAGE, *feat)), jnp.bfloat16)
+    seen = set()
+    for step in range(40):
+        op = rng.random()
+        slot = int(rng.integers(slots))
+        if op < 0.15 and pool.held(slot):
+            pool.free(slot)
+            continue
+        if op < 0.3:
+            # share another slot's whole pages into an emptied slot, and
+            # fork its partly written page as this slot's next page
+            src = int(rng.integers(slots))
+            full = int(pool.lens[src]) // PAGE
+            if src != slot and full:
+                pool.free(slot)
+                shared = [int(p) for p in pool.page_table[src, :full]]
+                pool.mark_cached(shared)
+                pool.install(slot, shared)
+                pool.lens[slot] = full * PAGE
+                if pool.lens[src] % PAGE and pool.free_count:
+                    pool.fork(slot, int(pool.page_table[src, full]))
+                    pool.lens[slot] += int(rng.integers(pool.lens[src] % PAGE))
+                continue
+        rows = [slot] if rng.random() < 0.5 else list(range(slots))
+        s = int(rng.integers(1, 6))
+        for r in rows:
+            # cover only part of the chunk now and then: its tail rows
+            # then meet unmapped entries
+            want = min(int(pool.lens[r]) + int(rng.integers(0, s + 1)),
+                       MAX_LEN)
+            try:
+                pool.alloc(r, want)
+            except KV.OutOfPagesError:
+                pass
+        table, lens = pool.page_table[rows].copy(), pool.lens[rows].copy()
+        mask = rng.random(len(rows)) < 0.75
+        layer = (0, LAYERS - 1)[step % 2] if step < 4 else int(
+            rng.integers(LAYERS))
+        seen.add(layer)
+        new = rng.standard_normal((len(rows), s, *feat)).astype(np.float32)
+        want_pool, want_view = _sliced_reference(
+            pool.pages["pages_k"], layer, new, table, lens, mask)
+        got_pool, got_view = _in_place(pool.pages["pages_k"], layer, new,
+                                       table, lens, mask)
+        np.testing.assert_array_equal(np.asarray(got_view), want_view)
+        np.testing.assert_array_equal(np.asarray(got_pool), want_pool)
+        pool.pages["pages_k"] = got_pool
+        for r, m in zip(rows, mask):
+            if m:
+                pool.lens[r] = min(int(pool.lens[r]) + s,
+                                   pool.held(r) * PAGE)
+        pool.check_no_aliasing()
+    assert {0, LAYERS - 1} <= seen
+
+
+def test_pool_rows_merge_heads_narrower_than_a_lane_tile():
+    cfg = model_zoo.reduced_config(model_zoo.get_config("stablelm-3b"))
+    (feat, _), = {KV.leaf_specs_for(cfg)["pages_k"]}
+    assert feat == (cfg.num_kv_heads * cfg.head_dim,)
+    wide = dataclasses.replace(cfg, head_dim=128)
+    assert KV.leaf_specs_for(wide)["pages_v"][0] == (cfg.num_kv_heads, 128)
+
+
+# ---------------------------------------------------------- HLO guard
+_GUARD_HLO = """
+  %pool = bf16[2,12,8,32]{3,2,1,0} parameter(0)
+  %flat = bf16[192,32]{1,0} bitcast(%pool)
+  %scatter.1 = bf16[192,32]{1,0} scatter(%flat, %i, %u), metadata={op_name="jit(_paged_prefill)/while/body/kv_write/scatter"}
+  %gather.1 = bf16[96,32]{1,0} gather(%scatter.1, %j), metadata={op_name="jit(_paged_decode)/while/body/kv_view/gather"}
+  %copy.1 = bf16[96,32]{0,1} copy(%gather.1), metadata={op_name="jit(_paged_decode)/while/body/kv_view/gather"}
+  %pad.1 = bf16[3,64,32]{2,1,0} pad(%copy.1, %z), metadata={op_name="jit(_paged_decode)/while/body/kv_view/jit(_pad)/pad"}
+  %copy.2 = bf16[96,32]{0,1} copy(%flat), metadata={op_name="jit(_paged_decode)/while/body/kv_view/gather"}
+  %copy.3 = bf16[2,12,8,32]{0,3,2,1} copy(%pool)
+  %dynamic-slice.1 = bf16[1,12,8,32]{3,2,1,0} dynamic-slice(%pool, %l), metadata={op_name="jit(_paged_prefill)/while/body/kv_view/dynamic_slice"}
+  %fusion.1 = bf16[2,12,8,32]{3,2,1,0} fusion(%pool, %d), kind=kLoop, metadata={op_name="jit(_paged_prefill)/while/body/kv_write/dynamic_update_slice"}
+  %fusion.2 = bf16[1,12,8,32]{3,2,1,0} fusion(%w), kind=kLoop, metadata={op_name="jit(_paged_prefill)/while/body/ffn/dot_general"}
+  %copy.4 = bf16[8,32]{1,0} copy(%flat)
+"""
+
+
+def test_the_guard_flags_pool_copies_and_passes_the_scatter_and_view():
+    found = _kv_guard.pool_copies(_GUARD_HLO, 12 * 8 * 32)
+    bad = {b.split()[0] for b in found}
+    # a copy of the pool posing as the view's relayout, a layout copy of
+    # the stacked pool, a layer's slice and its write-back
+    assert bad == {"copy.2", "copy.3", "dynamic-slice.1", "fusion.1"}
+
+
+@pytest.mark.parametrize("head_dim", [16, 128])
+def test_paged_steps_compile_without_pool_copies(head_dim):
+    """The compiled prefill-chunk, decode and megastep steps of a small
+    GQA model move no array of a layer pool's size but the in-place
+    scatter (and the views, which are smaller here: the pool has spare
+    pages)."""
+    cfg = dataclasses.replace(
+        model_zoo.reduced_config(model_zoo.get_config("stablelm-3b")),
+        head_dim=head_dim)
+    eng = Engine(cfg, model_zoo.build(cfg), max_len=32, packed=False)
+    num_pages = 12                  # 2 slots x 4 pages, and spares
+    texts = _kv_guard.step_texts(eng, slots=2, chunk=8, page_size=8,
+                                 num_pages=num_pages)
+    layer = _kv_guard.layer_pool_elements(eng, num_pages=num_pages,
+                                          page_size=8)
+    for name, text in texts.items():
+        assert "scatter" in text and "gather" in text, name
+        assert _kv_guard.pool_copies(text, layer) == [], name

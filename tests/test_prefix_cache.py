@@ -159,14 +159,14 @@ def test_fork_copies_page_contents():
     vals = rng.standard_normal((1, 8, *FEAT)).astype(np.float32)
     pool.alloc(0, 8)
     pool.pages["pages_k"] = KV.paged_update(
-        pool.pages["pages_k"][0], jnp.asarray(vals),
-        pool.table_device([0]), pool.lens_device([0]), PAGE)[None]
+        pool.pages["pages_k"], jnp.asarray(vals),
+        pool.table_device([0]), pool.lens_device([0]), PAGE, 0)
     pool.lens[0] = 8
     cache.insert(0, _prompt(1, 2, 3, 4, 5, 6, 7, 8))
     pool.free(0)
     cache.admit(1, _prompt(1, 2, 3, 4, 5, 6, 7, 99, 98))
     view = np.asarray(KV.paged_gather(
-        pool.pages["pages_k"][0], pool.table_device([1]), PAGE))
+        pool.pages["pages_k"], pool.table_device([1]), PAGE, 0))
     # shared page verbatim + the forked page's reused head
     np.testing.assert_array_equal(view[0, :7], vals[0, :7])
 

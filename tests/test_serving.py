@@ -8,6 +8,8 @@ scheduler invariants (slot exclusivity, exactly-once completion, FIFO
 admission, no freed-page aliasing) and the plans-stay-hot property
 (``plan_cache_info().misses`` flat after the first refill cycle).
 """
+import dataclasses
+
 import numpy as np
 import pytest
 try:
@@ -102,6 +104,22 @@ def test_parity_softcap_window_arch():
     cfg = model_zoo.reduced_config(model_zoo.get_config("gemma2-9b"))
     eng = Engine(cfg, model_zoo.build(cfg), max_len=MAX_LEN, packed=False)
     reqs = _requests(cfg, [5, 20, 11], seed=1)
+    mns = [4, 6, 3]
+    refs = _refs(eng, reqs, mns)
+    outs, _ = eng.serve(reqs, batch_slots=2, max_new_tokens=mns,
+                        prefill_chunk=CHUNK, page_size=PAGE)
+    for o, r in zip(outs, refs):
+        np.testing.assert_array_equal(o, r)
+
+
+def test_parity_lane_wide_heads():
+    """Heads of 128 keep their own pool dim (kv_cache.leaf_specs_for
+    merges narrower ones into one row): the same gate on that layout."""
+    cfg = dataclasses.replace(
+        model_zoo.reduced_config(model_zoo.get_config("stablelm-3b")),
+        head_dim=128)
+    eng = Engine(cfg, model_zoo.build(cfg), max_len=MAX_LEN, packed=False)
+    reqs = _requests(cfg, [5, 20, 11], seed=4)
     mns = [4, 6, 3]
     refs = _refs(eng, reqs, mns)
     outs, _ = eng.serve(reqs, batch_slots=2, max_new_tokens=mns,

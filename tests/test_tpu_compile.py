@@ -5,7 +5,9 @@ block shapes off the (8, 128) tiling, casts it does not lower, VMEM
 over budget.  These tests compile each kernel at stablelm-3b's published
 widths, at the blocks the dispatch policy resolves for them, against a
 described ``v5e:2x2`` topology — no chip needed — and check that the
-compiled program holds the kernel (``tpu_custom_call``).
+compiled program holds the kernel (``tpu_custom_call``).  The paged
+serving steps compile at the benchmark cells' shapes too, and must index
+the KV pool in place.
 
 The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU compiler library, so a
@@ -22,11 +24,13 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+import _kv_guard
 from repro import gemm
 from repro.kernels.flash_attention import flash_attention
 from repro.models import model_zoo
 from repro.quant import formats as F
 from repro.quant import kernels as QK
+from repro.runtime.serve_loop import Engine
 
 ARCH = "stablelm-3b"
 PREFILL_M = 128          # one prefill chunk's rows
@@ -276,3 +280,34 @@ def test_sparse_kernel_carries_its_name(one_chip):
         jax.ShapeDtypeStruct((len(occupied), n), jnp.float32),
         sharding=one_chip)
     assert 'kernel_name = "sparse_quant_panel_gemm"' in text
+
+
+# the serving cells: one pipeline stage of each model at its published
+# widths, 4 slots x 2064 tokens in pages of 16, 128-token prefill chunks
+CELLS = {"stablelm-3b-16l": ("stablelm-3b", 16),
+         "deepseek-7b-8l": ("deepseek-7b", 8)}
+SLOTS, CELL_MAX_LEN, CELL_PAGE = 4, 2064, 16
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_paged_steps_index_the_pool_in_place(one_chip, cell):
+    """The compiled prefill-chunk, decode and megastep steps slice, copy
+    and write back no layer's pool: the one instruction of a layer pool's
+    size besides the slots' view is the scatter into the donated pool.
+    Here the pool holds exactly the slots' 4 x 2064 tokens, so a decode
+    step's view has a layer pool's elements."""
+    arch, layers = CELLS[cell]
+    cfg = dataclasses.replace(model_zoo.get_config(arch), num_layers=layers)
+    packed = _on_chip(jax.eval_shape(
+        lambda p: model_zoo.pack_for_inference(cfg, p),
+        model_zoo.abstract_params(cfg)), one_chip)
+    eng = Engine(cfg, packed, max_len=CELL_MAX_LEN, packed=True)
+    num_pages = SLOTS * CELL_MAX_LEN // CELL_PAGE
+    texts = _kv_guard.step_texts(eng, slots=SLOTS, chunk=PREFILL_M,
+                                 page_size=CELL_PAGE, num_pages=num_pages,
+                                 sharding=one_chip)
+    layer = _kv_guard.layer_pool_elements(eng, num_pages=num_pages,
+                                          page_size=CELL_PAGE)
+    for name, text in texts.items():
+        assert "kv_write/scatter" in text, name
+        assert _kv_guard.pool_copies(text, layer) == [], name
