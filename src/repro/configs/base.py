@@ -40,11 +40,25 @@ class ModelConfig:
     qk_rope_dim: int = 0
     qk_nope_dim: int = 0
     v_head_dim: int = 0
+    kv_latent_norm: bool = False     # RMSNorm on the latent before caching
+    # YaRN rope (yarn_factor 0 = plain rope)
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+    first_k_dense: int = 0           # leading dense-FFN layers (d_ff wide)
+                                     # before the scan of MoE layers
     # MoE
     num_experts: int = 0
     num_shared_experts: int = 0
     experts_per_token: int = 0
     moe_d_ff: int = 0
+    norm_topk_prob: bool = True      # renormalise the top-k weights
+    routed_scaling: float = 1.0      # times the top-k weights
+    held_experts: tuple = ()         # (lo, hi): the routed experts this
+                                     # chip holds; () = all of them
     capacity_factor: float = 1.25
     moe_dispatch_groups: int = 0     # 0/1 = global cumsum; launchers set
                                      # this to the batch-shard count so
@@ -84,6 +98,11 @@ class ModelConfig:
         return _DTYPES[self.compute_dtype]
 
     @property
+    def held_range(self) -> tuple[int, int]:
+        """(lo, hi) of the routed experts this chip holds."""
+        return tuple(self.held_experts) or (0, self.num_experts)
+
+    @property
     def is_windowed_only(self) -> bool:
         """True iff every attention layer is windowed (ring cache legal)."""
         return (self.window is not None and self.local_global_period == 0
@@ -108,8 +127,9 @@ class ModelConfig:
             per += d * self.num_heads * self.head_dim * 2  # wq, wo
             per += d * self.num_kv_heads * self.head_dim * 2
         if self.attention_kind == "mla":
+            q_in = self.q_lora_rank or d
             per += d * self.q_lora_rank
-            per += self.q_lora_rank * self.num_heads * (
+            per += q_in * self.num_heads * (
                 self.qk_nope_dim + self.qk_rope_dim)
             per += d * (self.kv_lora_rank + self.qk_rope_dim)
             per += self.kv_lora_rank * self.num_heads * (
@@ -120,20 +140,26 @@ class ModelConfig:
             gn = self.ssm_groups * self.ssm_state
             per += d * (2 * d_in + 2 * gn + self.ssm_heads)
             per += d_in * d + d_in
-        if self.family == "moe":
-            per += d * self.num_experts                # router
-            per += self.num_experts * d * self.moe_d_ff * 3
-            per += self.num_shared_experts * d * self.moe_d_ff * 3
-        elif self.d_ff:
-            per += d * self.d_ff * 3
-        return n + lyr * per
+        if self.kv_latent_norm:
+            per += self.kv_lora_rank
+        dense_ffn = d * self.d_ff * 3 if self.d_ff else 0
+        if self.family != "moe":
+            return n + lyr * (per + dense_ffn)
+        lo, hi = self.held_range
+        moe = d * self.num_experts                     # router
+        moe += (hi - lo) * d * self.moe_d_ff * 3
+        moe += self.num_shared_experts * d * self.moe_d_ff * 3
+        k = self.first_k_dense
+        return n + lyr * per + k * dense_ffn + (lyr - k) * moe
 
     def active_param_count(self) -> int:
         """Params touched per token (MoE: only routed top-k experts)."""
         if self.family != "moe":
             return self.param_count()
-        inactive = (self.num_experts - self.experts_per_token) \
-            * self.d_model * self.moe_d_ff * 3 * self.num_layers
+        lo, hi = self.held_range
+        inactive = (hi - lo - min(self.experts_per_token, hi - lo)) \
+            * self.d_model * self.moe_d_ff * 3 \
+            * (self.num_layers - self.first_k_dense)
         return self.param_count() - inactive
 
 

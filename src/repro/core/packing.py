@@ -44,6 +44,11 @@ class PackedWeight:
     split map — the parts' LOGICAL widths, in order; for a fused pack
     ``n`` is the kernel-visible concatenated width (interior zero padding
     included).  ``n_splits == ()`` marks an ordinary single-weight pack.
+
+    A *grouped* pack (``pack_grouped``, ``groups > 0``) holds a bank of
+    ``groups`` same-shape weights as data ``[groups, K_pad, N_pad]``
+    (MoE experts), each packed as above; the grouped GEMM arm runs it
+    over row groups of varying size.
     """
     data: jax.Array
     n: int = dataclasses.field(metadata=dict(static=True))
@@ -52,6 +57,7 @@ class PackedWeight:
     block_k: int = dataclasses.field(metadata=dict(static=True))
     n_splits: tuple = dataclasses.field(default=(),
                                         metadata=dict(static=True))
+    groups: int = dataclasses.field(default=0, metadata=dict(static=True))
 
     @property
     def shape(self):  # logical shape (fused: padded-concat width)
@@ -209,6 +215,30 @@ def _pack_fused_fp32(parts, *, transposed, block_n, block_k, dtype,
         data = jax.device_put(data, sharding)
     return PackedWeight(data=data, n=int(data.shape[-1]), k=k,
                         block_n=bn, block_k=block_k, n_splits=n_splits)
+
+
+def pack_grouped(parts, *, block_n: int = _kernel.DEFAULT_BLOCK_N,
+                 block_k: int = _kernel.DEFAULT_BLOCK_K) -> PackedWeight:
+    """Pack a bank of weights ``[..., G, K, N]`` once at load for the
+    grouped GEMM arm: one part, or several same-K parts fused along N as
+    ``pack_fused`` fuses them (an expert's gate and up for the glu
+    epilogue).  Leading dims (the scan's layers) ride through; blocks fit
+    the parts' widths (``fit_block``) so the bank is padded as little as
+    the panel grid allows."""
+    parts = list(parts)
+    with _spans.span("pack_grouped", parts=len(parts)):
+        if len(parts) > 1:
+            pw = _pack_fused_fp32(parts, transposed=False, block_n=block_n,
+                                  block_k=block_k, dtype=None,
+                                  sharding=None)
+        else:
+            w = parts[0]
+            k, n = w.shape[-2:]
+            bk, bn = fit_block(k, block_k), fit_block(n, block_n)
+            pads = [(0, 0)] * (w.ndim - 2) + [(0, (-k) % bk), (0, (-n) % bn)]
+            pw = PackedWeight(data=jnp.pad(w, pads), n=n, k=k, block_n=bn,
+                              block_k=bk)
+        return dataclasses.replace(pw, groups=int(pw.data.shape[-3]))
 
 
 def pack_percall(w: jax.Array, *, transposed: bool, block_n: int,

@@ -45,12 +45,16 @@ class Backend:
     block_k, out_dtype, [epilogue kwargs])`` — dispatched by execute()
     only when the plan's ``weight_format`` is quantized.  A backend
     without it rejects quantized plans (registered extensions predating
-    the quant subsystem keep working for fp32 plans unchanged)."""
+    the quant subsystem keep working for fp32 plans unchanged).
+    ``run_grouped`` (optional) runs grouped plans —
+    ``run_grouped(x, bank [G, K_pad, N_pad], group_sizes [G], *,
+    out_dtype, epilogue)`` over rows sorted by group."""
     name: str
     run: RunFn
     needs_blocks: bool = True    # False: shape-agnostic, skip block padding
     description: str = ""
     run_quant: RunFn | None = None
+    run_grouped: RunFn | None = None
 
 
 _REGISTRY: dict[str, Backend] = {}
@@ -65,6 +69,7 @@ class UnknownBackendError(KeyError):
 def register_backend(name: str, run: RunFn, *, needs_blocks: bool = True,
                      description: str = "",
                      run_quant: RunFn | None = None,
+                     run_grouped: RunFn | None = None,
                      overwrite: bool = False) -> Backend:
     """Register a GEMM backend under ``name`` (the extension hook)."""
     with _LOCK:
@@ -72,7 +77,8 @@ def register_backend(name: str, run: RunFn, *, needs_blocks: bool = True,
             raise ValueError(f"backend {name!r} already registered; "
                              f"pass overwrite=True to replace it")
         b = Backend(name=name, run=run, needs_blocks=needs_blocks,
-                    description=description, run_quant=run_quant)
+                    description=description, run_quant=run_quant,
+                    run_grouped=run_grouped)
         _REGISTRY[name] = b
         return b
 
@@ -293,9 +299,28 @@ def _run_quant_interpret(x_p, codes, scales, **kw):
     return _run_quant_pallas(x_p, codes, scales, interpret=True, **kw)
 
 
+# Grouped run: the held experts of a MoE layer as one grouped GEMM.
+def _run_grouped_xla(x, bank, group_sizes, *, out_dtype, epilogue=None):
+    """XLA's ragged dot: rows sorted by group, ``group_sizes[g]`` of them
+    through ``bank[g]``, fp32 accumulate at the default precision (the
+    activations promote to the bank's dtype, as ``jnp.dot`` promotes
+    them in ``_run_xla``); rows past the groups' sum come back zero.
+    ``ragged_dot`` leaves those rows unspecified (the TPU's kernel need
+    not write them), so they are selected away here, in the fusion of
+    the epilogue (the experts' glu), which runs on the fp32 result."""
+    group_sizes = group_sizes.astype(jnp.int32)
+    acc = jax.lax.ragged_dot(x.astype(bank.dtype), bank, group_sizes,
+                             preferred_element_type=jnp.float32)
+    live = jnp.arange(acc.shape[0])[:, None] < jnp.sum(group_sizes)
+    acc = jnp.where(live, acc, 0.0)
+    if epilogue is not None:
+        acc = _kernel.apply_epilogue(acc, epilogue)
+    return acc.astype(out_dtype or x.dtype)
+
+
 register_backend("xla", _run_xla, needs_blocks=False,
                  description="shape-agnostic XLA dot (Accelerate analogue)",
-                 run_quant=_run_quant_xla)
+                 run_quant=_run_quant_xla, run_grouped=_run_grouped_xla)
 register_backend("pallas", _run_pallas,
                  description="compiled Pallas panel kernel (TPU deploy)",
                  run_quant=_run_quant_pallas)

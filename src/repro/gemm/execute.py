@@ -65,7 +65,7 @@ def _pad_cols(x: jax.Array, to: int) -> jax.Array:
 
 
 def execute(p: GemmPlan, x: jax.Array, w, *, bias=None, residual=None,
-            out_dtype=None) -> jax.Array:
+            out_dtype=None, group_sizes=None) -> jax.Array:
     """y[..., N_out] = epilogue(x[..., K] @ w), dispatched per ``p``.
 
     Observability (repro.obs): when a flight recorder or manifest scope
@@ -91,14 +91,23 @@ def execute(p: GemmPlan, x: jax.Array, w, *, bias=None, residual=None,
     concatenated output — slice per part with :func:`split_fused` — except
     under a glu epilogue, where the halves are combined in the store step
     and only the single ``p.n_out``-wide result comes back.
+
+    A grouped plan (``p.groups``) runs a grouped pack over rows sorted by
+    group: ``group_sizes`` [groups] int32 says how many of the leading
+    rows each group's weight takes, in order; rows past their sum come
+    back as zeros.
     """
+    if p.groups:
+        run = _execute_grouped
+        kw = {"group_sizes": group_sizes}
+    else:
+        run = _execute_impl
+        kw = {"bias": bias, "residual": residual}
     if not _flight._HOT:
-        return _execute_impl(p, x, w, bias=bias, residual=residual,
-                             out_dtype=out_dtype)
+        return run(p, x, w, out_dtype=out_dtype, **kw)
     rec = _flight.active_recorder()
     t0 = time.perf_counter()
-    y = _execute_impl(p, x, w, bias=bias, residual=residual,
-                      out_dtype=out_dtype)
+    y = run(p, x, w, out_dtype=out_dtype, **kw)
     if isinstance(y, jax.core.Tracer):
         # jit-trace time: no wall clock exists per dispatch — register
         # the plan into the open manifest scope instead (once per
@@ -246,6 +255,33 @@ def _execute_impl(p: GemmPlan, x: jax.Array, w, *, bias=None,
         y = backend.run(x2, w_p, block_m=p.block_m, block_n=p.block_n,
                         block_k=p.block_k, out_dtype=out_dtype, **epi_kw)
     return y[:m, :p.n_out].reshape(*lead, p.n_out)
+
+
+def _execute_grouped(p: GemmPlan, x: jax.Array, w, *, group_sizes,
+                     out_dtype=None) -> jax.Array:
+    backend = _backends.get_backend(p.backend)
+    _check(isinstance(w, packing.PackedWeight) and w.groups == p.groups,
+           f"grouped plan needs a grouped pack of {p.groups} "
+           f"({p.describe()})")
+    _check((w.k, w.n, w.block_n, w.block_k, w.n_splits)
+           == (p.k, p.n, p.block_n, p.block_k, p.fused_n_splits),
+           f"grouped pack {w.shape} vs plan {p.describe()}")
+    _check(group_sizes is not None
+           and tuple(group_sizes.shape) == (p.groups,),
+           f"grouped plan needs group_sizes [{p.groups}]")
+    _check(backend.run_grouped is not None,
+           f"backend {p.backend!r} has no grouped run "
+           f"(register_backend(..., run_grouped=))")
+    lead = x.shape[:-1]
+    x2 = x.reshape(-1, p.k)
+    m = x2.shape[0]
+    _check(m == p.m, f"operand M={m} vs plan M={p.m}")
+    k_pad = w.data.shape[-2]
+    if k_pad != p.k:
+        x2 = _pad_cols(x2, k_pad)
+    y = backend.run_grouped(x2, w.data, group_sizes, out_dtype=out_dtype,
+                            epilogue=p.epilogue)
+    return y[:, :p.n_out].reshape(*lead, p.n_out)
 
 
 def split_fused(p: GemmPlan, y: jax.Array) -> tuple:

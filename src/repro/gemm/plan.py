@@ -76,6 +76,12 @@ class GemmPlan:
     plan for one weight shares one slice map and ``serve`` stays
     bit-identical to per-request ``generate``.
 
+    Grouped field: ``groups > 0`` marks a plan of the grouped arm — a
+    bank of ``groups`` packed weights (``PackedWeight.groups``) applied
+    to ``m`` rows sorted into groups whose sizes arrive per call
+    (``execute(..., group_sizes=)``): the held experts of a MoE layer.
+    Plan-keyed with the groups, K, N and the row bucket ``m``.
+
     Sparse-ternary field: ``density_bucket`` is ``-1`` on the dense arm
     and the pack's zero-group-fraction decile (0..9, see
     ``quant.density_bucket_of``) on a plan resolved for a
@@ -106,6 +112,7 @@ class GemmPlan:
     split_k: int = 1
     decode: bool = False
     density_bucket: int = -1
+    groups: int = 0
 
     # ----------------------------------------------------------- geometry
     @property
@@ -179,6 +186,8 @@ class GemmPlan:
             epi += f", split_k={self.split_k}"
         if self.vmem_clamped:
             epi += ", vmem_clamped"
+        if self.groups:
+            epi += f", groups={self.groups}"
         return (f"GemmPlan[{self.m}x{self.n}x{self.k} {self.dtype} "
                 f"-> {self.backend}, blocks=({self.block_m},{self.block_n},"
                 f"{self.block_k}), lever={self.lever}, pack={self.pack}"

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import dataclasses
 import threading
 import warnings
 from typing import Any, Iterator
@@ -69,6 +70,7 @@ CacheInfo = collections.namedtuple(
 
 _cache: "collections.OrderedDict[tuple, GemmPlan]" = collections.OrderedDict()
 _cache_lock = threading.Lock()
+_grouped: dict = {}      # (groups, base plan) -> grouped-arm plan
 _hits = 0
 _misses = 0
 # per-key in-flight resolutions (bugfix: two threads missing on one key
@@ -99,6 +101,7 @@ def plan_cache_clear() -> None:
     global _hits, _misses
     with _cache_lock:
         _cache.clear()
+        _grouped.clear()
         _hits = _misses = 0
         _vmem_warned.clear()
 
@@ -750,6 +753,21 @@ def plan_for_packed(m: int, pw: packing.PackedWeight, *,
     the plan, selecting the sparse arm."""
     fmt = getattr(pw, "fmt", "fp32")
     dtype = "float32" if fmt != "fp32" else pw.dtype
+    if getattr(pw, "groups", 0):
+        # the grouped arm: the bank's blocks and the row bucket resolve as
+        # for one weight of the bank, and the plan carries the groups
+        base = plan(m, pw.n, pw.k, dtype=dtype, backend=backend,
+                    num_cores=num_cores, block_n=pw.block_n,
+                    block_k=pw.block_k, pack=PACK_PREPACKED,
+                    sharding=_packed_sharding(pw), epilogue=epilogue,
+                    fused_n_splits=pw.n_splits, decode=decode)
+        key = (pw.groups, base)
+        with _cache_lock:
+            p = _grouped.get(key)
+            if p is None:
+                p = _grouped[key] = dataclasses.replace(base,
+                                                        groups=pw.groups)
+        return p
     return plan(m, pw.n, pw.k, dtype=dtype, backend=backend,
                 num_cores=num_cores, block_n=pw.block_n,
                 block_k=pw.block_k, pack=PACK_PREPACKED, validate=validate,

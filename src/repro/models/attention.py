@@ -258,53 +258,103 @@ def gqa_attention(p, cfg, x, *, positions, window=None, cache=None,
 
 # --------------------------------------------------------------------- MLA
 def mla_params(key, cfg, dtype):
-    """DeepSeek-V3 Multi-head Latent Attention weights (absorbed layout)."""
+    """Multi-head Latent Attention weights: W_q (or DeepSeek-V3's q-LoRA
+    pair w_dq/w_uq), the latent's down-projection w_dkv and rope key
+    w_kr (together the release's W_kv_a), the latent's up-projections
+    w_uk/w_uv (together W_kv_b, per head), W_o, and the latent's RMSNorm
+    scale when ``cfg.kv_latent_norm``."""
     from repro.models import layers as L
     ks = jax.random.split(key, 8)
     d, h = cfg.d_model, cfg.num_heads
     nope, rope_d, vd = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-    return {
-        "w_dq": L.init_dense(ks[0], (d, cfg.q_lora_rank), dtype=dtype),
-        "w_uq": L.init_dense(ks[1], (cfg.q_lora_rank, h * (nope + rope_d)),
-                             dtype=dtype),
+    if cfg.q_lora_rank:
+        p = {"w_dq": L.init_dense(ks[0], (d, cfg.q_lora_rank), dtype=dtype),
+             "w_uq": L.init_dense(ks[1], (cfg.q_lora_rank,
+                                          h * (nope + rope_d)), dtype=dtype)}
+    else:
+        p = {"wq": L.init_dense(ks[0], (d, h * (nope + rope_d)),
+                                dtype=dtype)}
+    p.update({
         "w_dkv": L.init_dense(ks[2], (d, cfg.kv_lora_rank), dtype=dtype),
         "w_kr": L.init_dense(ks[3], (d, rope_d), dtype=dtype),
         "w_uk": L.init_dense(ks[4], (cfg.kv_lora_rank, h * nope),
                              dtype=dtype),
         "w_uv": L.init_dense(ks[5], (cfg.kv_lora_rank, h * vd), dtype=dtype),
         "wo": L.init_dense(ks[6], (h * vd, d), dtype=dtype),
-    }
+    })
+    if cfg.kv_latent_norm:
+        p["kv_norm"] = jnp.ones((cfg.kv_lora_rank,), dtype)
+    return p
 
 
-def mla_attention(p, cfg, x, *, positions, cache=None, cache_index=None,
-                  window=None):
-    """Absorbed-form MLA: attention runs as MQA over the compressed latent
-    (kv_lora_rank + rope_dim per token) — the cache stores ONLY the latent,
-    never expanded K/V.  q_nope is absorbed through W_UK; values are read
-    as latent context then expanded through W_UV.  (window unused; MLA
-    archs here are full-attention.)"""
+def mla_scale(cfg) -> float:
+    """Softmax scale over the (nope + rope)-wide query/key dot; YaRN
+    multiplies it by mscale(factor, mscale_all_dim)^2."""
+    from repro.models import layers as L
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        scale *= L.yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_project(p, cfg, x, positions):
+    """The block's projections of x: (q_nope [B,S,H,nope], q_pe
+    [B,S,H,rope] roped, the latent c_kv [B,S,r] (normed when the config
+    says so), k_pe [B,S,rope] roped)."""
     from repro.models import layers as L
     b, s, _ = x.shape
-    h = cfg.num_heads
-    nope, rope_d, vd = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-    r = cfg.kv_lora_rank
-
+    h, nope, rope_d = cfg.num_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
     if "w_dqkr" in p:
         # fused down-projections: q-latent, kv-latent, and k-rope all
         # consume x — one pass instead of three x reads
         cq, ckv, kr = L.fused_linear(x, p["w_dqkr"])
+        q = L.linear(cq, p["w_uq"])
+    elif "w_qkr" in p:
+        # no q-LoRA: W_q and W_kv_a both read x, one fused pass
+        q, ckv, kr = L.fused_linear(x, p["w_qkr"])
     else:
-        cq = L.linear(x, p["w_dq"])
         ckv = L.linear(x, p["w_dkv"])                      # [B,S,r]
         kr = L.linear(x, p["w_kr"])
-    q = L.linear(cq, p["w_uq"]).reshape(b, s, h, nope + rope_d)
-    q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = L.rope(q_rope, positions, cfg.rope_theta)
+        q = (L.linear(x, p["wq"]) if "wq" in p
+             else L.linear(L.linear(x, p["w_dq"]), p["w_uq"]))
+    q = q.reshape(b, s, h, nope + rope_d)
+    if "kv_norm" in p:
+        ckv = L.rms_norm(ckv, p["kv_norm"], cfg.norm_eps)
+    freq = L.rope_freqs(cfg, rope_d)
+    q_pe = L.rope(q[..., nope:], positions, cfg.rope_theta, freq)
+    kpe = L.rope(kr[:, :, None, :], positions, cfg.rope_theta,
+                 freq)[:, :, 0]                            # [B,S,rope_d]
+    return q[..., :nope], q_pe, ckv, kpe
 
-    krope = L.rope(kr[:, :, None, :], positions,
-                   cfg.rope_theta)[:, :, 0]                # [B,S,rope_d]
+
+def mla_attention(p, cfg, x, *, positions, cache=None, cache_index=None,
+                  window=None, page_size=None, mode="prefill"):
+    """Multi-head Latent Attention.  The cache stores only the latent
+    (kv_lora_rank + rope_dim per token), never expanded K/V.
+
+    A dense ``ckv``/``krope`` cache (or none) runs the absorbed form: MQA
+    over the latent, q_nope absorbed through W_UK, the latent context
+    expanded through W_UV.  A paged cache (``pages_lat``, the stacked
+    latent pool of runtime/kv_cache, with the scan's layer index) is
+    written with this step's latents and read block by block up to the
+    live length (``_mla_paged``).  (window unused; MLA archs here are
+    full-attention.)"""
+    from repro.models import layers as L
+    b, s, _ = x.shape
+    h = cfg.num_heads
+    vd = cfg.v_head_dim
+    r = cfg.kv_lora_rank
+    q_nope, q_pe, ckv, krope = _mla_project(p, cfg, x, positions)
+
+    if cache is not None and "pages_lat" in cache:
+        out, new_cache = _mla_paged(p, cfg, q_nope, q_pe, ckv, krope, cache,
+                                    page_size=page_size,
+                                    absorbed=(mode == "decode"
+                                              or MLA_PREFILL_ABSORBED))
+        return L.linear(out.reshape(b, s, h * vd), p["wo"]), new_cache
 
     # absorb: q_abs[b,s,h,r] = q_nope . W_UK(per head)
+    nope = cfg.qk_nope_dim
     w_uk = p["w_uk"].reshape(r, h, nope)
     dt = L.dot_dtype(x.dtype)
     q_abs = jnp.einsum("bshn,rhn->bshr", q_nope.astype(dt),
@@ -327,15 +377,145 @@ def mla_attention(p, cfg, x, *, positions, cache=None, cache_index=None,
         kv_len, q_offset = s, 0
 
     # MQA over latent: kv head = 1, key dim = r + rope_d, value = latent (r)
-    q_full = jnp.concatenate([q_abs, q_rope], axis=-1)      # [B,S,H,r+rd]
+    q_full = jnp.concatenate([q_abs, q_pe], axis=-1)        # [B,S,H,r+rd]
     k_full = jnp.concatenate([ckv_all, krope_all],
                              axis=-1)[:, :, None, :]        # [B,T,1,r+rd]
     v_lat = ckv_all[:, :, None, :]                          # [B,T,1,r]
     ctx = blocked_attention(
-        q_full, k_full, v_lat, scale=(nope + rope_d) ** -0.5, causal=True,
+        q_full, k_full, v_lat, scale=mla_scale(cfg), causal=True,
         kv_len=kv_len, q_offset=q_offset)                   # [B,S,H,r]
 
     w_uv = p["w_uv"].reshape(r, h, vd)
     out = jnp.einsum("bshr,rhv->bshv", ctx.astype(dt), w_uv.astype(dt),
                      preferred_element_type=jnp.float32).astype(x.dtype)
     return L.linear(out.reshape(b, s, h * vd), p["wo"]), new_cache
+
+
+MLA_KEY_PAGES = 32       # pages per key block of the paged MLA loop
+# The paged prefill step expands its key blocks through W_kv_b: on a v5e
+# at 1024-row chunks that beat the absorbed form at every context
+# (docs/serving.md).  True runs prefill absorbed, as decode runs.
+MLA_PREFILL_ABSORBED = False
+
+
+def _mla_paged(p, cfg, q_nope, q_pe, ckv, kpe, cache, *, page_size,
+               absorbed: bool):
+    """MLA over the paged latent pool.  Writes this step's rows
+    ``[c_kv | k_pe]`` at each slot's length (``kv_write``), then attends
+    over the slot's pages in blocks of ``MLA_KEY_PAGES`` pages, only as
+    many blocks as the longest live slot needs (a traced trip count), with
+    an online softmax.  Each block is gathered from the pool in place
+    (``kv_view``) and:
+
+    * absorbed (decode; prefill when ``MLA_PREFILL_ABSORBED``): scored
+      as MQA over the 576-wide latent, q_nope absorbed through W_UK
+      (``mla_absorb``), the latent context expanded through W_UV once at
+      the end;
+    * expanded (prefill): expanded through W_kv_b into per-head keys and
+      values (``mla_expand``), then ordinary attention over 192-wide keys.
+
+    Returns (out [B, S, H, v_head_dim], the pool)."""
+    from repro.models import layers as L
+    from repro.runtime import kv_cache as KV
+    pt, lens, li = cache["page_table"], cache["lens"], cache["layer"]
+    b, s, h, nope = q_nope.shape
+    r, vd, rope_d = cfg.kv_lora_rank, cfg.v_head_dim, cfg.qk_rope_dim
+    cdt = cache["pages_lat"].dtype
+    dt = L.dot_dtype(cdt)
+    # the pool's rows are r + rope_d padded to whole lanes (leaf_specs_for)
+    lat = jnp.concatenate([ckv, kpe], axis=-1)               # [B,S,r+rd]
+    width = cache["pages_lat"].shape[-1]
+    lat = jnp.pad(lat, ((0, 0), (0, 0), (0, width - lat.shape[-1])))
+    pool = KV.paged_update(cache["pages_lat"], lat, pt, lens, page_size,
+                           li, write_mask=cache.get("write_mask"))
+    w_uk = p["w_uk"].reshape(r, h, nope).astype(dt)
+    w_uv = p["w_uv"].reshape(r, h, vd).astype(dt)
+    q_pos = lens[:, None] + jnp.arange(s, dtype=jnp.int32)[None]   # [B,S]
+    kv_len = lens + s
+
+    if absorbed:
+        with jax.named_scope("mla_absorb"):
+            q_abs = jnp.einsum("bshn,rhn->bshr", q_nope.astype(dt), w_uk,
+                               preferred_element_type=jnp.float32)
+            q = jnp.concatenate([q_abs.astype(cdt), q_pe.astype(cdt)], -1)
+
+        def kv_of(blk):                                      # MQA: 1 head
+            return blk[:, :, None, :r + rope_d], blk[:, :, None, :r]
+    else:
+        q = jnp.concatenate([q_nope, q_pe], axis=-1).astype(cdt)
+
+        def kv_of(blk):
+            with jax.named_scope("mla_expand"):
+                c = blk[..., :r].astype(dt)
+                k_nope = jnp.einsum("btr,rhn->bthn", c, w_uk,
+                                    preferred_element_type=jnp.float32)
+                v = jnp.einsum("btr,rhv->bthv", c, w_uv,
+                               preferred_element_type=jnp.float32)
+                k_pe = jnp.broadcast_to(blk[:, :, None, r:r + rope_d],
+                                        k_nope.shape[:3] + (rope_d,))
+                k = jnp.concatenate([k_nope.astype(cdt), k_pe], -1)
+                return k, v.astype(cdt)
+
+    out = _paged_blocks(q, pool, pt, page_size, li, kv_len, q_pos, kv_of,
+                        scale=mla_scale(cfg))
+    if absorbed:
+        with jax.named_scope("mla_absorb"):
+            out = jnp.einsum("bshr,rhv->bshv", out.astype(dt), w_uv,
+                             preferred_element_type=jnp.float32)
+    return out.astype(q_nope.dtype), {"pages_lat": pool}
+
+
+def _paged_blocks(q, pool, page_table, page_size, layer, kv_len, q_pos,
+                  kv_of, *, scale):
+    """Causal online-softmax attention of q [B,S,H,D] over each slot's
+    paged rows, ``MLA_KEY_PAGES`` pages a block, for
+    ceil(max(kv_len) / block) blocks.  ``kv_of(rows [B,T,F])`` returns
+    the block's keys [B,T,Hk,D] and values [B,T,Hk,Dv] (Hk divides H).
+    Probabilities round to the values' dtype before they weight them, as
+    ``blocked_attention`` does.  Returns [B,S,H,Dv] float32."""
+    from repro.models import layers as L
+    from repro.runtime import kv_cache as KV
+    b, s, h, d = q.shape
+    tb = MLA_KEY_PAGES * page_size
+    n_pages = page_table.shape[1]
+    with jax.named_scope("kv_view"):
+        pt = jnp.pad(page_table, ((0, 0), (0, (-n_pages) % MLA_KEY_PAGES)),
+                     constant_values=-1)
+    n_blocks = (jnp.max(kv_len) + tb - 1) // tb
+    k0, v0 = jax.eval_shape(
+        kv_of, jax.ShapeDtypeStruct((b, tb, pool.shape[-1]), pool.dtype))
+    hk, dv = k0.shape[2], v0.shape[3]
+    g = h // hk
+    dt = L.dot_dtype(q.dtype)
+    qg = q.reshape(b, s, hk, g, d).astype(dt)
+
+    def body(j, carry):
+        m, l, acc = carry
+        with jax.named_scope("kv_view"):
+            pt_j = jax.lax.dynamic_slice_in_dim(pt, j * MLA_KEY_PAGES,
+                                                MLA_KEY_PAGES, axis=1)
+            rows = KV.paged_gather(pool, pt_j, page_size, layer)
+        k, v = kv_of(rows)
+        sc = jnp.einsum("bskgd,btkd->bkgst", qg, k.astype(dt),
+                        preferred_element_type=jnp.float32) * scale
+        k_pos = j * tb + jnp.arange(tb, dtype=jnp.int32)
+        mask = ((k_pos[None, None, :] < kv_len[:, None, None])
+                & (k_pos[None, None, :] <= q_pos[:, :, None]))   # [B,S,T]
+        mask = mask[:, None, None]
+        sc = jnp.where(mask, sc, _NEG)
+        m_new = jnp.maximum(m, jnp.max(sc, axis=-1))
+        pr = jnp.where(mask, jnp.exp(sc - m_new[..., None]), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(pr, axis=-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bkgst,btkv->bkgsv", pr.astype(v.dtype).astype(dt),
+            v.astype(dt), preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    m0 = jnp.full((b, hk, g, s), _NEG, jnp.float32)
+    l0 = jnp.zeros((b, hk, g, s), jnp.float32)
+    a0 = jnp.zeros((b, hk, g, s, dv), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, a0))
+    l = jnp.where(l == 0.0, 1.0, l)
+    out = (acc / l[..., None]).transpose(0, 3, 1, 2, 4)      # [B,S,Hk,G,Dv]
+    return out.reshape(b, s, h, dv)

@@ -3,6 +3,7 @@ whole stack can be switched between raw weights (training) and the paper's
 pre-packed path (inference) — see models/model_zoo.pack_for_inference."""
 from __future__ import annotations
 
+import math
 import os
 
 import jax
@@ -77,6 +78,27 @@ def fused_linear(x: jax.Array, w: PackedWeight) -> tuple:
     return _gemm.split_fused(p, _gemm.execute(p, x, w))
 
 
+def grouped_linear(x: jax.Array, w, group_sizes: jax.Array, *,
+                   glu: str | None = None, out_dtype=None) -> jax.Array:
+    """Rows of ``x [M, K]`` sorted by group through a bank of weights:
+    the first ``group_sizes[0]`` rows through the bank's weight 0, the
+    next through weight 1, ...; rows past the sizes' sum come back zero.
+    ``w`` is a grouped pack (``packing.pack_grouped``), which dispatches
+    through the plan/execute grouped arm, or a raw ``[G, K, N]`` bank, or
+    for ``glu`` a raw ``(gate, up)`` pair of banks, which run through the
+    ``xla`` backend's grouped run; ``glu`` combines ``act(gate) * up`` on
+    the fp32 accumulators (the pack's glu epilogue)."""
+    out_dtype = out_dtype or x.dtype
+    spec = _gemm.EpilogueSpec(glu=glu) if glu else None
+    if isinstance(w, PackedWeight):
+        p = _gemm.plan_for_packed(_gemm.lead_m(x), w, epilogue=spec)
+        return _gemm.execute(p, x, w, group_sizes=group_sizes,
+                             out_dtype=out_dtype)
+    bank = jnp.concatenate(w, axis=-1) if glu else w
+    return _gemm.get_backend("xla").run_grouped(
+        x, bank, group_sizes, out_dtype=out_dtype, epilogue=spec)
+
+
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6,
              plus_one: bool = False) -> jax.Array:
     dt = x.dtype
@@ -88,11 +110,53 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6,
     return (x * s).astype(dt)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0):
-    """Rotary embedding.  x: [..., S, H, D] (D even), positions: [..., S]."""
+def yarn_correction_range(cfg, dim: int) -> tuple[int, int]:
+    """YaRN's ramp between extrapolated and interpolated frequency pairs:
+    the (low, high) pair indices, of ``dim // 2``, where the wavelength
+    passes ``beta_fast`` and ``beta_slow`` turns over the original
+    context (arXiv:2309.00071 §3.2, as DeepSeek-V2 computes it)."""
+    def dim_at(turns):
+        return (dim * math.log(cfg.yarn_original_max_pos
+                               / (turns * 2 * math.pi))
+                / (2 * math.log(cfg.rope_theta)))
+    low = math.floor(dim_at(cfg.yarn_beta_fast))
+    high = math.ceil(dim_at(cfg.yarn_beta_slow))
+    return max(low, 0), min(high, dim - 1)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def rope_freqs(cfg, dim: int) -> jax.Array | None:
+    """Per-pair inverse frequencies of ``cfg``'s rotary embedding over
+    ``dim`` features: None for plain rope (``rope`` computes it), YaRN's
+    blend of extrapolated and interpolated frequencies when
+    ``cfg.yarn_factor`` is set.  YaRN's cos/sin scale is
+    mscale(factor, mscale) / mscale(factor, mscale_all_dim); configs that
+    set both equal (DeepSeek-V2) get 1, the only case this supports."""
+    if not cfg.yarn_factor:
+        return None
+    if cfg.yarn_mscale != cfg.yarn_mscale_all_dim:
+        raise NotImplementedError("YaRN with a cos/sin scale other than 1")
+    half = dim // 2
+    extra = cfg.rope_theta ** (-jnp.arange(0, half, dtype=jnp.float32)
+                               / half)
+    inter = extra / cfg.yarn_factor
+    low, high = yarn_correction_range(cfg, dim)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return inter * ramp + extra * (1.0 - ramp)
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
+         freq: jax.Array | None = None):
+    """Rotary embedding.  x: [..., S, H, D] (D even), positions: [..., S].
+    ``freq`` ([D/2]) overrides the plain ``theta`` frequencies (YaRN)."""
     d = x.shape[-1]
     half = d // 2
-    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if freq is None:
+        freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     ang = positions[..., :, None, None].astype(jnp.float32) * freq
     sin, cos = jnp.sin(ang), jnp.cos(ang)
     x1, x2 = x[..., :half], x[..., half:]

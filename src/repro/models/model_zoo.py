@@ -31,6 +31,8 @@ ARCHS = {
     "internvl2-76b": "internvl2_76b",
     "mamba2-370m": "mamba2_370m",
     "hymba-1.5b": "hymba_1_5b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
+    "deepseek-v2-lite-ep8": "deepseek_v2_lite_ep8",
 }
 
 # The paper's Table 3: twelve LLM prefill GEMMs at M = S = 128, as
@@ -92,13 +94,20 @@ def reduced_config(cfg: ModelConfig) -> ModelConfig:
         kw.update(num_heads=4, num_kv_heads=max(1, min(cfg.num_kv_heads, 2)),
                   head_dim=16)
     if cfg.attention_kind == "mla":
-        kw.update(num_heads=4, num_kv_heads=4, head_dim=24, q_lora_rank=32,
+        kw.update(num_heads=4, num_kv_heads=4, head_dim=24,
+                  q_lora_rank=32 if cfg.q_lora_rank else 0,
                   kv_lora_rank=16, qk_rope_dim=8, qk_nope_dim=16,
                   v_head_dim=16)
+    if cfg.yarn_factor:
+        kw.update(yarn_original_max_pos=64)
     if cfg.d_ff:
         kw.update(d_ff=128)
     if cfg.family == "moe":
         kw.update(num_experts=8, experts_per_token=2, moe_d_ff=64)
+        if cfg.held_experts:           # a share: half of the 8 experts
+            kw.update(held_experts=(0, 4))
+    if cfg.first_k_dense:
+        kw.update(num_layers=3, first_k_dense=1)
     if cfg.ssm_heads:
         kw.update(ssm_heads=4, ssm_head_dim=16, ssm_state=8, ssm_chunk=16)
     if cfg.window is not None:
@@ -183,9 +192,11 @@ _PACKABLE = {
     "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "w_dq", "w_uq",
     "w_dkv", "w_kr", "in_proj", "out_proj", "lm_head",
 }
-# Deliberately unpacked: embed (gather), router (small, fp32), MoE expert
-# banks (3-D batched einsum — packed per-expert form is a §Perf item),
-# MLA absorbed factors w_uk/w_uv (consumed reshaped to (r, H, d) inside the
+# MoE expert banks pack as grouped packs (``packing.pack_grouped``): gate
+# and up fused into "wi_gate_up" for the glu epilogue, the down bank as
+# "wo"; the serving layer runs them through the grouped plan/execute arm.
+# Deliberately unpacked: embed (gather), router (small, fp32), MLA
+# absorbed factors w_uk/w_uv (consumed reshaped to (r, H, d) inside the
 # einsum, not through `linear`), conv/norm vectors.
 
 # Horizontal fusion groups: same-input projections concatenated along N
@@ -193,12 +204,13 @@ _PACKABLE = {
 # streams the shared activations once.  The fused key is what the model
 # layers branch on (``attention.gqa_attention``: "wqkv";
 # ``transformer._layer_forward``: "w_gate_up"; ``mla_attention``:
-# "w_dqkr").  glu groups combine in the kernel store step, so their pack
+# "w_dqkr", or "w_qkr" without q-LoRA).  glu groups combine in the kernel store step, so their pack
 # blocks reserve VMEM for the two-accumulator epilogue.
 _FUSE_GROUPS = (
     (("wq", "wk", "wv"), "wqkv", None),
     (("w_gate", "w_up"), "w_gate_up", "glu"),
     (("w_dq", "w_dkv", "w_kr"), "w_dqkr", None),
+    (("wq", "w_dkv", "w_kr"), "w_qkr", None),
 )
 
 
@@ -324,9 +336,26 @@ def pack_for_inference(cfg: ModelConfig, params, *, block_n=None,
                                 quant=fmt)
         return place_pw(pw, shard_node)
 
+    def pack_banks(node):
+        # the grouped layer's expert banks [.., G, K, N]: blocks as the
+        # policy packs one expert's weight; fp32 whatever ``quant`` says
+        gate, up, down = node["wi_gate"], node["wi_up"], node["wo"]
+        spec = gemm_api.EpilogueSpec(glu=cfg.act)
+        bn, bk = blocks_for(2 * gate.shape[-1], gate.shape[-2],
+                            epilogue=spec)
+        out = {k: v for k, v in node.items()
+               if k not in ("wi_gate", "wi_up", "wo")}
+        out["wi_gate_up"] = packing.pack_grouped([gate, up], block_n=bn,
+                                                 block_k=bk)
+        bn, bk = blocks_for(down.shape[-1], down.shape[-2])
+        out["wo"] = packing.pack_grouped([down], block_n=bn, block_k=bk)
+        return out
+
     def walk(path, node, shard_node):
         if isinstance(node, dict):
             shard = shard_node if isinstance(shard_node, dict) else {}
+            if path and path[-1] == "moe":
+                node = pack_banks(node)
             out = {}
             done = set()
             if fuse:
@@ -344,10 +373,9 @@ def pack_for_inference(cfg: ModelConfig, params, *, block_n=None,
                 out[key] = walk(path + (key,), v, shard.get(key))
             return out
         name = path[-1]
-        if name not in _PACKABLE or node.ndim < 2:
+        if (isinstance(node, packing.PackedWeight) or name not in _PACKABLE
+                or node.ndim < 2):
             return node
-        if name == "wo" and "moe" in path:
-            return node                         # MoE expert bank, not attn
         return pack_one(name, node, shard_node)
 
     return walk((), params, shardings)

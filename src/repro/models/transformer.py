@@ -23,7 +23,10 @@ from repro.models import ssm as S
 
 
 # ----------------------------------------------------------------- params
-def _init_layer(key, cfg, dtype):
+def _init_layer(key, cfg, dtype, moe: bool | None = None):
+    """One layer's weights; ``moe=False`` makes a leading dense layer of
+    a MoE config (a d_ff-wide SwiGLU where its MoE layers have experts)."""
+    moe = cfg.family == "moe" if moe is None else moe
     ks = jax.random.split(key, 8)
     p = {"ln1": jnp.ones((cfg.d_model,), dtype)}
     if cfg.attention_kind == "gqa":
@@ -40,7 +43,7 @@ def _init_layer(key, cfg, dtype):
     if cfg.post_norms:
         p["ln1_post"] = jnp.ones((cfg.d_model,), dtype)
         p["ln2_post"] = jnp.ones((cfg.d_model,), dtype)
-    if cfg.family == "moe":
+    if moe:
         p["ln2"] = jnp.ones((cfg.d_model,), dtype)
         p["moe"] = M.moe_params(ks[2], cfg, dtype)
     elif cfg.d_ff:
@@ -63,7 +66,15 @@ def init_params(cfg, key):
     if cfg.modality == "text":
         params["embed"] = L.init_dense(ks[0], (cfg.vocab_size, cfg.d_model),
                                        scale=1.0, dtype=dtype)
+    # layer l's weights come from split(ks[1], num_layers)[l]; a config's
+    # first_k_dense leading layers stack apart (params["dense_layers"])
+    # since their FFN differs from the scan's MoE layers
     layer_keys = jax.random.split(ks[1], cfg.num_layers)
+    nd = cfg.first_k_dense
+    if nd:
+        params["dense_layers"] = jax.vmap(
+            lambda k: _init_layer(k, cfg, dtype, moe=False))(layer_keys[:nd])
+        layer_keys = layer_keys[nd:]
     params["layers"] = jax.vmap(
         lambda k: _init_layer(k, cfg, dtype))(layer_keys)
     params["final_norm"] = jnp.ones((cfg.d_model,), dtype)
@@ -117,13 +128,17 @@ def init_cache(cfg, batch: int, max_len: int):
 # ------------------------------------------------------------------ layers
 def _layer_forward(cfg, lp, x, *, window_l, positions, cache_l, cache_index,
                    mode, shard_fn=None, page_size=None):
-    """One decoder layer.  Returns (x, new_cache_l, aux_loss)."""
+    """One decoder layer.  Returns (x, new_cache_l, aux_loss, rows): rows
+    is the grouped MoE layer's count of rows routed to each held expert
+    ([held] int32), None for every other layer."""
     aux = jnp.zeros((), jnp.float32)
+    rows = None
     new_cache = {}
     h = L.rms_norm(x, lp["ln1"], cfg.norm_eps, cfg.norm_plus_one)
 
     attn_cache = None
-    if cache_l is not None and "pages_k" in cache_l:
+    if cache_l is not None and ("pages_k" in cache_l
+                                or "pages_lat" in cache_l):
         attn_cache = cache_l          # stacked pools + page_table/lens/layer
     elif cache_l is not None and "k" in cache_l:
         attn_cache = {k: cache_l[k] for k in ("k", "v", "pos")
@@ -143,7 +158,8 @@ def _layer_forward(cfg, lp, x, *, window_l, positions, cache_l, cache_index,
             new_cache.update(nc)
     elif cfg.attention_kind == "mla":
         out, nc = A.mla_attention(lp["attn"], cfg, h, positions=positions,
-                                  cache=attn_cache, cache_index=cache_index)
+                                  cache=attn_cache, cache_index=cache_index,
+                                  page_size=page_size, mode=mode)
         if nc:
             new_cache.update(nc)
     elif cfg.attention_kind == "parallel_ssm":
@@ -169,16 +185,19 @@ def _layer_forward(cfg, lp, x, *, window_l, positions, cache_l, cache_index,
                          cfg.norm_plus_one)
     x = x + out
 
-    if cfg.family == "moe":
+    if "moe" in lp:
         h2 = L.rms_norm(x, lp["ln2"], cfg.norm_eps, cfg.norm_plus_one)
-        out2, aux = M.moe_ffn(lp["moe"], cfg, h2,
-                              capacity_factor=cfg.capacity_factor,
-                              shard_fn=shard_fn)
+        if mode == "train":           # capacity dispatch + aux loss
+            out2, aux = M.moe_ffn(lp["moe"], cfg, h2,
+                                  capacity_factor=cfg.capacity_factor,
+                                  shard_fn=shard_fn)
+        else:                         # serving: drop-free, grouped GEMMs
+            out2, rows = M.moe_grouped(lp["moe"], cfg, h2)
         if cfg.post_norms:
             out2 = L.rms_norm(out2, lp["ln2_post"], cfg.norm_eps,
                               cfg.norm_plus_one)
         x = x + out2
-    elif cfg.d_ff:
+    elif "ffn" in lp:
         h2 = L.rms_norm(x, lp["ln2"], cfg.norm_eps, cfg.norm_plus_one)
         ffn = lp["ffn"]
         if "w_gate_up" in ffn:
@@ -202,7 +221,7 @@ def _layer_forward(cfg, lp, x, *, window_l, positions, cache_l, cache_index,
                                   cfg.norm_plus_one)
             x = x + out2
 
-    return x, (new_cache or None), aux
+    return x, (new_cache or None), aux, rows
 
 
 # ----------------------------------------------------------------- forward
@@ -238,9 +257,9 @@ def forward(cfg, params, inputs, *, cache=None, mode: str = "train",
     paged = cache is not None and "page_table" in cache
     s = x.shape[1]
     if paged:
-        if cfg.attention_kind != "gqa":
+        if cfg.attention_kind not in ("gqa", "mla"):
             raise NotImplementedError(
-                f"paged decode supports GQA archs; got "
+                f"paged decode supports GQA and MLA archs; got "
                 f"{cfg.attention_kind!r}")
         if page_size is None:
             raise ValueError("paged cache needs page_size")
@@ -272,6 +291,9 @@ def forward(cfg, params, inputs, *, cache=None, mode: str = "train",
     # and the layer index down instead: the KV path scatters into and
     # gathers from the pool at layer ``li`` in place (runtime/kv_cache),
     # so no layer's whole pool is sliced, copied or written back.
+    # A config's leading dense layers run as a scan of their own before
+    # the main one; the layer index both hand down counts from the stack's
+    # first layer, so every layer addresses its own rows of one pool.
     def body(carry, xs):
         x, aux, cl = carry
         lp, w_l, li = xs
@@ -282,7 +304,7 @@ def forward(cfg, params, inputs, *, cache=None, mode: str = "train",
             with jax.named_scope("kv_view"):
                 c_l = jax.tree.map(lambda buf: jax.lax.dynamic_index_in_dim(
                     buf, li, 0, keepdims=False), cl)
-        x, new_c, a = _layer_forward(
+        x, new_c, a, rows = _layer_forward(
             cfg, lp, x, window_l=w_l, positions=positions, cache_l=c_l,
             cache_index=cache_index, mode=mode, shard_fn=shard,
             page_size=page_size)
@@ -294,7 +316,7 @@ def forward(cfg, params, inputs, *, cache=None, mode: str = "train",
                     lambda buf, new: jax.lax.dynamic_update_index_in_dim(
                         buf, new.astype(buf.dtype), li, 0), cl, new_c)
         x = shard(x, "batch", "seq", "d_model")
-        return (x, aux + a, cl), None
+        return (x, aux + a, cl), rows
 
     if mode == "train" and cfg.remat:
         policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
@@ -302,9 +324,14 @@ def forward(cfg, params, inputs, *, cache=None, mode: str = "train",
                   else jax.checkpoint_policies.nothing_saveable)
         body = jax.checkpoint(body, policy=policy)
 
-    xs = (params["layers"], w_arr, jnp.arange(cfg.num_layers))
-    (x, aux, new_cache_layers), _ = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32), cache_layers), xs)
+    carry = (x, jnp.zeros((), jnp.float32), cache_layers)
+    nd = cfg.first_k_dense
+    if nd:
+        carry, _ = jax.lax.scan(body, carry, (
+            params["dense_layers"], w_arr[:nd], jnp.arange(nd)))
+        w_arr = w_arr[nd:]
+    xs = (params["layers"], w_arr, jnp.arange(nd, cfg.num_layers))
+    (x, aux, new_cache_layers), expert_rows = jax.lax.scan(body, carry, xs)
 
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps, cfg.norm_plus_one)
     if logits_mode == "last":
@@ -337,8 +364,12 @@ def forward(cfg, params, inputs, *, cache=None, mode: str = "train",
     if has_cache:
         if paged:
             # lens/page_table are host-owned (the scheduler advances
-            # them between steps); pass through unchanged
+            # them between steps); pass through unchanged.  A grouped
+            # MoE stack adds its rows per held expert per MoE layer
+            # ([MoE layers, held] int32): the step's expert-load counter
             new_cache = dict(cache, layers=new_cache_layers)
+            if expert_rows is not None:
+                new_cache["expert_rows"] = expert_rows
         else:
             new_cache = {"layers": new_cache_layers,
                          "index": cache_index + s}

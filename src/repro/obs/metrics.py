@@ -337,6 +337,16 @@ def publish_serve_stats(stats, reg: "MetricsRegistry | None" = None) -> None:
     for req in stats.requests:
         for gap in req.itl_s:
             h_i.observe(gap * 1e3)
+    rows = getattr(stats, "expert_rows", None)
+    if rows:
+        # rows routed to each held expert, summed over layers and
+        # dispatches, and the busiest held expert over the mean
+        per_expert = stats.expert_rows_of().sum(axis=(0, 1))
+        for e, n in enumerate(per_expert):
+            c("serve_expert_rows").inc(int(n), expert=str(e))
+        mean = float(per_expert.mean())
+        g("serve_expert_load_max_over_mean").set(
+            float(per_expert.max()) / mean if mean else 0.0)
     if stats.prefix is not None:
         publish_prefix_stats(stats.prefix, reg)
 

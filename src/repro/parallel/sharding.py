@@ -63,10 +63,13 @@ PARAM_RULES = {
     "w_down": P("model", "data"),
     # MLA fused down-projections [K, q_lora + kv_lora + rope]
     "w_dqkr": P("data", "model"),
+    # MLA without q-LoRA: fused W_q | W_kv_a [K, H*(nope+rope) + r + rope]
+    "w_qkr": P("data", "model"),
     # MoE (EP: experts over model)
     "router": P("data", None),
     "wi_gate": P("model", "data", None),  # (E, d, f)
     "wi_up": P("model", "data", None),
+    "wi_gate_up": P("model", "data", None),  # grouped glu pack (E, d, 2f)
     # mamba
     "in_proj": P("data", "model"),
     "conv_w": P(None, "model"),
@@ -78,6 +81,7 @@ _MOE_WO = P("model", None, "data")
 _VEC_KEYS = {  # 1-D per-layer vectors: replicate
     "ln1", "ln2", "ln1_post", "ln2_post", "final_norm", "norm",
     "ln_attn_out", "ln_ssm_out", "a_log", "d_skip", "dt_bias", "conv_b",
+    "kv_norm",
 }
 
 
@@ -153,8 +157,8 @@ def param_specs(params_tree, mesh: Mesh):
     specs = []
     for path, leaf in flat:
         names = _path_names(path)
-        stacked = "layers" in names and hasattr(leaf, "ndim") \
-            and leaf.ndim >= 2
+        stacked = ("layers" in names or "dense_layers" in names) \
+            and hasattr(leaf, "ndim") and leaf.ndim >= 2
         # PackedWeight static fields (ints) flatten away; leaves here are
         # arrays / ShapeDtypeStructs only.
         specs.append(_spec_for(names, leaf.shape, mesh, stacked))

@@ -192,6 +192,11 @@ class ServeStats:
     ``sync_per_step`` block + the final materialize) and
     ``megastep_depth`` echoes the configured D.
 
+    Expert load (MoE configs): ``expert_rows`` holds,
+    per paged dispatch, ``(phase, rows)`` with rows ``[MoE layers,
+    held experts]`` int32 — the rows the step routed to each expert this
+    chip holds — left on the device until read (:meth:`expert_rows_of`).
+
     Fault-isolation observability: ``outcomes`` maps rid to
     :class:`RequestOutcome` (every submitted request, terminal states
     included); ``dispatch_retries`` / ``backend_fallbacks`` count the
@@ -229,6 +234,14 @@ class ServeStats:
     degraded: dict = dataclasses.field(default_factory=dict)
     stragglers: list = dataclasses.field(default_factory=list)
     trace_dropped: int = 0
+    expert_rows: list = dataclasses.field(default_factory=list)
+
+    def expert_rows_of(self, phase: str | None = None) -> np.ndarray:
+        """``[dispatches, MoE layers, held]`` int32 of ``phase``'s
+        dispatches ("prefill" | "decode"; None: both), in order."""
+        rows = [np.asarray(r) for p, r in self.expert_rows
+                if phase is None or p == phase]
+        return np.stack(rows) if rows else np.zeros((0, 0, 0), np.int32)
 
     @property
     def prefill_tps(self):
@@ -835,6 +848,7 @@ class ContinuousBatchingScheduler:
                                    error_type=type(e).__name__)
                 return True
             self.kv.pages = pages
+            self._count_experts("prefill")
             with _spans.span("fence"):
                 timer.fence(tok)
         with _spans.span("prefill_post"):
@@ -943,6 +957,7 @@ class ContinuousBatchingScheduler:
                 return True
             self._last = last
             self.kv.pages = pages
+            self._count_experts("decode")
             self.stats.decode_dispatches += 1
             with _spans.span("fence"):
                 timer.fence(self._last)
@@ -963,6 +978,11 @@ class ContinuousBatchingScheduler:
             if self.check_invariants:
                 self.kv.check_no_aliasing()
         return True
+
+    def _count_experts(self, phase: str) -> None:
+        rows = getattr(self.engine, "expert_rows", None)
+        if rows is not None:
+            self.stats.expert_rows.append((phase, rows))
 
     def _emit(self, i: int):
         sl = self.slots[i]

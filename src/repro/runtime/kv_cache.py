@@ -82,10 +82,16 @@ def pages_for(tokens: int, page_size: int) -> int:
 def leaf_specs_for(cfg) -> dict:
     """Per-token cached tensors for ``cfg`` as ``{name: (feat, dtype)}``.
 
-    Only the full-cache GQA layout is paged today; window (ring) caches
-    and SSM state are per-slot *constant-size* state with no paging win,
-    and MLA's latent cache is a straightforward extension left until an
-    MLA arch enters the serving matrix.
+    Full-cache GQA and MLA are paged; window (ring) caches and SSM state
+    are per-slot *constant-size* state with no paging win.
+
+    MLA caches one row per token, ``pages_lat``: the normalised latent
+    c_kv and the roped k_pe side by side (``kv_lora_rank + qk_rope_dim``,
+    576 for DeepSeek-V2), zero-padded to whole 128-lane tiles (640), so
+    one scatter writes a token and one gather reads a block.  Left 576
+    wide, or split into a 512- and a 64-wide pool, the TPU lays the
+    (narrow) pool out page-minor as it does narrow GQA rows (below), and
+    each step copies it whole around the layer loop.
 
     The feature dims are the pool's minor dims, and a TPU keeps an array
     row-major only when its minor dim fills whole 128-lane tiles.  With
@@ -97,12 +103,16 @@ def leaf_specs_for(cfg) -> dict:
     copy the whole stacked pool to a row-major layout and back.  Either
     way the flat rows the serving steps index are a free reshape.
     """
+    dt = jnp.dtype(cfg.cache_dtype)
+    if cfg.attention_kind == "mla":
+        return {"pages_lat": (
+            (-(-(cfg.kv_lora_rank + cfg.qk_rope_dim) // _LANES) * _LANES,),
+            dt)}
     if cfg.attention_kind != "gqa" or cfg.resolved_cache_kind != "full":
         raise NotImplementedError(
-            f"paged KV cache supports full-cache GQA archs; got "
+            f"paged KV cache supports full-cache GQA and MLA archs; got "
             f"attention_kind={cfg.attention_kind!r} / "
             f"cache={cfg.resolved_cache_kind!r}")
-    dt = jnp.dtype(cfg.cache_dtype)
     hkv, hd = cfg.num_kv_heads, cfg.head_dim
     feat = (hkv, hd) if hd % _LANES == 0 else (hkv * hd,)
     return {"pages_k": (feat, dt), "pages_v": (feat, dt)}

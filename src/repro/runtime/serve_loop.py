@@ -235,7 +235,7 @@ class Engine:
                         cfg, params, cache, tokens, page_size=page_size,
                         logit_index=logit_index, shard_fn=shard_fn)
                     tok = jnp.argmax(logits[0]).astype(jnp.int32)
-                    return tok, cache["layers"]
+                    return tok, cache["layers"], cache.get("expert_rows")
 
             def _decode_tick(params, pages, page_table, lens, write_mask,
                              last_tokens, *, page_size):
@@ -250,7 +250,7 @@ class Engine:
                 toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 # masked rows (idle / still prefilling) keep their token
                 new_last = jnp.where(write_mask, toks, last_tokens)
-                return new_last, cache["layers"]
+                return new_last, cache["layers"], cache.get("expert_rows")
 
             def _paged_decode(params, pages, page_table, lens, write_mask,
                               last_tokens, *, page_size):
@@ -285,19 +285,27 @@ class Engine:
                     hist0 = jnp.zeros((max_depth, last_tokens.shape[0]),
                                       jnp.int32)
                     step = write_mask.astype(jnp.int32)
+                    rows0 = None
+                    if cfg.family == "moe":
+                        lo, hi = cfg.held_range
+                        rows0 = jnp.zeros((cfg.num_layers
+                                           - cfg.first_k_dense, hi - lo),
+                                          jnp.int32)
 
                     def body(t, carry):
-                        last, pages, hist = carry
-                        last, pages = _decode_tick(
+                        last, pages, hist, rows = carry
+                        last, pages, r = _decode_tick(
                             params, pages, page_table, lens + t * step,
                             write_mask, last, page_size=page_size)
                         hist = jax.lax.dynamic_update_index_in_dim(
                             hist, last, t, 0)
-                        return last, pages, hist
+                        if r is not None:
+                            rows = rows + r
+                        return last, pages, hist, rows
 
-                    last, pages, hist = jax.lax.fori_loop(
-                        0, n_ticks, body, (last_tokens, pages, hist0))
-                    return last, hist, pages
+                    last, pages, hist, rows = jax.lax.fori_loop(
+                        0, n_ticks, body, (last_tokens, pages, hist0, rows0))
+                    return last, hist, pages, rows
 
             return {
                 "prefill": jax.jit(_paged_prefill, donate_argnums=donate,
@@ -312,6 +320,10 @@ class Engine:
         self._build_paged = _build_paged_steps
         self._paged = _build_paged_steps(backend)
         self._paged_fb = None           # lazy xla fallback step set
+        # the last paged dispatch's rows routed to each held expert per
+        # MoE layer ([MoE layers, held] int32, on the device; None for a
+        # dense config): the scheduler's counter
+        self.expert_rows = None
 
     # ------------------------------------------------------------- prefill
     def prefill(self, inputs):
@@ -349,10 +361,12 @@ class Engine:
         into one slot's pages at its current length.  Returns
         (greedy token for chunk row ``logit_index`` — the prompt's last
         real row on the final chunk — as a device scalar, pages).
-        ``fallback=True`` dispatches the xla-backend step set."""
-        return self._paged_steps(fallback)["prefill"](
-            self.params, pages, page_table, lens, tokens, logit_index,
-            page_size=page_size)
+        ``fallback=True`` dispatches the xla-backend step set.  The
+        step's expert-row counter lands in ``self.expert_rows``."""
+        tok, pages, self.expert_rows = self._paged_steps(fallback)[
+            "prefill"](self.params, pages, page_table, lens, tokens,
+                       logit_index, page_size=page_size)
+        return tok, pages
 
     def decode_step(self, pages, page_table, lens, write_mask,
                     last_tokens, *, page_size: int,
@@ -361,9 +375,10 @@ class Engine:
         [slots] back through the model at per-slot lengths, write-masked
         so idle / still-prefilling slots touch nothing.  Returns
         (next last_tokens [slots] — masked rows unchanged, pages)."""
-        return self._paged_steps(fallback)["decode"](
-            self.params, pages, page_table, lens, write_mask,
-            last_tokens, page_size=page_size)
+        last, pages, self.expert_rows = self._paged_steps(fallback)[
+            "decode"](self.params, pages, page_table, lens, write_mask,
+                      last_tokens, page_size=page_size)
+        return last, pages
 
     def decode_megastep(self, pages, page_table, lens, write_mask,
                         last_tokens, n_ticks: int, *, page_size: int,
@@ -376,10 +391,11 @@ class Engine:
         and every tick is bit-identical to a ``decode_step`` dispatch.
         Returns (last tokens [slots], token history [max_depth, slots]
         — rows past ``n_ticks`` are zeros, pages)."""
-        return self._paged_steps(fallback)["megastep"](
-            self.params, pages, page_table, lens, write_mask,
-            last_tokens, jnp.asarray(n_ticks, jnp.int32),
-            page_size=page_size, max_depth=max_depth)
+        last, hist, pages, self.expert_rows = self._paged_steps(fallback)[
+            "megastep"](self.params, pages, page_table, lens, write_mask,
+                        last_tokens, jnp.asarray(n_ticks, jnp.int32),
+                        page_size=page_size, max_depth=max_depth)
+        return last, hist, pages
 
     # ------------------------------------------------------- plan warmup
     def warmup_plans(self, *, batch_slots: int, prefill_chunk: int = 32,
@@ -391,8 +407,10 @@ class Engine:
 
         Two layers of warmup: (1) the paged serving steps — the
         chunked-prefill step at the ``bucket_m(prefill_chunk)``
-        admission width AND at every chunk-tail bucket below it (the
-        widths the scheduler's bucketed final/divergent chunks emit —
+        admission width AND at every chunk-tail width ``bucket_m`` gives
+        below it (the bucket ladder, then multiples of 128 up to a wider
+        chunk: the widths the scheduler's bucketed final/divergent
+        chunks emit —
         a prefix-cache hit starts prefill mid-prompt at arbitrary
         offsets, so every tail bucket is reachable), the ``[slots, 1]``
         decode step, and the megastep when ``megastep_depth > 1`` —
@@ -435,14 +453,17 @@ class Engine:
         pps = self.max_len // page_size
         i32 = jnp.int32
         timings = {}
-        # admission width PLUS every chunk-tail bucket below it: the
+        # admission width PLUS every chunk-tail width below it: the
         # scheduler dispatches a prompt's final chunk — and the whole
         # divergent remainder after a prefix-cache hit, which starts
         # mid-prompt at an arbitrary offset — at gemm.bucket_m(rem), so
-        # the tail widths the pool can emit are exactly the bucket
-        # ladder <= chunk.  Driving each once keeps chunk_plan_misses
-        # at 0 with the prefix cache on (benchmarks/table10_prefix.py).
-        widths = [b for b in gemm_api.PREFILL_M_BUCKETS if b < chunk]
+        # the tail widths the pool can emit are bucket_m(1..chunk): the
+        # bucket ladder, then multiples of 128 up to a wider chunk.
+        # Driving each once keeps chunk_plan_misses at 0 with the prefix
+        # cache on (benchmarks/table10_prefix.py), and nothing compiles
+        # while serving.
+        widths = sorted({gemm_api.bucket_m(r) for r in range(1, chunk)}
+                        - {chunk})
         for w in widths + [chunk]:
             t0 = time.perf_counter()
             tok, pages = self.prefill_chunk(

@@ -120,7 +120,8 @@ def step_texts(eng, *, slots: int, chunk: int, page_size: int,
 
 
 def layer_pool_elements(eng, *, num_pages: int, page_size: int) -> int:
-    """Elements of one layer's pool of one cached tensor (K or V)."""
+    """Elements of one layer's pool of one cached tensor (K or V; MLA's
+    latent rows)."""
     from repro.runtime import kv_cache as KV
-    feat, _ = KV.leaf_specs_for(eng.cfg)["pages_k"]
+    feat, _ = next(iter(KV.leaf_specs_for(eng.cfg).values()))
     return num_pages * page_size * math.prod(feat)

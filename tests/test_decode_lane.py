@@ -288,6 +288,25 @@ def test_warmup_plans_makes_first_tick_hot(packed_engine):
         np.testing.assert_array_equal(o, r)
 
 
+def test_warmup_covers_the_tails_of_a_wide_chunk():
+    """Past the bucket ladder the scheduler's tail widths are multiples
+    of 128 (``bucket_m``); a 384-row chunk's 200-token tail runs 256
+    rows, which warmup must have traced (no plan resolves while
+    serving)."""
+    from repro.models import model_zoo
+    from repro.runtime.serve_loop import Engine
+    cfg = model_zoo.reduced_config(model_zoo.get_config("stablelm-3b"))
+    eng = Engine(cfg, model_zoo.build(cfg), max_len=640, packed=True)
+    t = eng.warmup_plans(batch_slots=1, prefill_chunk=384, page_size=16)
+    assert {"prefill_chunk_m128", "prefill_chunk_m256",
+            "prefill_chunk"} <= set(t)
+    misses = gemm.plan_cache_info().misses
+    req = np.arange(1, 585, dtype=np.int32) % cfg.vocab_size
+    eng.serve([req], batch_slots=1, max_new_tokens=2, prefill_chunk=384,
+              page_size=16)
+    assert gemm.plan_cache_info().misses == misses
+
+
 def test_warmup_rejects_stub_frontends():
     class FakeCfg:
         modality = "image"

@@ -289,25 +289,73 @@ CELLS = {"stablelm-3b-16l": ("stablelm-3b", 16),
 SLOTS, CELL_MAX_LEN, CELL_PAGE = 4, 2064, 16
 
 
-@pytest.mark.parametrize("cell", sorted(CELLS))
-def test_paged_steps_index_the_pool_in_place(one_chip, cell):
+@pytest.fixture(scope="module")
+def cell_steps(one_chip):
+    """Compiled paged steps of each serving cell (compiled once per
+    module): ``{cell: (engine, {step: HLO text}, layer pool elements)}``."""
+    cache = {}
+
+    def get(cell):
+        if cell not in cache:
+            arch, layers, slots, max_len, chunk = CELL_STEPS[cell]
+            cfg = dataclasses.replace(model_zoo.get_config(arch),
+                                      num_layers=layers)
+            packed = _on_chip(jax.eval_shape(
+                lambda p: model_zoo.pack_for_inference(cfg, p),
+                model_zoo.abstract_params(cfg)), one_chip)
+            eng = Engine(cfg, packed, max_len=max_len, packed=True)
+            num_pages = slots * max_len // CELL_PAGE
+            texts = _kv_guard.step_texts(
+                eng, slots=slots, chunk=chunk, page_size=CELL_PAGE,
+                num_pages=num_pages, sharding=one_chip)
+            layer = _kv_guard.layer_pool_elements(
+                eng, num_pages=num_pages, page_size=CELL_PAGE)
+            cache[cell] = (eng, texts, layer)
+        return cache[cell]
+    return get
+
+
+# (arch, layers, slots, max_len, prefill chunk) of every serving cell;
+# the long-prompt cell is one chip's share of DeepSeek-V2-Lite (stage 1
+# of 2, routed experts 0-7 of 64)
+CELL_STEPS = {cell: (arch, layers, SLOTS, CELL_MAX_LEN, PREFILL_M)
+              for cell, (arch, layers) in CELLS.items()}
+CELL_STEPS["deepseek-v2-lite-14l"] = ("deepseek-v2-lite-ep8", 14, 8, 16400,
+                                      1024)
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_STEPS))
+def test_paged_steps_index_the_pool_in_place(cell_steps, cell):
     """The compiled prefill-chunk, decode and megastep steps slice, copy
     and write back no layer's pool: the one instruction of a layer pool's
     size besides the slots' view is the scatter into the donated pool.
-    Here the pool holds exactly the slots' 4 x 2064 tokens, so a decode
-    step's view has a layer pool's elements."""
-    arch, layers = CELLS[cell]
-    cfg = dataclasses.replace(model_zoo.get_config(arch), num_layers=layers)
-    packed = _on_chip(jax.eval_shape(
-        lambda p: model_zoo.pack_for_inference(cfg, p),
-        model_zoo.abstract_params(cfg)), one_chip)
-    eng = Engine(cfg, packed, max_len=CELL_MAX_LEN, packed=True)
-    num_pages = SLOTS * CELL_MAX_LEN // CELL_PAGE
-    texts = _kv_guard.step_texts(eng, slots=SLOTS, chunk=PREFILL_M,
-                                 page_size=CELL_PAGE, num_pages=num_pages,
-                                 sharding=one_chip)
-    layer = _kv_guard.layer_pool_elements(eng, num_pages=num_pages,
-                                          page_size=CELL_PAGE)
+    Here the GQA cells' pool holds exactly the slots' 4 x 2064 tokens, so
+    a decode step's view has a layer pool's elements; the latent pool's
+    steps read it in blocks and hold no whole view."""
+    _, texts, layer = cell_steps(cell)
     for name, text in texts.items():
         assert "kv_write/scatter" in text, name
         assert _kv_guard.pool_copies(text, layer) == [], name
+
+
+def test_latent_steps_run_grouped_expert_gemms(cell_steps):
+    """The long-prompt cell's steps compile at published widths with the
+    held experts as TPU grouped-matmul kernels (two per MoE layer of the
+    scan body: gate/up, down) and MLA's scopes, and its prefill step
+    fits one chip with the weights and the pool beside it."""
+    eng, texts, _ = cell_steps("deepseek-v2-lite-14l")
+    for name, text in texts.items():
+        assert text.count("%ragged-dot-none") >= 2, name
+        assert "moe_route" in text and "mla_" in text, name
+    assert "mla_expand" in texts["prefill"]
+    assert "mla_absorb" in texts["decode"]
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_dense_cells_pick_the_same_gemm_ops(cell_steps, cell):
+    """The GQA cells' compiled prefill steps give the benchmark's GEMM
+    rule (``chipbench/trace_reduce.gemm_op_names``) the 15 ops it picked
+    before the MLA/MoE path joined the shared scan (PERF.md)."""
+    from chipbench import trace_reduce
+    _, texts, _ = cell_steps(cell)
+    assert len(trace_reduce.gemm_op_names(texts["prefill"])) == 15
