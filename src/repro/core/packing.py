@@ -68,6 +68,17 @@ class PackedWeight:
         return self.data.dtype
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class StackedLayer:
+    """Layer ``layer`` of a stacked pack (every array of ``pack`` leading
+    ``[L, ...]``): what the layer scan hands a projection instead of a
+    slice, so the GEMM can read that layer's panels in place
+    (``gemm.execute(..., layer=)``)."""
+    pack: PackedWeight
+    layer: jax.Array
+
+
 def _pad_to(x: jax.Array, mults: tuple[int, int]) -> jax.Array:
     pk = (-x.shape[0]) % mults[0]
     pn = (-x.shape[1]) % mults[1]

@@ -6,10 +6,12 @@ outside that module could add a backend or scope a choice to one engine.
 Here backends are first-class registry entries:
 
   * ``xla``       — one shape-agnostic dot (the Accelerate-dispatch
-                    analogue and the process default).  Ignores the
+                    analogue, and the default for most plans).  Ignores the
                     plan's blocking (``needs_blocks=False``), so execute()
                     skips the block padding for it.
-  * ``pallas``    — the compiled panel kernel (TPU deployment path).
+  * ``pallas``    — the compiled panel kernel (TPU deployment path, and
+                    the TPU's default for the layer scan's stacked
+                    float32 packs, ``device_default``).
   * ``interpret`` — the same kernel through the Pallas interpreter:
                     kernel-validation mode, bit-identical to
                     ``kernels/ref.gemm_blocked`` by construction.
@@ -48,13 +50,17 @@ class Backend:
     the quant subsystem keep working for fp32 plans unchanged).
     ``run_grouped`` (optional) runs grouped plans —
     ``run_grouped(x, bank [G, K_pad, N_pad], group_sizes [G], *,
-    out_dtype, epilogue)`` over rows sorted by group."""
+    out_dtype, epilogue)`` over rows sorted by group.  ``in_place``: ``run``
+    takes a stacked pack ``[L, K_pad, N_pad]`` and ``layer=`` and reads
+    that layer's panels where they lie; execute() slices the layer out
+    for a backend without it."""
     name: str
     run: RunFn
     needs_blocks: bool = True    # False: shape-agnostic, skip block padding
     description: str = ""
     run_quant: RunFn | None = None
     run_grouped: RunFn | None = None
+    in_place: bool = False
 
 
 _REGISTRY: dict[str, Backend] = {}
@@ -70,6 +76,7 @@ def register_backend(name: str, run: RunFn, *, needs_blocks: bool = True,
                      description: str = "",
                      run_quant: RunFn | None = None,
                      run_grouped: RunFn | None = None,
+                     in_place: bool = False,
                      overwrite: bool = False) -> Backend:
     """Register a GEMM backend under ``name`` (the extension hook)."""
     with _LOCK:
@@ -78,7 +85,7 @@ def register_backend(name: str, run: RunFn, *, needs_blocks: bool = True,
                              f"pass overwrite=True to replace it")
         b = Backend(name=name, run=run, needs_blocks=needs_blocks,
                     description=description, run_quant=run_quant,
-                    run_grouped=run_grouped)
+                    run_grouped=run_grouped, in_place=in_place)
         _REGISTRY[name] = b
         return b
 
@@ -104,24 +111,44 @@ def list_backends() -> list[str]:
 
 
 # ----------------------------------------------------------- default choice
-# The process default on every device, the TPU included: the panel kernel
-# runs only where a caller names it (Engine(backend="pallas"),
-# launch/serve --backend pallas).  Which one a TPU should default to has
-# not been measured.
-_FALLBACK_DEFAULT = "xla"
+def device_default(platform: str, *, weight_format: str = "fp32",
+                   stacked: bool = False, m: int | None = None) -> str:
+    """The backend a plan gets on ``platform`` when no scope names one.
+
+    On a TPU the panel kernel runs a float32 plan for a stacked pack (a
+    layer scan's weights, ``execute(..., layer=)``) whose rows fit one
+    row panel (M <= ``DEFAULT_BLOCK_M``): it reads the layer's float32
+    panels in place, once, and rounds them to bf16 in VMEM, where XLA's
+    default-precision dot first writes a bf16 copy of the whole stack
+    and then reads it; on a v5e it takes about half XLA's time at M = 128
+    and M = 4 (PERF.md §6).  Everything else keeps ``xla``: a
+    single 2-D weight (the LM head), which XLA's dot reads as float32
+    with no copy and its wider tiles beat the kernel's; more rows than
+    one panel (the kernel streams the weights once per 128-row panel);
+    quantized plans (the int8 packs were measured on ``xla``'s
+    dequantizing run); and every other device.  The choice is one
+    device's: a step partitioned over a mesh of several devices names
+    ``xla`` in its scope instead (``Engine(mesh=)``), since XLA cannot
+    partition the kernel's custom call."""
+    if (platform == "tpu" and weight_format == "fp32" and stacked
+            and m is not None and m <= _kernel.DEFAULT_BLOCK_M):
+        return "pallas"
+    return "xla"
 
 
-def default_backend() -> str:
-    """The backend a plan gets when none is requested (innermost
-    ``use_backend`` scope wins; else the process default)."""
+def default_backend(**plan_kw) -> str:
+    """The backend a plan gets when none is requested: the innermost
+    ``use_backend`` scope's, else the device's (:func:`device_default`
+    of ``jax.default_backend()`` and the plan's ``weight_format``,
+    ``stacked`` and ``m``)."""
     stack = getattr(_STATE, "stack", None)
     if stack:
         return stack[-1]
-    return _FALLBACK_DEFAULT
+    return device_default(jax.default_backend(), **plan_kw)
 
 
-def resolve_backend(name: str | None) -> str:
-    name = name or default_backend()
+def resolve_backend(name: str | None, **plan_kw) -> str:
+    name = name or default_backend(**plan_kw)
     get_backend(name)            # validate early, at plan time
     return name
 
@@ -184,16 +211,16 @@ def _run_xla(x_p, w_p, *, block_m, block_n, block_k, out_dtype,
 
 def _run_pallas(x_p, w_p, *, block_m, block_n, block_k, out_dtype,
                 epilogue=None, bias=None, residual=None, split_k=1,
-                interpret=False):
+                layer=None, interpret=False):
     if split_k > 1:
         return _kernel.panel_gemm_splitk(
-            x_p, w_p, bias, residual, split_k=split_k, block_m=block_m,
-            block_n=block_n, block_k=block_k, out_dtype=out_dtype,
-            epilogue=epilogue, interpret=interpret)
-    return _kernel.panel_gemm(x_p, w_p, bias, residual, block_m=block_m,
-                              block_n=block_n, block_k=block_k,
-                              out_dtype=out_dtype, epilogue=epilogue,
-                              interpret=interpret)
+            x_p, w_p, bias, residual, layer, split_k=split_k,
+            block_m=block_m, block_n=block_n, block_k=block_k,
+            out_dtype=out_dtype, epilogue=epilogue, interpret=interpret)
+    return _kernel.panel_gemm(x_p, w_p, bias, residual, layer,
+                              block_m=block_m, block_n=block_n,
+                              block_k=block_k, out_dtype=out_dtype,
+                              epilogue=epilogue, interpret=interpret)
 
 
 def _run_interpret(x_p, w_p, **kw):
@@ -323,8 +350,8 @@ register_backend("xla", _run_xla, needs_blocks=False,
                  run_quant=_run_quant_xla, run_grouped=_run_grouped_xla)
 register_backend("pallas", _run_pallas,
                  description="compiled Pallas panel kernel (TPU deploy)",
-                 run_quant=_run_quant_pallas)
+                 run_quant=_run_quant_pallas, in_place=True)
 register_backend("interpret", _run_interpret,
                  description="Pallas interpreter (kernel validation)",
-                 run_quant=_run_quant_interpret)
+                 run_quant=_run_quant_interpret, in_place=True)
 _BUILTIN = frozenset(_REGISTRY)

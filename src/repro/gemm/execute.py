@@ -4,7 +4,12 @@ plan can remove has been removed at plan/pack time.
 The weight operand may be:
 
   * a ``PackedWeight`` (paid once at model load — the plan's ``prepack``
-    lever): per call only M-padding of the activations remains;
+    lever): per call only M-padding of the activations remains.  A
+    stacked pack (every array leading ``[L, ...]``, the layer scan's
+    weights) runs with ``layer=``: a float32 plan on a backend that
+    reads in place (``pallas``, ``interpret``) reads that layer's panels
+    where they lie; for every other run (``xla``, quantized packs,
+    grouped banks) execute indexes the layer out of each array first;
   * a ``QuantizedPackedWeight`` (repro.quant — quantized AND packed at
     load): the plan carries its ``weight_format`` and the backend's
     dequant-fused ``run_quant`` entry streams codes + scales;
@@ -65,7 +70,7 @@ def _pad_cols(x: jax.Array, to: int) -> jax.Array:
 
 
 def execute(p: GemmPlan, x: jax.Array, w, *, bias=None, residual=None,
-            out_dtype=None, group_sizes=None) -> jax.Array:
+            out_dtype=None, group_sizes=None, layer=None) -> jax.Array:
     """y[..., N_out] = epilogue(x[..., K] @ w), dispatched per ``p``.
 
     Observability (repro.obs): when a flight recorder or manifest scope
@@ -96,13 +101,21 @@ def execute(p: GemmPlan, x: jax.Array, w, *, bias=None, residual=None,
     group: ``group_sizes`` [groups] int32 says how many of the leading
     rows each group's weight takes, in order; rows past their sum come
     back as zeros.
+
+    ``layer`` (an int32 scalar, traced in the layer scan) selects the
+    layer of a stacked pack ``w`` (module docstring).
     """
+    stacked = layer is not None
+    if stacked and not _reads_in_place(p):
+        w = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+            a, layer, 0, keepdims=False), w)
+        layer = None
     if p.groups:
         run = _execute_grouped
         kw = {"group_sizes": group_sizes}
     else:
         run = _execute_impl
-        kw = {"bias": bias, "residual": residual}
+        kw = {"bias": bias, "residual": residual, "layer": layer}
     if not _flight._HOT:
         return run(p, x, w, out_dtype=out_dtype, **kw)
     rec = _flight.active_recorder()
@@ -112,7 +125,8 @@ def execute(p: GemmPlan, x: jax.Array, w, *, bias=None, residual=None,
         # jit-trace time: no wall clock exists per dispatch — register
         # the plan into the open manifest scope instead (once per
         # compilation; obs.report apportions tick time at export)
-        _flight.on_traced(p, lead_m(x))
+        _flight.on_traced(p, lead_m(x), stacked=stacked,
+                          in_place=layer is not None)
     elif rec is not None:
         fenced = rec.fence
         if fenced:
@@ -122,8 +136,16 @@ def execute(p: GemmPlan, x: jax.Array, w, *, bias=None, residual=None,
     return y
 
 
+def _reads_in_place(p: GemmPlan) -> bool:
+    """Whether ``p``'s run reads one layer of a stacked pack where it
+    lies (``execute(..., layer=)``): a float32, ungrouped plan on a
+    backend registered ``in_place``."""
+    return (not p.groups and not p.quantized
+            and _backends.get_backend(p.backend).in_place)
+
+
 def _execute_impl(p: GemmPlan, x: jax.Array, w, *, bias=None,
-                  residual=None, out_dtype=None) -> jax.Array:
+                  residual=None, out_dtype=None, layer=None) -> jax.Array:
     backend = _backends.get_backend(p.backend)
     spec = p.epilogue
     _check((bias is not None) == bool(spec is not None and spec.bias),
@@ -158,6 +180,9 @@ def _execute_impl(p: GemmPlan, x: jax.Array, w, *, bias=None,
                    f"pack density_bucket={w.density_bucket} vs plan "
                    f"density_bucket={p.density_bucket}; the pack was "
                    f"re-quantized since the plan resolved — re-plan")
+    _check(layer is None or (isinstance(w, packing.PackedWeight)
+                             and w.data.ndim == 3),
+           f"layer= reads a stacked pack ({p.describe()})")
     if isinstance(w, packing.PackedWeight):
         _check((w.k, w.n) == (p.k, p.n),
                f"packed weight {w.shape} vs plan ({p.k},{p.n})")
@@ -188,8 +213,8 @@ def _execute_impl(p: GemmPlan, x: jax.Array, w, *, bias=None,
 
     # padded geometry: a ternary pack stores four K rows per codes row,
     # so the codes' leading dim is NOT the padded contraction depth
-    k_pad = w.k_pad if quant else w_p.shape[0]
-    n_pad = w_p.shape[1]
+    k_pad = w.k_pad if quant else w_p.shape[-2]
+    n_pad = w_p.shape[-1]
     if k_pad != p.k:                 # weight K was pack-padded: pad x too
         x2 = _pad_cols(x2, k_pad)
     if backend.needs_blocks:
@@ -206,6 +231,8 @@ def _execute_impl(p: GemmPlan, x: jax.Array, w, *, bias=None,
                f"split_k={p.split_k} does not cut padded K={k_pad} into "
                f"whole block_k={p.block_k} slices ({p.describe()})")
         epi_kw["split_k"] = p.split_k
+    if layer is not None:
+        epi_kw["layer"] = layer
     if spec is not None:
         b2 = r2 = None
         if bias is not None:

@@ -559,13 +559,17 @@ def _plan_key(m: int, n: int, k: int, *, dtype: Any = jnp.float32,
               fused_n_splits: tuple = (), weight_format: str = "fp32",
               decode: bool = False,
               split_k: int | None = None,
-              density_bucket: int = -1) -> tuple:
+              density_bucket: int = -1, stacked: bool = False) -> tuple:
     """The normalized in-memory cache key for a ``plan()`` request
     (``validate`` at index ``_KEY_VALIDATE_IDX``; the persistent store
     key is this tuple minus that element — see :func:`store_key`).
     ``density_bucket`` is appended LAST so the validate slice below and
-    every persisted schema-v1 store key prefix stay position-stable."""
-    backend = _backends.resolve_backend(backend)
+    every persisted schema-v1 store key prefix stay position-stable.
+    ``stacked`` (the weight is a layer scan's stacked pack) only steers
+    the device's default backend, which the key holds."""
+    backend = _backends.resolve_backend(backend, m=m,
+                                        weight_format=weight_format,
+                                        stacked=stacked)
     dtype = _dtype_name(dtype)
     skey = _sharding_key(sharding)
     if epilogue is not None and epilogue.is_noop:
@@ -614,7 +618,7 @@ def plan(m: int, n: int, k: int, *, dtype: Any = jnp.float32,
          fused_n_splits: tuple = (),
          weight_format: str = "fp32", decode: bool | None = None,
          split_k: int | None = None,
-         density_bucket: int = -1) -> GemmPlan:
+         density_bucket: int = -1, stacked: bool = False) -> GemmPlan:
     """Resolve (and cache) the dispatch plan for a ``[m,k] @ [k,n]`` GEMM.
 
     ``backend=None`` takes the current default (``use_backend`` scope or
@@ -643,6 +647,10 @@ def plan(m: int, n: int, k: int, *, dtype: Any = jnp.float32,
     is pinned at 1, and the bucket is plan-keyed so sparse and dense
     ternary plans for one shape never alias.
 
+    ``stacked`` says the weight is a layer scan's stacked pack
+    (``plan_for_packed`` sets it); with ``backend=None`` it steers the
+    device's default (``backends.device_default``).
+
     When a plan store is active (``gemm.use_plan_store`` scope or the
     process default), an in-memory miss consults the store before
     ``_resolve``: a hit adopts the stored plan — skipping the analytic
@@ -661,7 +669,8 @@ def plan(m: int, n: int, k: int, *, dtype: Any = jnp.float32,
                     sharding=sharding, validate=validate, epilogue=epilogue,
                     fused_n_splits=fused_n_splits,
                     weight_format=weight_format, decode=decode,
-                    split_k=split_k, density_bucket=density_bucket)
+                    split_k=split_k, density_bucket=density_bucket,
+                    stacked=stacked)
     (m, n, k, dtype, backend, num_cores, block_m, block_n, block_k, pack,
      transposed, skey, validate, epilogue, fused_n_splits, weight_format,
      decode, split_k, density_bucket) = key
@@ -755,7 +764,11 @@ def plan_for_packed(m: int, pw: packing.PackedWeight, *,
     dtype = "float32" if fmt != "fp32" else pw.dtype
     if getattr(pw, "groups", 0):
         # the grouped arm: the bank's blocks and the row bucket resolve as
-        # for one weight of the bank, and the plan carries the groups
+        # for one weight of the bank, and the plan carries the groups; it
+        # runs on a backend that has a grouped run
+        backend = _backends.resolve_backend(backend)
+        if _backends.get_backend(backend).run_grouped is None:
+            backend = "xla"
         base = plan(m, pw.n, pw.k, dtype=dtype, backend=backend,
                     num_cores=num_cores, block_n=pw.block_n,
                     block_k=pw.block_k, pack=PACK_PREPACKED,
@@ -774,7 +787,8 @@ def plan_for_packed(m: int, pw: packing.PackedWeight, *,
                 sharding=_packed_sharding(pw), epilogue=epilogue,
                 fused_n_splits=pw.n_splits, weight_format=fmt,
                 decode=decode,
-                density_bucket=getattr(pw, "density_bucket", -1))
+                density_bucket=getattr(pw, "density_bucket", -1),
+                stacked=pw.data.ndim == 3)
 
 
 def pack_blocks(n: int, k: int, *, m_hint: int = 128,

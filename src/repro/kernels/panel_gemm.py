@@ -22,6 +22,16 @@ The kernel expects its B operand ALREADY in the packed layout produced by
 pack is paid once at model load (paper lever 2); this kernel is the
 per-call "compute loop only" path.
 
+Stacked packs, read in place: a model's per-layer weights live as one
+stack ``[L, K_pad, N_pad]``, and the layer scan hands the kernel the
+whole stack plus the layer's index (a scalar-prefetch operand that the
+weight's ``BlockSpec`` index map reads), so the kernel DMAs only that
+layer's panels and no per-layer slice is ever copied.  A 2-D pack is a
+stack of one.  On the chip each tile enters the MXU in the dtype the
+default matmul precision gives it (``mxu_operand_dtype``): fp32 panels
+are read as stored and rounded to bf16 in VMEM, so a step reads the
+pack's 4 B per parameter once and makes no bf16 copy of it.
+
 Fused epilogue (the lever ABOVE the store): the baseline kernel flushes
 the fp32 accumulator to HBM only for XLA to re-read it for bias /
 activation / residual.  ``EpilogueSpec`` instead applies those ops on the
@@ -68,6 +78,38 @@ def gate_interpret() -> bool:
     dispatch, so it attests what runs; elsewhere nothing can compile a
     Mosaic kernel and the interpreter stands in."""
     return jax.default_backend() != "tpu"
+
+
+def mxu_operand_dtype(interpret: bool):
+    """The dtype both operand tiles enter the MXU in: bf16 where the
+    kernel is compiled (Mosaic, a TPU) and the matmul precision is the
+    default, the operands XLA's default-precision dot gives the MXU; None
+    (tiles as stored) in the interpreter, which keeps it bit-identical to
+    ``kernels/ref.gemm_blocked``, and at any higher precision.  The cast
+    is explicit because Mosaic lowers an fp32 dot as a multi-pass
+    product: another precision, and compute-bound at M = 128."""
+    if interpret:
+        return None
+    prec = jax.config.jax_default_matmul_precision
+    return jnp.bfloat16 if prec in (None, "default", "bfloat16",
+                                    "fastest") else None
+
+
+def _mxu_dot(x, w, mxu):
+    if mxu is not None:
+        x, w = x.astype(mxu), w.astype(mxu)
+    return jnp.dot(x, w, preferred_element_type=jnp.float32)
+
+
+def _as_stack(w: jax.Array, layer) -> tuple:
+    """``(w [L, K_pad, N_pad], layer [1] int32)``: the stack and the index
+    of the layer the kernel reads; a 2-D pack is a stack of one."""
+    if w.ndim == 2:
+        assert layer is None, "a layer index needs a stacked pack"
+        return w[None], jnp.zeros((1,), jnp.int32)
+    assert w.ndim == 3 and layer is not None, (
+        f"stacked pack {w.shape} needs the layer to read")
+    return w, jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
 
 
 # ------------------------------------------------------------- epilogue
@@ -284,8 +326,8 @@ def vmem_bytes(block_m: int, block_n: int, block_k: int,
     return 2 * (x + w + scales) + acc + out + extra   # 2x: double buffering
 
 
-def _gemm_kernel(x_ref, w_ref, *refs, nk: int,
-                 spec: EpilogueSpec | None = None):
+def _gemm_kernel(layer_ref, x_ref, w_ref, *refs, nk: int,
+                 spec: EpilogueSpec | None = None, mxu=None):
     """One (i, j, k) grid step: acc[i,j] += x[i,k] @ w[k,j].
 
     The Z-discipline of the paper, verbatim in Pallas terms: the accumulator
@@ -294,10 +336,13 @@ def _gemm_kernel(x_ref, w_ref, *refs, nk: int,
     tile's partial sums leak into the next — the exact silent-drift bug the
     paper calls correctness-critical.
 
-    ``refs`` trail the optional epilogue operands: [bias], [residual],
-    then o_ref and the accumulator scratch.  The epilogue runs INSIDE the
-    STZ step, on the fp32 accumulator, before the single cast+store.
+    ``layer_ref`` (the prefetched layer index) is read by the weight's
+    index map only.  ``refs`` trail the optional epilogue operands:
+    [bias], [residual], then o_ref and the accumulator scratch.  The
+    epilogue runs INSIDE the STZ step, on the fp32 accumulator, before
+    the single cast+store.
     """
+    del layer_ref
     refs = list(refs)
     acc_ref = refs.pop()
     o_ref = refs.pop()
@@ -309,9 +354,7 @@ def _gemm_kernel(x_ref, w_ref, *refs, nk: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(
-        x_ref[...], w_ref[...], preferred_element_type=jnp.float32
-    )
+    acc_ref[...] += _mxu_dot(x_ref[...], w_ref[...], mxu)
 
     @pl.when(k == nk - 1)
     def _store():
@@ -326,13 +369,14 @@ def _gemm_kernel(x_ref, w_ref, *refs, nk: int,
         o_ref[...] = acc.astype(o_ref.dtype)
 
 
-def _gemm_glu_kernel(x_ref, wg_ref, wu_ref, *refs, nk: int,
-                     spec: EpilogueSpec):
+def _gemm_glu_kernel(layer_ref, x_ref, wg_ref, wu_ref, *refs, nk: int,
+                     spec: EpilogueSpec, mxu=None):
     """GLU variant: TWO accumulators ride the K grid — the gate and up
     column panels of one horizontally fused weight (``core.packing
     .pack_fused``).  x is loaded once per step and feeds both dots; the
     store step combines ``act(gate) * up`` on the fp32 accumulators, so
     the [M, 2F] intermediate never reaches HBM."""
+    del layer_ref
     refs = list(refs)
     acc_u_ref = refs.pop()
     acc_g_ref = refs.pop()
@@ -348,10 +392,8 @@ def _gemm_glu_kernel(x_ref, wg_ref, wu_ref, *refs, nk: int,
         acc_u_ref[...] = jnp.zeros_like(acc_u_ref)
 
     x = x_ref[...]
-    acc_g_ref[...] += jnp.dot(x, wg_ref[...],
-                              preferred_element_type=jnp.float32)
-    acc_u_ref[...] += jnp.dot(x, wu_ref[...],
-                              preferred_element_type=jnp.float32)
+    acc_g_ref[...] += _mxu_dot(x, wg_ref[...], mxu)
+    acc_u_ref[...] += _mxu_dot(x, wu_ref[...], mxu)
 
     @pl.when(k == nk - 1)
     def _store():
@@ -370,9 +412,10 @@ def _gemm_glu_kernel(x_ref, wg_ref, wu_ref, *refs, nk: int,
 )
 def panel_gemm(
     x: jax.Array,               # [M_pad, K_pad]  activations (pre-padded)
-    w: jax.Array,               # [K_pad, N_pad]  packed weight panels
+    w: jax.Array,               # [K_pad, N_pad] or stacked [L, K_pad, N_pad]
     bias: jax.Array | None = None,       # [N_pad] fp32 (iff epilogue.bias)
     residual: jax.Array | None = None,   # [M_pad, N_out_pad] fp32
+    layer: jax.Array | None = None,      # int32 scalar (iff w is stacked)
     *,
     block_m: int = DEFAULT_BLOCK_M,
     block_n: int = DEFAULT_BLOCK_N,
@@ -381,15 +424,17 @@ def panel_gemm(
     epilogue: EpilogueSpec | None = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """C[M_pad, N_pad] = epilogue(x @ w) via MXU panel tiles.
+    """C[M_pad, N_pad] = epilogue(x @ w[layer]) via MXU panel tiles.
 
     Shapes must be pre-padded to block multiples (the pack does this once at
     load for w; ops.py pads x per call — cheap, M=128 at prefill).  With a
     ``glu`` epilogue ``w`` holds [gate | up] column halves (each half
-    block-aligned) and the output is [M_pad, N_pad // 2].
+    block-aligned) and the output is [M_pad, N_pad // 2].  A stacked ``w``
+    is read in place at ``layer`` (module docstring).
     """
+    w, layer = _as_stack(w, layer)
     m, k = x.shape
-    k2, n = w.shape
+    _, k2, n = w.shape
     assert k == k2, f"contraction mismatch {k} vs {k2}"
     assert m % block_m == 0 and n % block_n == 0 and k % block_k == 0, (
         f"shapes ({m},{n},{k}) not aligned to blocks "
@@ -408,56 +453,66 @@ def panel_gemm(
     assert (bias is not None) == bool(spec is not None and spec.bias)
     assert (residual is not None) == bool(spec is not None and spec.residual)
 
+    # index maps take (i, j, kk, layer_ref); the weight's reads the layer
+    w_block = (pl.Squeezed(), block_k, block_n)
     ops = [x, w]
     in_specs = [
-        pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
-        pl.BlockSpec((block_k, block_n), lambda i, j, kk: (kk, j)),
+        pl.BlockSpec((block_m, block_k), lambda i, j, kk, li: (i, kk)),
+        pl.BlockSpec(w_block, lambda i, j, kk, li: (li[0], kk, j)),
     ]
     half_tiles = n_out // block_n
     if glu:        # up panel: same array, column-offset index map
         ops.append(w)
         in_specs.append(pl.BlockSpec(
-            (block_k, block_n), lambda i, j, kk: (kk, j + half_tiles)))
+            w_block, lambda i, j, kk, li: (li[0], kk, j + half_tiles)))
     if spec is not None and spec.bias:
         b2 = bias.reshape(1, n).astype(jnp.float32)
         ops.append(b2)
-        in_specs.append(pl.BlockSpec((1, block_n), lambda i, j, kk: (0, j)))
+        in_specs.append(pl.BlockSpec((1, block_n),
+                                     lambda i, j, kk, li: (0, j)))
         if glu:
             ops.append(b2)
             in_specs.append(pl.BlockSpec(
-                (1, block_n), lambda i, j, kk: (0, j + half_tiles)))
+                (1, block_n), lambda i, j, kk, li: (0, j + half_tiles)))
     if spec is not None and spec.residual:
         assert residual.shape == (m, n_out), (
             f"residual {residual.shape} vs output ({m},{n_out})")
         ops.append(residual.astype(jnp.float32))
         in_specs.append(pl.BlockSpec((block_m, block_n),
-                                     lambda i, j, kk: (i, j)))
+                                     lambda i, j, kk, li: (i, j)))
 
+    mxu = mxu_operand_dtype(interpret)
     if glu:
-        kernel = functools.partial(_gemm_glu_kernel, nk=nk, spec=spec)
+        kernel = functools.partial(_gemm_glu_kernel, nk=nk, spec=spec,
+                                   mxu=mxu)
         scratch = [pltpu.VMEM((block_m, block_n), jnp.float32),
                    pltpu.VMEM((block_m, block_n), jnp.float32)]
     else:
-        kernel = functools.partial(_gemm_kernel, nk=nk, spec=spec)
+        kernel = functools.partial(_gemm_kernel, nk=nk, spec=spec, mxu=mxu)
         scratch = [pltpu.VMEM((block_m, block_n), jnp.float32)]
 
     return pl.pallas_call(
         kernel,
         name="panel_gemm_glu" if glu else "panel_gemm",
-        grid=(m // block_m, n_out // block_n, nk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(m // block_m, n_out // block_n, nk),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((block_m, block_n),
+                                   lambda i, j, kk, li: (i, j)),
+            scratch_shapes=scratch,
+        ),
         out_shape=jax.ShapeDtypeStruct((m, n_out), out_dtype),
-        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(*ops)
+    )(layer, *ops)
 
 
 # ----------------------------------------------------------- split-K lane
-def _splitk_kernel(x_ref, w_ref, o_ref, acc_ref, *, nks: int):
+def _splitk_kernel(layer_ref, x_ref, w_ref, o_ref, acc_ref, *, nks: int,
+                   mxu=None):
     """One (s, i, j, kk) grid step of the split-K partials pass:
     acc[s,i,j] += x[i, s*nks + kk] @ w[s*nks + kk, j].
 
@@ -469,15 +524,14 @@ def _splitk_kernel(x_ref, w_ref, o_ref, acc_ref, *, nks: int):
     output panels, and ``s`` restores occupancy on the reduction side —
     the paper's fine-panel lever, generalized to K.
     """
+    del layer_ref
     kk = pl.program_id(3)
 
     @pl.when(kk == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(
-        x_ref[...], w_ref[...], preferred_element_type=jnp.float32
-    )
+    acc_ref[...] += _mxu_dot(x_ref[...], w_ref[...], mxu)
 
     @pl.when(kk == nks - 1)
     def _store():
@@ -491,9 +545,10 @@ def _splitk_kernel(x_ref, w_ref, o_ref, acc_ref, *, nks: int):
 )
 def panel_gemm_splitk(
     x: jax.Array,               # [M_pad, K_pad]  activations (pre-padded)
-    w: jax.Array,               # [K_pad, N_pad]  packed weight panels
+    w: jax.Array,               # [K_pad, N_pad] or stacked [L, K_pad, N_pad]
     bias: jax.Array | None = None,
     residual: jax.Array | None = None,
+    layer: jax.Array | None = None,      # int32 scalar (iff w is stacked)
     *,
     split_k: int,
     block_m: int = DECODE_BLOCK_M,
@@ -511,10 +566,12 @@ def panel_gemm_splitk(
     on the combined fp32 accumulator via the shared
     :func:`apply_epilogue` (so fused == unfused stays bit-identical,
     glu included).  Bit-identical to ``ref.gemm_splitk`` at the same
-    ``(block_k, split_k)`` — gated by ``gemm.validate_plan``.
+    ``(block_k, split_k)`` — gated by ``gemm.validate_plan``.  A stacked
+    ``w`` is read in place at ``layer``, as ``panel_gemm`` reads it.
     """
+    w, layer = _as_stack(w, layer)
     m, k = x.shape
-    k2, n = w.shape
+    _, k2, n = w.shape
     assert k == k2, f"contraction mismatch {k} vs {k2}"
     assert split_k >= 1 and k % split_k == 0, (
         f"K={k} not divisible by split_k={split_k}")
@@ -538,25 +595,30 @@ def panel_gemm_splitk(
                                           and spec.residual)
 
     partials = pl.pallas_call(
-        functools.partial(_splitk_kernel, nks=nks),
+        functools.partial(_splitk_kernel, nks=nks,
+                          mxu=mxu_operand_dtype(interpret)),
         name="panel_gemm_splitk",
-        grid=(split_k, m // block_m, n // block_n, nks),
-        in_specs=[
-            pl.BlockSpec((block_m, block_k),
-                         lambda s, i, j, kk: (i, s * nks + kk)),
-            pl.BlockSpec((block_k, block_n),
-                         lambda s, i, j, kk: (s * nks + kk, j)),
-        ],
-        out_specs=pl.BlockSpec((1, block_m, block_n),
-                               lambda s, i, j, kk: (s, i, j)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(split_k, m // block_m, n // block_n, nks),
+            in_specs=[
+                pl.BlockSpec((block_m, block_k),
+                             lambda s, i, j, kk, li: (i, s * nks + kk)),
+                pl.BlockSpec((pl.Squeezed(), block_k, block_n),
+                             lambda s, i, j, kk, li: (li[0], s * nks + kk,
+                                                      j)),
+            ],
+            out_specs=pl.BlockSpec((1, block_m, block_n),
+                                   lambda s, i, j, kk, li: (s, i, j)),
+            scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
+        ),
         out_shape=jax.ShapeDtypeStruct((split_k, m, n), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
         interpret=interpret,
-    )(x, w)
+    )(layer, x, w)
     acc = splitk_combine(partials)
     if spec is not None:
         acc = apply_epilogue(acc, spec, bias=bias, residual=residual)

@@ -15,6 +15,12 @@ read measured records only, the fenced eager dispatches
 GEMM's device time is in a profiler trace (``launch/serve
 --profile-dir``).
 
+Per step manifest it prints the share of the layer scan's projections
+whose weights were read in place from their stacked pack
+(``obs.stacked_in_place_share``): 100 % where every one ran a kernel
+that reads the layer's panels where they lie, 0 % where each layer's
+weight was sliced out first (the ``xla`` backend).
+
 Also prints a span census (event counts and total self time by span
 name) with ``--spans``, and writes the table as JSON with ``--json``.
 """
@@ -23,6 +29,7 @@ from __future__ import annotations
 import argparse
 import json
 
+from repro.obs import recorder as _recorder
 from repro.obs import report as _report
 from repro.obs import spans as _spans
 
@@ -68,6 +75,14 @@ def main(argv=None):
     print("per-shape GEMM characterization (medians of fenced eager "
           "records; apportioned = jitted, not timed):")
     print(_report.format_table(rows))
+    in_place = {k: _recorder.stacked_in_place_share(v)
+                for k, v in sorted(mani.items())}
+    if any(v is not None for v in in_place.values()):
+        print()
+        print("stacked weights read in place, per step:")
+        for key, share in in_place.items():
+            if share is not None:
+                print(f"  {key:<24} {100 * share:6.1f} %")
 
     if args.spans:
         print()
@@ -80,7 +95,8 @@ def main(argv=None):
         with open(args.json, "w") as f:
             json.dump({"table": rows, "events": n_ev,
                        "flight_records": len(fr),
-                       "manifest_steps": len(mani)}, f, indent=1)
+                       "manifest_steps": len(mani),
+                       "stacked_in_place_share": in_place}, f, indent=1)
         print(f"\ntable rows -> {args.json}")
     return rows
 

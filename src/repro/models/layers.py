@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import gemm as _gemm
-from repro.core.packing import PackedWeight
+from repro.core.packing import PackedWeight, StackedLayer
 from repro.kernels.panel_gemm import act_fn as _act_fn
 
 
@@ -31,10 +31,19 @@ def dot_dtype(native):
     return native
 
 
+def _pack_and_layer(w) -> tuple:
+    """(pack, layer) of a packed weight: a ``StackedLayer``'s stacked pack
+    and layer index, else the pack itself and None."""
+    if isinstance(w, StackedLayer):
+        return w.pack, w.layer
+    return w, None
+
+
 def linear(x: jax.Array, w, bias=None, *, softcap: float | None = None,
            residual=None, out_dtype=None) -> jax.Array:
     """x[..., K] @ w[K, N] (+ fused epilogue).  w may be a raw array, a
-    PackedWeight (pre-packed once at model load — paper lever 2), or a
+    PackedWeight (pre-packed once at model load — paper lever 2), one
+    layer of a stacked pack (``StackedLayer``, read in place), or a
     QuantizedPackedWeight (quantized at pack time — the plan picks up
     its format and dispatches the dequant-fused path, repro.quant).
 
@@ -49,15 +58,16 @@ def linear(x: jax.Array, w, bias=None, *, softcap: float | None = None,
     path applies the identical fp32 ops (bit-identical for fp32
     operands).
     """
-    if isinstance(w, PackedWeight):
+    if isinstance(w, (PackedWeight, StackedLayer)):
         spec = None
         if bias is not None or softcap is not None or residual is not None:
             spec = _gemm.EpilogueSpec(bias=bias is not None,
                                       softcap=softcap,
                                       residual=residual is not None)
-        p = _gemm.plan_for_packed(_gemm.lead_m(x), w, epilogue=spec)
-        return _gemm.execute(p, x, w, bias=bias, residual=residual,
-                             out_dtype=out_dtype)
+        pw, layer = _pack_and_layer(w)
+        p = _gemm.plan_for_packed(_gemm.lead_m(x), pw, epilogue=spec)
+        return _gemm.execute(p, x, pw, bias=bias, residual=residual,
+                             out_dtype=out_dtype, layer=layer)
     y = jnp.dot(x, w, preferred_element_type=jnp.float32)
     if bias is not None:
         y = y + bias.astype(jnp.float32)
@@ -68,14 +78,15 @@ def linear(x: jax.Array, w, bias=None, *, softcap: float | None = None,
     return y.astype(out_dtype or x.dtype)
 
 
-def fused_linear(x: jax.Array, w: PackedWeight) -> tuple:
-    """One GEMM pass over a horizontally fused pack (``pack_fused``):
-    streams x once, returns the per-part outputs of the static split map
-    (Q/K/V; MLA's down-projections).  Two HBM reads of x deleted per
-    call vs three separate projections.
+def fused_linear(x: jax.Array, w) -> tuple:
+    """One GEMM pass over a horizontally fused pack (``pack_fused``, or
+    one layer of a stacked one): streams x once, returns the per-part
+    outputs of the static split map (Q/K/V; MLA's down-projections).
+    Two HBM reads of x deleted per call vs three separate projections.
     """
-    p = _gemm.plan_for_packed(_gemm.lead_m(x), w)
-    return _gemm.split_fused(p, _gemm.execute(p, x, w))
+    pw, layer = _pack_and_layer(w)
+    p = _gemm.plan_for_packed(_gemm.lead_m(x), pw)
+    return _gemm.split_fused(p, _gemm.execute(p, x, pw, layer=layer))
 
 
 def grouped_linear(x: jax.Array, w, group_sizes: jax.Array, *,
@@ -83,17 +94,19 @@ def grouped_linear(x: jax.Array, w, group_sizes: jax.Array, *,
     """Rows of ``x [M, K]`` sorted by group through a bank of weights:
     the first ``group_sizes[0]`` rows through the bank's weight 0, the
     next through weight 1, ...; rows past the sizes' sum come back zero.
-    ``w`` is a grouped pack (``packing.pack_grouped``), which dispatches
-    through the plan/execute grouped arm, or a raw ``[G, K, N]`` bank, or
+    ``w`` is a grouped pack (``packing.pack_grouped``, or one layer of a
+    stacked one), which dispatches through the plan/execute grouped arm,
+    or a raw ``[G, K, N]`` bank, or
     for ``glu`` a raw ``(gate, up)`` pair of banks, which run through the
     ``xla`` backend's grouped run; ``glu`` combines ``act(gate) * up`` on
     the fp32 accumulators (the pack's glu epilogue)."""
     out_dtype = out_dtype or x.dtype
     spec = _gemm.EpilogueSpec(glu=glu) if glu else None
-    if isinstance(w, PackedWeight):
-        p = _gemm.plan_for_packed(_gemm.lead_m(x), w, epilogue=spec)
-        return _gemm.execute(p, x, w, group_sizes=group_sizes,
-                             out_dtype=out_dtype)
+    if isinstance(w, (PackedWeight, StackedLayer)):
+        pw, layer = _pack_and_layer(w)
+        p = _gemm.plan_for_packed(_gemm.lead_m(x), pw, epilogue=spec)
+        return _gemm.execute(p, x, pw, group_sizes=group_sizes,
+                             out_dtype=out_dtype, layer=layer)
     bank = jnp.concatenate(w, axis=-1) if glu else w
     return _gemm.get_backend("xla").run_grouped(
         x, bank, group_sizes, out_dtype=out_dtype, epilogue=spec)
@@ -173,17 +186,18 @@ def swiglu(x, w_gate, w_up, w_down, act: str = "silu"):
     return linear(a * linear(x, w_up), w_down)
 
 
-def swiglu_fused(x, w_gate_up: PackedWeight, w_down, act: str = "silu",
-                 residual=None):
+def swiglu_fused(x, w_gate_up, w_down, act: str = "silu", residual=None):
     """Gated FFN over a horizontally fused gate+up pack: ONE kernel pass
     streams x once, carries two accumulators, and combines
     ``act(gate) * up`` on fp32 in the store step — the [.., 2F]
     intermediate never reaches HBM (glu ``EpilogueSpec``).  ``residual``
     rides the down-projection's epilogue (pre-norm blocks), deleting the
-    separate residual-add round-trip too."""
+    separate residual-add round-trip too.  Either pack may be one layer
+    of a stacked pack (``StackedLayer``)."""
     spec = _gemm.EpilogueSpec(glu=act)
-    p = _gemm.plan_for_packed(_gemm.lead_m(x), w_gate_up, epilogue=spec)
-    h = _gemm.execute(p, x, w_gate_up)
+    pw, layer = _pack_and_layer(w_gate_up)
+    p = _gemm.plan_for_packed(_gemm.lead_m(x), pw, epilogue=spec)
+    h = _gemm.execute(p, x, pw, layer=layer)
     return linear(h, w_down, residual=residual)
 
 

@@ -257,8 +257,9 @@ def pack_for_inference(cfg: ModelConfig, params, *, block_n=None,
     and tolerance-gated by the error ledger (docs/quantization.md).
 
     Stacked per-layer weights (L, K, N) pack along their last two dims;
-    lax.scan slices the leading dim, so inside the scan body each
-    PackedWeight carries the 2-D panels the kernel consumes.  ``shardings``
+    the layer scan hands each GEMM the whole stack and the layer's index,
+    and the kernel reads that layer's panels in place (``gemm.execute(...,
+    layer=)``; quantized packs and grouped banks are sliced).  ``shardings``
     (a matching pytree) re-places each packed array so no resharding
     appears per call.
     """

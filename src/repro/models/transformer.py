@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.packing import PackedWeight, StackedLayer
 from repro.models import attention as A
 from repro.models import layers as L
 from repro.models import moe as M
@@ -225,6 +226,18 @@ def _layer_forward(cfg, lp, x, *, window_l, positions, cache_l, cache_index,
 
 
 # ----------------------------------------------------------------- forward
+def _split_stack(stack) -> tuple:
+    """A stacked layer tree as (xs, packs, treedef): the flat leaves the
+    layer scan slices, with None where a pack is handed down whole
+    instead, and those packs, with None elsewhere."""
+    leaves, treedef = jax.tree.flatten(
+        stack, is_leaf=lambda a: isinstance(a, PackedWeight))
+    is_pack = [isinstance(a, PackedWeight) for a in leaves]
+    xs = [None if f else a for a, f in zip(leaves, is_pack)]
+    packs = [a if f else None for a, f in zip(leaves, is_pack)]
+    return xs, packs, treedef
+
+
 def forward(cfg, params, inputs, *, cache=None, mode: str = "train",
             logits_mode: str = "all", shard_fn=None, page_size=None,
             logit_index=None):
@@ -291,47 +304,60 @@ def forward(cfg, params, inputs, *, cache=None, mode: str = "train",
     # and the layer index down instead: the KV path scatters into and
     # gathers from the pool at layer ``li`` in place (runtime/kv_cache),
     # so no layer's whole pool is sliced, copied or written back.
+    # The stacked packs go down the same way: they stay out of the scan's
+    # xs (whose slice XLA would copy a layer at a time), and each layer
+    # hands its GEMMs the whole stack and its index (``StackedLayer``);
+    # gemm.execute reads the layer in place or slices it, as the plan's
+    # run can.  Norms and raw weights ride xs.
     # A config's leading dense layers run as a scan of their own before
     # the main one; the layer index both hand down counts from the stack's
     # first layer, so every layer addresses its own rows of one pool.
-    def body(carry, xs):
-        x, aux, cl = carry
-        lp, w_l, li = xs
-        c_l = None
-        if cl is not None and paged_extra is not None:
-            c_l = {**cl, **paged_extra, "layer": li}
-        elif cl is not None:
-            with jax.named_scope("kv_view"):
-                c_l = jax.tree.map(lambda buf: jax.lax.dynamic_index_in_dim(
-                    buf, li, 0, keepdims=False), cl)
-        x, new_c, a, rows = _layer_forward(
-            cfg, lp, x, window_l=w_l, positions=positions, cache_l=c_l,
-            cache_index=cache_index, mode=mode, shard_fn=shard,
-            page_size=page_size)
-        if new_c is not None and paged_extra is not None:
-            cl = new_c
-        elif new_c is not None:
-            with jax.named_scope("kv_write"):
-                cl = jax.tree.map(
-                    lambda buf, new: jax.lax.dynamic_update_index_in_dim(
-                        buf, new.astype(buf.dtype), li, 0), cl, new_c)
-        x = shard(x, "batch", "seq", "d_model")
-        return (x, aux + a, cl), rows
+    def scan_layers(carry, stack, windows, first):
+        sliced, packs, treedef = _split_stack(stack)
 
-    if mode == "train" and cfg.remat:
-        policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-                  if cfg.remat_policy == "dots"
-                  else jax.checkpoint_policies.nothing_saveable)
-        body = jax.checkpoint(body, policy=policy)
+        def body(carry, xs):
+            x, aux, cl = carry
+            lp, w_l, li = xs
+            lp = treedef.unflatten([
+                leaf if pw is None else StackedLayer(pw, li - first)
+                for leaf, pw in zip(lp, packs)])
+            c_l = None
+            if cl is not None and paged_extra is not None:
+                c_l = {**cl, **paged_extra, "layer": li}
+            elif cl is not None:
+                with jax.named_scope("kv_view"):
+                    c_l = jax.tree.map(
+                        lambda buf: jax.lax.dynamic_index_in_dim(
+                            buf, li, 0, keepdims=False), cl)
+            x, new_c, a, rows = _layer_forward(
+                cfg, lp, x, window_l=w_l, positions=positions, cache_l=c_l,
+                cache_index=cache_index, mode=mode, shard_fn=shard,
+                page_size=page_size)
+            if new_c is not None and paged_extra is not None:
+                cl = new_c
+            elif new_c is not None:
+                with jax.named_scope("kv_write"):
+                    cl = jax.tree.map(
+                        lambda buf, new: jax.lax.dynamic_update_index_in_dim(
+                            buf, new.astype(buf.dtype), li, 0), cl, new_c)
+            x = shard(x, "batch", "seq", "d_model")
+            return (x, aux + a, cl), rows
+
+        if mode == "train" and cfg.remat:
+            policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+                      if cfg.remat_policy == "dots"
+                      else jax.checkpoint_policies.nothing_saveable)
+            body = jax.checkpoint(body, policy=policy)
+        n = windows.shape[0]
+        return jax.lax.scan(body, carry, (sliced, windows,
+                                          jnp.arange(first, first + n)))
 
     carry = (x, jnp.zeros((), jnp.float32), cache_layers)
     nd = cfg.first_k_dense
     if nd:
-        carry, _ = jax.lax.scan(body, carry, (
-            params["dense_layers"], w_arr[:nd], jnp.arange(nd)))
-        w_arr = w_arr[nd:]
-    xs = (params["layers"], w_arr, jnp.arange(nd, cfg.num_layers))
-    (x, aux, new_cache_layers), expert_rows = jax.lax.scan(body, carry, xs)
+        carry, _ = scan_layers(carry, params["dense_layers"], w_arr[:nd], 0)
+    (x, aux, new_cache_layers), expert_rows = scan_layers(
+        carry, params["layers"], w_arr[nd:], nd)
 
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps, cfg.norm_plus_one)
     if logits_mode == "last":
@@ -345,7 +371,6 @@ def forward(cfg, params, inputs, *, cache=None, mode: str = "train",
             else params["lm_head"])
     logits = None
     if logits_mode != "none":
-        from repro.core.packing import PackedWeight
         with jax.named_scope("lm_head"):
             if isinstance(head, PackedWeight) and cfg.logit_softcap:
                 # packed LM head: the tanh softcap runs on the fp32

@@ -39,7 +39,7 @@ from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
 from repro.obs.recorder import (FlightRecorder, active_recorder,
                                 manifest_scope, manifests, no_recorder,
                                 reset_manifests, set_recorder,
-                                use_recorder)
+                                stacked_in_place_share, use_recorder)
 from repro.obs.report import (format_table, gemm_events, per_shape_table,
                               synthesize_gemm_events)
 from repro.obs.spans import (Tracer, active_tracer, current_span, instant,
@@ -55,6 +55,7 @@ __all__ = [
     "manifest_scope", "manifests", "measure", "no_recorder", "no_tracer",
     "per_shape_table", "publish_prefix_stats", "publish_serve_stats",
     "reset_manifests", "set_metrics", "set_recorder", "set_tracer",
-    "span", "synthesize_gemm_events", "use_metrics", "use_recorder",
+    "span", "stacked_in_place_share", "synthesize_gemm_events",
+    "use_metrics", "use_recorder",
     "use_tracer", "validate_chrome_trace",
 ]

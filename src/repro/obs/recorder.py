@@ -227,14 +227,20 @@ class FlightRecorder:
                 "fence": self.fence}
 
 
-def on_traced(p, m: int) -> None:
+def on_traced(p, m: int, *, stacked: bool = False,
+              in_place: bool = False) -> None:
     """Called by ``gemm.execute`` when a dispatch ran on tracers (i.e.
     at jit-trace time).  Registers the plan's static record into the
     open manifest scope, if any — once per compilation, zero
-    per-dispatch cost at run time."""
+    per-dispatch cost at run time.  ``stacked``: the weight came as a
+    stacked pack and a layer index (the layer scan's projections);
+    ``in_place``: the backend read that layer's panels where they lie,
+    with no per-layer slice or copy (``stacked_in_place_share``)."""
     mkey = getattr(_STATE, "mkey", None)
     if mkey is not None:
-        _MANIFESTS.setdefault(mkey, []).append(_plan_record(p, m))
+        rec = _plan_record(p, m)
+        rec.update(stacked=stacked, in_place=in_place)
+        _MANIFESTS.setdefault(mkey, []).append(rec)
     rec = active_recorder()
     if rec is not None:
         rec.note_traced()
@@ -263,6 +269,15 @@ def _roofline_frac(rec: dict, wall_s: float) -> float | None:
     except Exception:
         pass
     return None
+
+
+def stacked_in_place_share(records: list[dict]) -> float | None:
+    """Of one step manifest's dispatches whose weight came as a stacked
+    pack, the share read in place; None when the step has none."""
+    stacked = [r for r in records if r.get("stacked")]
+    if not stacked:
+        return None
+    return sum(bool(r.get("in_place")) for r in stacked) / len(stacked)
 
 
 def reset_manifests() -> None:

@@ -93,8 +93,11 @@ class Engine:
                  keep_fp32=("head", "embed"),
                  plan_store=None):
         """``backend`` pins this engine's GEMM backend (a registry name
-        from ``repro.gemm.list_backends()``); None keeps the process
-        default.  The choice is scoped to this engine's traces — two
+        from ``repro.gemm.list_backends()``); None takes the device's
+        default per plan (``gemm.backends.device_default``), except on a
+        ``mesh`` of several devices, which pins ``xla``: the panel kernel
+        is one device's custom call, and XLA cannot partition it over a
+        mesh.  The choice is scoped to this engine's traces — two
         engines with different backends coexist in one process, which the
         old ``REPRO_GEMM_IMPL`` process global could not express.
 
@@ -121,6 +124,8 @@ class Engine:
         GEMMs run the dequant-fused path, and the error ledger
         tolerance-gates each pack.  Requires ``packed=True``.
         """
+        if backend is None and mesh is not None and mesh.size > 1:
+            backend = "xla"
         self.cfg = cfg
         self.mesh = mesh
         self.max_len = max_len

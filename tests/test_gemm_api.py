@@ -226,6 +226,79 @@ def test_use_backend_scope_nests():
         assert G.default_backend() == "xla"
 
 
+@pytest.mark.parametrize("platform,fmt,stacked,m,want", [
+    ("tpu", "fp32", True, 128, "pallas"),     # a layer's pack, one panel
+    ("tpu", "fp32", True, 4, "pallas"),       # the decode step
+    ("tpu", "fp32", True, 1024, "xla"),       # rows past one row panel
+    ("tpu", "fp32", False, 1, "xla"),         # a 2-D weight: the LM head
+    ("tpu", "int8", True, 128, "xla"),        # quantized packs
+    ("cpu", "fp32", True, 128, "xla"),
+])
+def test_device_default_backend(platform, fmt, stacked, m, want):
+    """Off the TPU every plan runs ``xla``; on it the panel kernel runs
+    the float32 plans of stacked packs up to one row panel of rows."""
+    assert G.backends.device_default(platform, weight_format=fmt,
+                                     stacked=stacked, m=m) == want
+
+
+def test_plan_for_a_stacked_pack_takes_the_device_default(monkeypatch):
+    """``plan_for_packed`` tells the default whether the pack is stacked
+    (and a scope still wins over the device's choice)."""
+    monkeypatch.setattr(G.backends.jax, "default_backend", lambda: "tpu")
+    stack = packing.PackedWeight(data=jnp.zeros((2, 256, 256)), n=256,
+                                 k=256, block_n=128, block_k=128)
+    flat = packing.PackedWeight(data=jnp.zeros((256, 256)), n=256, k=256,
+                                block_n=128, block_k=128)
+    assert G.plan_for_packed(128, stack).backend == "pallas"
+    assert G.plan_for_packed(256, stack).backend == "xla"
+    assert G.plan_for_packed(128, flat).backend == "xla"
+    with G.use_backend("interpret"):
+        assert G.plan_for_packed(128, flat).backend == "interpret"
+
+
+_MESH_SCRIPT = """
+import jax, jax.numpy as jnp
+from repro import obs
+from repro.gemm import backends
+from repro.launch.mesh import make_host_mesh
+from repro.models import model_zoo
+from repro.runtime.serve_loop import Engine
+chip = backends.device_default
+backends.device_default = lambda _platform, **kw: chip("tpu", **kw)
+cfg = model_zoo.reduced_config(model_zoo.get_config("stablelm-3b"))
+params = model_zoo.build(cfg)
+tokens = jnp.zeros((2, 8), jnp.int32)
+for mesh in (None, make_host_mesh()):
+    eng = Engine(cfg, params, mesh=mesh, max_len=32, packed=True)
+    jax.eval_shape(eng._prefill, eng.params, tokens)
+    recs = obs.manifests().pop("prefill_m16")
+    print(mesh is not None and mesh.size, eng.backend,
+          sorted({r["backend"] for r in recs if r["stacked"]}))
+    if mesh is not None:
+        eng.prefill(tokens)[0].block_until_ready()
+        print("ran")
+"""
+
+
+def test_a_multi_device_engine_keeps_the_kernel_off_its_plans():
+    """The panel kernel is one device's custom call, which XLA cannot
+    partition: with the TPU's default in force, an engine on one device
+    runs its layer projections on ``pallas`` and one whose mesh spans
+    two devices pins ``xla`` (and its step runs; on the kernel the CPU
+    could not even lower it)."""
+    import os
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=2")
+    r = subprocess.run([sys.executable, "-c", _MESH_SCRIPT], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert r.stdout.splitlines() == [
+        "False None ['pallas']", "2 xla ['xla']", "ran"]
+
+
 # ------------------------------------------- epilogue / fusion bitexact
 EPI_SPECS = [
     G.EpilogueSpec(bias=True),

@@ -468,3 +468,34 @@ def test_trace_report_medians_read_measured_records_only():
     assert rows[16]["median_roofline_frac"] == 0.5
     table = obs.format_table(obs.per_shape_table(trace))
     assert "—" in table.splitlines()[2]         # m=8: not measured
+
+
+@pytest.mark.parametrize("backend,share", [("interpret", 1.0), ("xla", 0.0)])
+def test_manifest_counts_stacked_weights_read_in_place(engine, backend,
+                                                       share, tmp_path,
+                                                       capsys):
+    """Each traced dispatch records whether its weight came as a stacked
+    pack and whether the backend read that layer in place: every layer
+    projection of a step on the kernel (``interpret``), none on ``xla``,
+    which slices the layer out first; the LM head is no stacked pack.
+    ``launch/trace_report`` prints the share per step."""
+    import jax
+    from repro.launch import trace_report
+    from repro.models import transformer
+    cfg, eng = engine
+    key = f"in_place_{backend}"
+    tokens = jnp.asarray(_requests(cfg, [6])[0][None])
+    with gemm.use_backend(backend), obs.manifest_scope(key):
+        jax.eval_shape(lambda p, t: transformer.forward(
+            cfg, p, t, mode="prefill"), eng.params, tokens)
+    recs = obs.manifests()[key]
+    stacked = [r for r in recs if r["stacked"]]
+    assert len(stacked) == 4          # wqkv, wo, gate/up, down
+    assert [r for r in recs if not r["stacked"]][0]["n"] == cfg.vocab_size
+    assert obs.stacked_in_place_share(recs) == share
+    trace = {"traceEvents": [], "gemmManifests": {key: recs}}
+    path = tmp_path / "t.json"
+    path.write_text(json.dumps(trace))
+    trace_report.main([str(path)])
+    printed = capsys.readouterr().out.split("read in place, per step:")[1]
+    assert f"{key:<24} {100 * share:6.1f} %" in printed

@@ -6,8 +6,9 @@ over budget.  These tests compile each kernel at stablelm-3b's published
 widths, at the blocks the dispatch policy resolves for them, against a
 described ``v5e:2x2`` topology — no chip needed — and check that the
 compiled program holds the kernel (``tpu_custom_call``).  The paged
-serving steps compile at the benchmark cells' shapes too, and must index
-the KV pool in place.
+serving steps compile at the benchmark cells' shapes too, on the chip's
+dispatch default, and must index the KV pool and the stacked weight
+packs in place.
 
 The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU compiler library, so a
@@ -25,7 +26,9 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 import _kv_guard
+import _weight_guard
 from repro import gemm
+from repro.gemm import backends
 from repro.kernels.flash_attention import flash_attention
 from repro.models import model_zoo
 from repro.quant import formats as F
@@ -305,9 +308,15 @@ def cell_steps(one_chip):
                 model_zoo.abstract_params(cfg)), one_chip)
             eng = Engine(cfg, packed, max_len=max_len, packed=True)
             num_pages = slots * max_len // CELL_PAGE
-            texts = _kv_guard.step_texts(
-                eng, slots=slots, chunk=chunk, page_size=CELL_PAGE,
-                num_pages=num_pages, sharding=one_chip)
+            with pytest.MonkeyPatch.context() as mp:
+                # the host is a CPU; the steps are traced with the plans
+                # the chip they compile for would give them
+                chip = backends.device_default
+                mp.setattr(backends, "device_default",
+                           lambda _platform, **kw: chip("tpu", **kw))
+                texts = _kv_guard.step_texts(
+                    eng, slots=slots, chunk=chunk, page_size=CELL_PAGE,
+                    num_pages=num_pages, sharding=one_chip)
             layer = _kv_guard.layer_pool_elements(
                 eng, num_pages=num_pages, page_size=CELL_PAGE)
             cache[cell] = (eng, texts, layer)
@@ -352,10 +361,29 @@ def test_latent_steps_run_grouped_expert_gemms(cell_steps):
 
 
 @pytest.mark.parametrize("cell", sorted(CELLS))
+def test_dense_steps_read_the_weight_packs_in_place(cell_steps, cell):
+    """The GQA cells' compiled prefill-chunk, decode and megastep steps
+    convert, slice or copy no stacked weight pack (no bf16 copy of a
+    stack, no layer's slice copied out of it): four panel kernels (QKV,
+    W_o, gate/up, down) read them in place, and the benchmark's GEMM
+    rule picks every one of them."""
+    eng, texts, _ = cell_steps(cell)
+    shapes = _weight_guard.stack_shapes(eng.params)
+    for name, text in texts.items():
+        assert _weight_guard.weight_copies(text, shapes) == [], name
+        assert len(_weight_guard.panel_kernels(text)) == 4, name
+        assert _weight_guard.kernels_unpicked(text) == set(), name
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
 def test_dense_cells_pick_the_same_gemm_ops(cell_steps, cell):
     """The GQA cells' compiled prefill steps give the benchmark's GEMM
-    rule (``chipbench/trace_reduce.gemm_op_names``) the 15 ops it picked
-    before the MLA/MoE path joined the shared scan (PERF.md)."""
+    rule (``chipbench/trace_reduce.gemm_op_names``) seven ops: the four
+    panel kernels that read the layers' stacked packs in place, and the
+    LM head's one-row product, XLA's multiply-reduce fusion over the
+    float32 head with the two instructions it fuses (PERF.md)."""
     from chipbench import trace_reduce
     _, texts, _ = cell_steps(cell)
-    assert len(trace_reduce.gemm_op_names(texts["prefill"])) == 15
+    picks = trace_reduce.gemm_op_names(texts["prefill"])
+    assert _weight_guard.panel_kernels(texts["prefill"]) <= picks
+    assert len(picks) == 7
