@@ -1,18 +1,14 @@
 """End-to-end serving driver — the paper's deployment scenario.
 
 Loads a model, packs every projection weight once (untimed model-load
-phase, paper §3.2), then serves a queue of mixed-length requests two
-ways over the SAME packed engine:
-
-  * the legacy phase-locked loop (``serve_chunked``): sequential static
-    batches, every slot waiting for its chunk's slowest request;
-  * real continuous batching (``serve``): slot refill mid-generation,
-    paged KV cache, chunked prefill admission (docs/serving.md).
-
-and reports useful generated tokens/s plus per-request latency
-percentiles for the continuous pool — the framework-native analogue of
-the paper's llama.cpp integration (§4.7), where the pre-packed path
-lifted full-forward throughput 291→420 tok/s.
+phase, paper §3.2), then serves a queue of mixed-length requests through
+continuous batching (``serve``: slot refill mid-generation, paged KV
+cache, chunked prefill admission; docs/serving.md), and reports useful
+generated tokens/s plus per-request latency percentiles for the pool —
+the framework-native analogue of the paper's llama.cpp integration
+(§4.7), where the pre-packed path lifted full-forward throughput
+291→420 tok/s.  These are host timings of a reduced model, not a
+measurement of chip speed (that is chipbench/).
 
 Run: PYTHONPATH=src python examples/serve_batched.py [--arch deepseek-7b]
      [--requests 12] [--prompt-len 64] [--max-new 16] [--batch-slots 4]
@@ -65,19 +61,9 @@ def main():
     print(f"model load + pack (untimed): {time.perf_counter() - t0:.2f}s  "
           f"[fused GEMMs {'off' if args.no_fusion else 'on'}]")
 
-    # warm both paths' traces (compile is part of model load, not serving)
-    warm = requests[:2]
-    eng.serve_chunked(warm, batch_slots=args.batch_slots,
-                      prompt_len=args.prompt_len, max_new_tokens=2)
-    eng.serve(warm, batch_slots=args.batch_slots, max_new_tokens=2,
+    # warm the traces (compile is part of model load, not serving)
+    eng.serve(requests[:2], batch_slots=args.batch_slots, max_new_tokens=2,
               prefill_chunk=args.prefill_chunk, page_size=args.page_size)
-
-    t0 = time.perf_counter()
-    eng.serve_chunked(requests, batch_slots=args.batch_slots,
-                      prompt_len=args.prompt_len, max_new_tokens=mns)
-    t_old = time.perf_counter() - t0
-    print(f"{'phase-locked (legacy)':24s} {useful / t_old:8,.0f} useful "
-          f"tok/s  ({useful} tokens, {t_old:.2f}s)")
 
     t0 = time.perf_counter()
     outs, stats = eng.serve(requests, batch_slots=args.batch_slots,
@@ -86,8 +72,7 @@ def main():
                             page_size=args.page_size)
     t_new = time.perf_counter() - t0
     print(f"{'continuous batching':24s} {useful / t_new:8,.0f} useful "
-          f"tok/s  ({len(outs)} requests, {t_new:.2f}s, "
-          f"{t_old / t_new:.2f}x)")
+          f"tok/s  ({len(outs)} requests, {useful} tokens, {t_new:.2f}s)")
     qw95 = stats.percentile("queue_wait_s", 95) * 1e3
     tf95 = stats.percentile("ttft_s", 95) * 1e3
     print(f"  queue wait p95 {qw95:.1f} ms | TTFT p95 {tf95:.1f} ms "

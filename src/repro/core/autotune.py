@@ -14,8 +14,7 @@ wins all twelve shapes.  Same protocol here:
   3. one (block_n, block_k) pair is deployed uniformly across shapes
      (the paper: "it is not tuned against any one comparison").
 
-Run via benchmarks/table5_panel_sweep.py; the deployed defaults in
-kernels/panel_gemm.py record the result.
+The deployed defaults in kernels/panel_gemm.py record the result.
 """
 from __future__ import annotations
 
@@ -98,7 +97,7 @@ def deployed_pair(shapes, **kw) -> tuple[int, int]:
 # The paper's sharpest deployment finding is that mis-tuning the single
 # column-panel width costs ~2x — an argument for MEASURING candidate
 # plans rather than trusting the analytic model above.  Protocol (the
-# benchmark suite's §4.1 discipline, see benchmarks/common.py):
+# paper's §4.1 timing discipline):
 #
 #   1. the analytic ``scheduler.plan`` prediction PRUNES the candidate
 #      block triples (and decode split-K counts) to a short list that

@@ -15,8 +15,7 @@ On TPU the per-call costs a stateless GEMM pays are the analogues we remove:
 
 ``PackedWeight`` is a pytree, so it flows through jit/pjit/scan/checkpoint
 like any array.  The stateless baseline (pack-every-call) is a plan
-decision (``gemm.plan(..., pack=PACK_PERCALL)``) and is benchmarked
-against this path (benchmarks/table3_prefill_gemms.py).
+decision (``gemm.plan(..., pack=PACK_PERCALL)``).
 """
 from __future__ import annotations
 

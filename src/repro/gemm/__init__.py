@@ -30,8 +30,8 @@ from repro.gemm.policy import (DECODE_M_BUCKETS, DECODE_SPLIT_K_CANDIDATES,
                                bucket_m, decode_lane, in_decode_lane,
                                pack_blocks, plan, plan_cache_clear,
                                plan_cache_info, plan_for_packed,
-                               policy_table, sparse_threshold,
-                               store_key, vmem_clamped_count)
+                               sparse_threshold, store_key,
+                               vmem_clamped_count)
 from repro.kernels.panel_gemm import apply_epilogue, splitk_combine
 
 __all__ = [
@@ -46,7 +46,7 @@ __all__ = [
     "host_fingerprint", "in_decode_lane", "lead_m", "list_backends",
     "no_plan_store", "pack_blocks", "pack_for_plan", "plan",
     "plan_cache_clear", "plan_cache_info", "plan_for_packed",
-    "plan_store_info", "policy_table", "register_backend",
+    "plan_store_info", "register_backend",
     "set_plan_store", "sparse_threshold", "split_fused",
     "splitk_combine", "store_key",
     "unregister_backend", "use_backend", "use_plan_store",

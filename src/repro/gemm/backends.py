@@ -229,10 +229,9 @@ def _run_interpret(x_p, w_p, **kw):
 
 # Dequant-fused runs (repro.quant): same trio, streaming codes + scales.
 # The xla run dequantizes inside ONE jitted computation, so XLA fuses
-# the cast/scale into the dot's operand path — the dequant-THEN-sgemm
-# baseline (benchmarks/table8_quant.py) instead materializes the fp32
-# weight as a separate dispatch, which is exactly the round-trip the
-# fused path deletes.
+# the cast/scale into the dot's operand path — a dequant-THEN-sgemm
+# baseline instead materializes the fp32 weight as a separate dispatch,
+# which is exactly the round-trip the fused path deletes.
 def _run_quant_xla(x_p, codes, scales, *, weight_format, block_m, block_n,
                    block_k, out_dtype, epilogue=None, bias=None,
                    residual=None, split_k=1, sparse_layout=None):

@@ -15,7 +15,7 @@ The weight operand may be:
     dequant-fused ``run_quant`` entry streams codes + scales;
   * a raw array (``[K, N]``, or ``[N, K]`` when the plan was built with
     ``transposed=True``): the transpose+pad runs inside the call — the
-    honest cblas/BNNSMatMul baseline the benchmarks compare against.
+    honest cblas/BNNSMatMul baseline of the paper.
 
 Numerics contract (the paper's discipline): for a given block triple the
 result is bit-identical across packed / per-call operands and across the
@@ -201,7 +201,7 @@ def _execute_impl(p: GemmPlan, x: jax.Array, w, *, bias=None,
                f"weight {tuple(ww.shape)} vs plan ({p.k},{p.n})")
         # The pack decision is the PLAN's, not the backend's: the percall
         # baseline pays its transpose+pad even when the compute loop runs
-        # through the shape-agnostic xla dot (table3/table6 protocol).
+        # through the shape-agnostic xla dot.
         # PACK_NONE (the raw-dot analogue) skips it — unless the backend
         # is a panel kernel that physically needs the blocked layout.
         if p.pack != PACK_NONE or backend.needs_blocks:

@@ -47,7 +47,7 @@ from repro.obs import spans as _spans
 
 # Occupancy target of the fine-panel lever: the paper tunes panels against
 # the two AMX blocks; the TPU analogue scores candidates against this many
-# parallel compute units (table5's sweep setting).
+# parallel compute units.
 DEFAULT_NUM_CORES = 8
 
 # Column-panel widths the fine lever considers (the paper's Nc in
@@ -88,7 +88,7 @@ def plan_cache_info() -> CacheInfo:
 def vmem_clamped_count() -> int:
     """How many currently-cached plans had their blocks shrunk to honor
     the kernel VMEM budget (serving observability: surfaced in
-    ``GenStats``/``ServeStats`` and the benchmark reports)."""
+    ``GenStats``/``ServeStats``)."""
     with _cache_lock:
         return sum(1 for p in _cache.values() if p.vmem_clamped)
 
@@ -378,10 +378,10 @@ def _resolve(m: int, n: int, k: int, *, dtype: str, backend: str,
     if split_k is None:
         # the split lever targets the PANEL-GRID backends: occupancy is
         # a property of the kernel grid, and a shape-agnostic backend
-        # (xla) has no reduction-side grid to fill — measured on the
-        # CPU host the restructure there is a wash-to-loss
-        # (BENCH_decode's lane_splitk context column), so the policy
-        # keeps split_k=1 for it.  Explicit split_k= overrides remain
+        # (xla) has no reduction-side grid to fill, so the policy keeps
+        # split_k=1 for it.  The only reading behind that rule is a CPU
+        # one (a wash-to-loss on the host); it awaits a chip measurement
+        # (ROADMAP debt 4).  Explicit split_k= overrides remain
         # available on every backend.
         split_k = (_decode_split_k(n, k, k_pad, block_m=bm, block_n=bn,
                                    block_k=bk, dtype=dtype,
@@ -624,7 +624,7 @@ def plan(m: int, n: int, k: int, *, dtype: Any = jnp.float32,
     ``backend=None`` takes the current default (``use_backend`` scope or
     the process default — never the removed ``REPRO_GEMM_IMPL`` env
     var).  Explicit ``block_*`` / ``pack`` override the policy
-    (benchmark sweeps, baseline paths); ``validate=True`` runs the
+    (autotune sweeps, baseline paths); ``validate=True`` runs the
     autotune bit-exactness gate on the resolved blocks (and
     ``epilogue`` / ``split_k``, if any) before the plan is issued.
     ``epilogue`` / ``fused_n_splits`` / ``weight_format`` are
@@ -845,25 +845,3 @@ def sparse_threshold(m: int = 128, n: int = 4096, k: int = 4096, *,
         if t < dense:
             return gs
     return 1.0
-
-
-def policy_table(shapes, *, m: int | None = None,
-                 num_cores: int = DEFAULT_NUM_CORES) -> list[dict]:
-    """Lever resolution for a set of ``(m, n, k)`` (or ``(n, k)`` with
-    ``m=``) shapes — the paper's twelve-shape table, as data."""
-    rows = []
-    for s in shapes:
-        if len(s) == 2 and m is None:
-            raise ValueError(
-                f"2-tuple shape {s} needs the m= argument (the row count "
-                f"the plans target), e.g. policy_table(shapes, m=128)")
-        mm, n, k = (m, *s) if len(s) == 2 else s
-        p = plan(mm, n, k, num_cores=num_cores)
-        rows.append({
-            "M": p.m, "N": p.n, "K": p.k, "lever": p.lever,
-            "prepack": p.prepack, "block_n": p.block_n,
-            "block_k": p.block_k, "panels": p.grid[0] * p.grid[1],
-            "occupancy": round(p.occupancy, 3),
-            "pred_ms": round(p.t_pred * 1e3, 4),
-        })
-    return rows

@@ -2,7 +2,7 @@
 
 ``repro.gemm`` (plan/execute) is the deployment surface
 (packed/per-call/xla paths); these wrappers expose the raw kernels with
-shape massaging for tests, benchmarks, and the attention layer.
+shape massaging for tests and the attention layer.
 """
 from __future__ import annotations
 

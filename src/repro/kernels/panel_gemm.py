@@ -55,10 +55,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 # Deployed block pair (the (Nc, Kc) analogue), fixed by the offline sweep
 # in core/autotune.py under the bit-exactness gate (winner over the twelve
-# paper shapes; re-derived in benchmarks/table5_panel_sweep.py).  The deep
-# K block mirrors the paper's Kc = 2,048 — affordable only because the
-# weight is pre-packed (paper §3.3); packing.fit_block shrinks it per
-# weight when K is not block-divisible.  See EXPERIMENTS.md §Perf.
+# paper shapes).  That sweep ranks by the scheduler model's predicted
+# time; no chip measurement backs the pair yet (ROADMAP debt 4).  The
+# deep K block mirrors the paper's Kc = 2,048 — affordable only because
+# the weight is pre-packed (paper §3.3); packing.fit_block shrinks it per
+# weight when K is not block-divisible.
 DEFAULT_BLOCK_M = 128     # the paper's M = S = 128 prefill row panel
 DEFAULT_BLOCK_N = 512     # column-panel width (lever-1 knob)
 DEFAULT_BLOCK_K = 2048    # K-blocking depth (lever-2-unlocked knob)

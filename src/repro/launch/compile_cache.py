@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
 The entry points that compile the serving steps (``launch/serve``,
-``launch/autotune``, ``chip_smoke.py``, the benchmarks) call
+``launch/autotune``, ``chip_smoke.py``, ``chipbench``) call
 :func:`enable_compile_cache` once, before their first compile, so a
 second process on the same machine reuses the executables of the first.
 

@@ -36,7 +36,7 @@ ARCHS = {
 }
 
 # The paper's Table 3: twelve LLM prefill GEMMs at M = S = 128, as
-# (model, op, N, K).  These drive benchmarks/table3 and the GEMM tests.
+# (model, op, N, K).  These drive the GEMM tests and the autotune sweep.
 PAPER_GEMM_SHAPES = [
     ("gpt2-style", "qkv", 2048, 2048),
     ("gpt2-style", "ffn1", 8192, 2048),

@@ -7,7 +7,7 @@ with the fraction-of-roofline from ``roofline.analysis.gemm_roofline``.
 Scoped exactly like ``gemm.use_backend`` (:func:`use_recorder` /
 :func:`no_recorder` / :func:`set_recorder`); when inactive the hook in
 ``gemm/execute.py`` is a single module-level int check — zero
-allocation, below measurement noise (gated by benchmarks/table12_obs).
+allocation.
 
 Two dispatch regimes, recorded honestly rather than papered over:
 

@@ -10,8 +10,7 @@ in ``ui.perfetto.dev``.
 Activation mirrors ``gemm.use_backend``: a thread-local scope stack over
 a process default (:func:`use_tracer` / :func:`set_tracer` /
 :func:`no_tracer`), with a module-level activity counter so the
-inactive path is a single integer check — instrumented call sites cost
-nothing measurable when tracing is off (the table12_obs overhead gate).
+path with tracing off is a single integer check.
 
 Two sinks, one span API.  The default ``sink="memory"`` keeps events in
 ``Tracer.events`` on ``time.perf_counter``.  ``sink="profiler"``

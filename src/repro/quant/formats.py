@@ -59,13 +59,12 @@ GROUP_K = 128
 # enough whole GROUP_K K-groups to pay for its bitmap + group-offset
 # index and the per-group (vs per-block_k) kernel schedule.  Set well
 # above the analytic break-even ``gemm.policy.sparse_threshold()``
-# resolves from the t_pred byte model (~0.03), because the MEASURED
-# crossover is higher and shape-dependent: host dot kernels are not
-# monotone in K (table8's density sweep caught a 1024x1024 shape whose
-# compacted K' = 768 dot ran slower than the full K = 1024 dot, losing
-# 15% at zero-group fraction 0.25), so the arm engages only where the
-# sweep shows every paper shape winning.  measured_autotune can still
-# override the arm per shape.
+# resolves from the t_pred byte model (~0.03), because host dot kernels
+# are not monotone in K (a CPU density sweep found a 1024x1024 shape
+# whose compacted K' = 768 dot ran slower than the full K = 1024 dot).
+# That CPU reading is the threshold's only grounds; it awaits a chip
+# measurement (ROADMAP debt 4).  measured_autotune can still override
+# the arm per shape.
 SPARSE_DENSITY_THRESHOLD = 0.3
 
 # Four packed zero codes (code 0 stores as crumb 0b01): the byte value
@@ -174,8 +173,8 @@ class SparseTernaryPackedWeight(QuantizedPackedWeight):
 
     @property
     def group_sparsity(self) -> float:
-        """Zero-group fraction — the density-sweep knob (bench "density")
-        and the quantity ``SPARSE_DENSITY_THRESHOLD`` thresholds."""
+        """Zero-group fraction — the quantity
+        ``SPARSE_DENSITY_THRESHOLD`` thresholds."""
         if not self.k_groups:
             return 0.0
         return 1.0 - len(self.group_index) / self.k_groups

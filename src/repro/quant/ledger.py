@@ -72,7 +72,7 @@ class LedgerEntry:
         return self.density < 1.0
 
     def row(self) -> dict:
-        """Benchmark/report row (table8's ledger columns)."""
+        """Report row: the entry's shape, format and error columns."""
         return {"N": self.n, "K": self.k, "format": self.fmt,
                 "max_abs_err": self.max_abs, "max_rel_err": self.max_rel,
                 "tolerance": self.tol, "within_tol": self.within_tol,

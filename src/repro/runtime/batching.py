@@ -37,9 +37,8 @@ poisoned request's pages are freed (refcounts keep shared prefix pages
 safe), its slot is recycled, and the batch continues.  Because batched
 greedy decode is row-independent (each slot attends only to its own
 pages and masked rows write nothing), **survivors stay bit-identical
-to a fault-free run** — the gate ``tests/test_chaos.py`` and
-``benchmarks/chaos_serving.py`` enforce under injected faults
-(runtime/faults).
+to a fault-free run** — the gate ``tests/test_chaos.py`` enforces
+under injected faults (runtime/faults).
 
 Outputs are bit-identical to per-request greedy ``Engine.generate`` —
 the serving analogue of the paper's bit-exactness gate, enforced by

@@ -10,14 +10,12 @@ compute loop.  ``Engine`` is that discipline as a class:
   * ``prefill`` / ``decode`` — per-call compute only; no pack, no
     resharding collective in the step HLO (asserted by the dry-run).
   * per-call mode (``packed=False``) keeps raw weights — the
-    cblas/BNNSMatMul analogue the benchmarks compare against.
+    cblas/BNNSMatMul analogue of the paper's per-call baseline.
 
 Batched requests run through ``serve`` — real continuous batching
 (runtime/batching): a static-shape slot pool whose finished rows are
 refilled *mid-generation*, a paged KV cache so refills reuse freed
 blocks, and chunked prefill admission interleaved with decode steps.
-The legacy phase-locked loop survives as ``serve_chunked`` — the
-baseline the serving benchmark measures against.
 """
 from __future__ import annotations
 
@@ -38,13 +36,12 @@ from repro.parallel import sharding as Sh
 
 @dataclasses.dataclass
 class GenStats:
-    """Token accounting for ``generate``/``serve_chunked``.
+    """Token accounting for ``generate``.
 
     ``prefill_tokens`` counts prompt tokens *processed*; ``decode_tokens``
     counts tokens *emitted* — ``rows x max_new_tokens`` for ``generate``
     (the prefill-sampled first token included: generate emits
-    ``max_new_tokens`` per row, not ``max_new_tokens - 1``).  Both count
-    only live, non-pad tokens when accumulated by ``serve_chunked``.
+    ``max_new_tokens`` per row, not ``max_new_tokens - 1``).
     ``fused`` records whether the engine ran the horizontally fused
     QKV / gate-up GEMM path (None: raw-weight engine, fusion n/a);
     ``quant`` the engine's quantized weight format (None: fp32).
@@ -464,9 +461,8 @@ class Engine:
         # mid-prompt at an arbitrary offset — at gemm.bucket_m(rem), so
         # the tail widths the pool can emit are bucket_m(1..chunk): the
         # bucket ladder, then multiples of 128 up to a wider chunk.
-        # Driving each once keeps chunk_plan_misses at 0 with the prefix
-        # cache on (benchmarks/table10_prefix.py), and nothing compiles
-        # while serving.
+        # Driving each once keeps serving from resolving a plan with the
+        # prefix cache on, and nothing compiles while serving.
         widths = sorted({gemm_api.bucket_m(r) for r in range(1, chunk)}
                         - {chunk})
         for w in widths + [chunk]:
@@ -640,53 +636,3 @@ class Engine:
         if self.plan_store is not None:
             stats.plan_store = self.plan_store.info()
         return outs, stats
-
-    # -------------------------------------- legacy phase-locked baseline
-    def serve_chunked(self, requests: list[np.ndarray], *,
-                      batch_slots: int, prompt_len: int, max_new_tokens):
-        """The old "continuous batching lite": sequential static batches
-        where every slot waits for the chunk's slowest request.  Kept as
-        the baseline benchmarks/serving_mixed_lengths.py measures the
-        real scheduler against.
-
-        requests are padded/truncated to ``prompt_len``; max_new_tokens
-        may be per-request (each chunk then runs its max, and the extra
-        tokens of early finishers are wasted occupancy — exactly the
-        failure mode ``serve`` removes).  Stats count only live-slot,
-        non-pad tokens.
-        """
-        n = len(requests)
-        mn = ([int(max_new_tokens)] * n if np.isscalar(max_new_tokens)
-              else [int(m) for m in max_new_tokens])
-        stats = GenStats(fused=self.fused if self.packed else None,
-                         quant=self.quant if self.packed else None)
-        stats.quant_density, stats.quant_sparse_packs = \
-            self._quant_pack_stats()
-        results: dict[int, np.ndarray] = {}
-        queue = list(enumerate(requests))
-        while queue:
-            chunk = queue[:batch_slots]
-            queue = queue[batch_slots:]
-            ids = [i for i, _ in chunk]
-            step_new = max(mn[i] for i in ids)
-            toks = np.zeros((batch_slots, prompt_len), np.int32)
-            for r, (_, p) in enumerate(chunk):
-                p = np.asarray(p, np.int32)[:prompt_len]
-                toks[r, :len(p)] = p
-            gen, s = self.generate(jnp.asarray(toks), step_new)
-            stats.prefill_s += s.prefill_s
-            stats.decode_s += s.decode_s
-            # live-slot, non-pad accounting: dead rows (len(chunk) <
-            # batch_slots), prompt padding, and over-generation past a
-            # request's own max_new all count nothing
-            stats.prefill_tokens += sum(
-                min(len(np.asarray(requests[i])), prompt_len) for i in ids)
-            stats.decode_tokens += sum(mn[i] for i in ids)
-            gen = np.asarray(gen)
-            for r, i in enumerate(ids):
-                results[i] = gen[r, :mn[i]]
-        stats.plan_cache = gemm_api.plan_cache_info()
-        stats.vmem_clamped_plans = gemm_api.vmem_clamped_count()
-        if self.plan_store is not None:
-            stats.plan_store = self.plan_store.info()
-        return [results[i] for i in range(len(requests))], stats

@@ -15,8 +15,7 @@ dispatch retry + xla-backend fallback (the degradation ladder),
 deadline expiry and cooperative cancel, prefix-cache errors (cold-
 prefill degradation), plan-resolution faults, scheduler stalls,
 straggler ticks (watchdog), bounded-queue rejection, and SIGTERM
-graceful drain (subprocess).  benchmarks/chaos_serving.py runs the
-same parity gate over larger mixes; CI runs both.
+graceful drain (subprocess), alone and in combined mixes.
 """
 import os
 import signal
@@ -291,6 +290,50 @@ def test_prefix_cache_error_degrades_to_cold_prefill(stablelm):
     assert sum(stats.degraded.values()) >= 1
     for o, r in zip(outs, refs):
         np.testing.assert_array_equal(o, r)
+
+
+def _oom():
+    return KV.OutOfPagesError("injected pool exhaustion")
+
+
+# Several fault classes in one run: a transient prefill failure, a
+# decode fault whose whole ladder fails for rid 1, an allocator OOM, a
+# straggler tick and, with the prefix cache on, failing cache lookups.
+COMBINED_MIXES = {
+    "combined": (
+        (F.FaultSpec("prefill_dispatch", at=(0,)),
+         F.FaultSpec("decode_dispatch", at=(1, 2, 3), target_rid=1),
+         F.FaultSpec("alloc_oom", at=(9,), error=_oom),
+         F.FaultSpec("slow_tick", at=(6,), delay_s=0.05)),
+        {}),
+    "combined_prefix": (
+        (F.FaultSpec("prefill_dispatch", at=(0,)),
+         F.FaultSpec("decode_dispatch", at=(1, 2, 3), target_rid=1),
+         F.FaultSpec("prefix_cache", p=0.5)),
+        {"prefix_cache": True}),
+}
+
+
+@pytest.mark.parametrize("mix", sorted(COMBINED_MIXES))
+def test_combined_fault_mix_survivor_parity(stablelm, mix):
+    """Fault classes fired together in one seeded run: every survivor
+    is bit-identical to the fault-free run, every failure carries a
+    structured outcome, and the pool audits clean at teardown."""
+    cfg, eng = stablelm
+    specs, serve_kw = COMBINED_MIXES[mix]
+    reqs = _requests(cfg, LENS)
+    refs = _refs(eng, reqs, MNS)
+    plan = F.FaultPlan(*specs, seed=0)
+    with F.use_faults(plan):
+        outs, stats = eng.serve(reqs, batch_slots=3, max_new_tokens=MNS,
+                                prefill_chunk=CHUNK, page_size=PAGE,
+                                **serve_kw)
+    _assert_survivor_parity(outs, refs, stats)
+    assert set(plan.fired) == {spec.point for spec in specs}, \
+        f"a fault class of the mix never fired: {dict(plan.fired)}"
+    assert plan.fired["prefill_dispatch"] == 1
+    assert stats.dispatch_retries >= 1 and stats.failed >= 1
+    assert stats.completed + stats.failed == len(reqs)
 
 
 def test_plan_resolve_fault_releases_inflight_waiters():

@@ -145,6 +145,38 @@ def test_xla_splitk_run_bitwise_vs_slice_reference():
                                   "xla split-K execute")
 
 
+@pytest.mark.parametrize("fmt", ["fp32", "int8", "ternary"])
+def test_decode_lane_bitwise_vs_prefill_plans(fmt):
+    """The decode-arm plan (prepacked, skinny block_m, split_k=1 on xla)
+    computes the same bits as the prefill policy's plans for the shape:
+    the prepacked plan on the same pack, and the per-call plan on the
+    checkpoint-layout [N, K] weight (dequantized for quant formats)."""
+    rng = np.random.default_rng(9)
+    slots, n, k = 2, 256, 1024
+    w = jnp.asarray(rng.standard_normal((k, n)) * 0.02, jnp.float32)
+    x = jnp.asarray(rng.standard_normal((slots, k)), jnp.float32)
+    quant = None if fmt == "fp32" else fmt
+    bn, bk = gemm.pack_blocks(n, k, weight_format=fmt)
+    pw = packing.pack(w, block_n=bn, block_k=bk, quant=quant)
+    lane = gemm.plan_for_packed(slots, pw, backend="xla", decode=True)
+    assert lane.decode and lane.split_k == 1
+    prefill = gemm.plan_for_packed(slots, pw, backend="xla", decode=False)
+    assert not prefill.decode
+    percall = gemm.plan(slots, n, k, backend="xla", transposed=True,
+                        pack=gemm.PACK_PERCALL, block_n=pw.block_n,
+                        block_k=pw.block_k)
+    if quant:
+        q, s = F.quantize(w, fmt)
+        w = q.astype(jnp.float32) * F.expand_scales(s, k)
+    y_lane = np.asarray(gemm.execute(lane, x, pw))
+    bitexact.assert_bit_identical(
+        y_lane, np.asarray(gemm.execute(prefill, x, pw)),
+        f"{fmt}: decode lane vs prefill prepacked plan")
+    bitexact.assert_bit_identical(
+        y_lane, np.asarray(gemm.execute(percall, x, w.T)),
+        f"{fmt}: decode lane vs per-call plan")
+
+
 def test_execute_rejects_undivisible_split():
     w = jnp.zeros((512, 128), jnp.float32)
     pw = packing.pack(w, block_n=128, block_k=256)

@@ -165,17 +165,6 @@ def test_serve_parity_with_fusion_on(stablelm32):
     assert stats.fused is True
 
 
-def test_serve_chunked_reports_fused_flag(stablelm32):
-    """Review fix: the legacy chunked loop must report the engine's
-    fusion state like generate/serve do."""
-    cfg, params = stablelm32
-    eng = Engine(cfg, params, max_len=48, packed=True, fuse=True)
-    reqs = [np.arange(1, 6, dtype=np.int32)]
-    _, stats = eng.serve_chunked(reqs, batch_slots=1, prompt_len=8,
-                                 max_new_tokens=2)
-    assert stats.fused is True
-
-
 def test_near_budget_pack_survives_residual_epilogue():
     """Review fix: the VMEM footprint budgets bias/residual operand
     headroom unconditionally, so a pack that fits cannot be re-clamped

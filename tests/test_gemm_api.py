@@ -544,8 +544,8 @@ def test_vmem_warn_state_kept_while_sibling_cached():
 def test_plan_cache_clear_resets_counters():
     """Bugfix contract: ``plan_cache_clear`` resets entries AND both
     hit/miss counters AND the vmem warn/clamp observability — a cleared
-    cache reads (0, 0, maxsize, 0) exactly, so tests and benchmarks
-    can treat counter deltas as absolute."""
+    cache reads (0, 0, maxsize, 0) exactly, so callers can treat
+    counter deltas as absolute."""
     from repro.gemm import policy as pol
     G.plan(128, 2048, 2048)
     G.plan(128, 2048, 2048)

@@ -5,8 +5,8 @@ Four contracts:
 * **Non-perturbation** — serving with the full obs stack active
   (tracer + flight recorder + metrics) returns bit-identical tokens to
   the un-instrumented per-request ``generate`` reference, and the
-  inactive instrumentation adds no measurable overhead to the eager
-  GEMM path (the strict <=3% gate lives in benchmarks/table12_obs.py).
+  inactive instrumentation adds no gross overhead to the eager GEMM
+  path.
 * **Schema** — an exported trace is valid Chrome-trace JSON
   (``validate_chrome_trace`` finds nothing) and its synthesized
   ``gemm_dispatch`` spans carry plan key and lever, and no GFLOPS.
@@ -83,9 +83,9 @@ def test_serve_parity_with_full_obs_active(engine):
 
 def test_inactive_obs_overhead_bounded():
     """With no tracer/recorder/metrics active, the execute() hook is one
-    int check — eager dispatch time must not regress measurably.
-    Generous 1.5x bound with retry-on-noise (the tight 3% gate is
-    benchmarks/table12_obs.py, which uses many more reps)."""
+    int check — eager dispatch time must not regress grossly.  A
+    generous 1.5x bound with retry-on-noise: this is a CPU timing, so it
+    guards against a gross regression, never measures device speed."""
     import importlib
     exec_mod = importlib.import_module("repro.gemm.execute")
     assert obs_recorder._HOT == 0 and obs_spans._ANY == 0

@@ -123,7 +123,3 @@ def test_engine_slot_pool_serves_all(small):
     assert all(o.shape == (4,) for o in outs)
     assert stats.decode_tokens == 5 * 4
     assert stats.prefill_tokens == sum(len(r) for r in reqs)
-    # the legacy phase-locked loop still serves (the benchmark baseline)
-    outs2, stats2 = eng.serve_chunked(reqs, batch_slots=2, prompt_len=12,
-                                      max_new_tokens=4)
-    assert len(outs2) == 5 and stats2.decode_tokens == 5 * 4

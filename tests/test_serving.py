@@ -284,19 +284,6 @@ def test_genstats_generate_counts_emitted_tokens(stablelm):
     assert stats.prefill_tokens == 3 * 6
 
 
-def test_serve_chunked_counts_only_live_nonpad(stablelm):
-    """Dead slots (len(chunk) < batch_slots), prompt padding, and
-    over-generation past a request's own budget count nothing."""
-    cfg, eng = stablelm
-    lens, mns = [5, 9, 3], [4, 2, 6]       # 3 requests, 2 slots
-    reqs = _requests(cfg, lens, seed=5)
-    outs, stats = eng.serve_chunked(reqs, batch_slots=2, prompt_len=16,
-                                    max_new_tokens=mns)
-    assert stats.prefill_tokens == sum(lens)       # not 2 chunks * 2 * 16
-    assert stats.decode_tokens == sum(mns)         # not sum of chunk maxes
-    assert [len(o) for o in outs] == mns
-
-
 def test_serve_rejects_oversized_request(stablelm):
     cfg, eng = stablelm
     with pytest.raises(ValueError):
@@ -456,6 +443,32 @@ def test_prefix_cache_parity_under_eviction_pressure(stablelm):
             o, r, err_msg=f"request {i} diverged under eviction")
     assert stats.prefix.evicted_pages > 0, \
         "tight pool never pressured the cache — gate unexercised"
+
+
+def test_prefix_hits_after_warmup_resolve_no_plans(stablelm_packed):
+    """A prefix hit starts prefill mid-prompt, so the divergent remainder
+    dispatches at a chunk-tail width; ``warmup_plans`` covers every such
+    width, so serving with the cache on resolves no new plan, and its
+    tokens equal the cache-off serve's and per-request ``generate``'s."""
+    cfg, eng = stablelm_packed
+    chunk = 16
+    reqs = _shared_prefix_reqs(cfg, groups=2, per_group=3, prefix_len=18,
+                               tail_lens=[5, 2, 7], seed=15)
+    mns = [3] * len(reqs)
+    refs = _refs(eng, reqs, mns)
+    eng.warmup_plans(batch_slots=2, prefill_chunk=chunk, page_size=PAGE)
+    misses = gemm.plan_cache_info().misses
+    outs, stats = eng.serve(reqs, batch_slots=2, max_new_tokens=mns,
+                            prefill_chunk=chunk, page_size=PAGE,
+                            prefix_cache=True)
+    assert gemm.plan_cache_info().misses == misses, \
+        "a prefix-hit remainder resolved a plan warmup should have owned"
+    assert stats.prefix.hits >= 1
+    outs_off, _ = eng.serve(reqs, batch_slots=2, max_new_tokens=mns,
+                            prefill_chunk=chunk, page_size=PAGE)
+    for i, (o, o_off, r) in enumerate(zip(outs, outs_off, refs)):
+        np.testing.assert_array_equal(o, o_off, err_msg=f"request {i}")
+        np.testing.assert_array_equal(o, r, err_msg=f"request {i}")
 
 
 @pytest.mark.slow
