@@ -50,10 +50,14 @@ class Backend:
     the quant subsystem keep working for fp32 plans unchanged).
     ``run_grouped`` (optional) runs grouped plans —
     ``run_grouped(x, bank [G, K_pad, N_pad], group_sizes [G], *,
-    out_dtype, epilogue)`` over rows sorted by group.  ``in_place``: ``run``
-    takes a stacked pack ``[L, K_pad, N_pad]`` and ``layer=`` and reads
-    that layer's panels where they lie; execute() slices the layer out
-    for a backend without it."""
+    out_dtype, epilogue)`` over rows sorted by group, where any group may
+    be empty: a grouped plan that reads a stacked bank in place
+    (``GemmPlan.in_place``) hands it the whole stack folded to
+    ``[L * G, K_pad, N_pad]``, with the sizes zero outside the layer's
+    ``G``.  ``in_place``: ``run`` takes a stacked pack
+    ``[L, K_pad, N_pad]`` and ``layer=`` and reads that layer's panels
+    where they lie; execute() slices the layer out for a backend without
+    it."""
     name: str
     run: RunFn
     needs_blocks: bool = True    # False: shape-agnostic, skip block padding
@@ -134,6 +138,35 @@ def device_default(platform: str, *, weight_format: str = "fp32",
             and m is not None and m <= _kernel.DEFAULT_BLOCK_M):
         return "pallas"
     return "xla"
+
+
+def device_grouped_in_place(platform: str, *, dtype: str = "float32",
+                            stacked: bool = False) -> bool:
+    """Whether a grouped plan for a stacked bank ``[L, G, K_pad, N_pad]``
+    reads it in place on ``platform`` when no scope names a backend.
+
+    On a TPU a float32 stacked bank folds its layer axis into the group
+    axis: the grouped run gets the whole stack as ``[L * G, K_pad,
+    N_pad]`` (a bitcast) with group sizes that are zero outside the
+    layer's ``G``, and the TPU's grouped-matmul kernel visits only the
+    live groups' tiles.  XLA cannot fuse a layer's dynamic slice into
+    that kernel, so the sliced run copies the layer's bank first
+    (277 MB a DeepSeek-V2-Lite MoE layer, PERF.md §5).  Every other
+    platform keeps the slice: XLA:CPU's ``ragged_dot`` computes every
+    group, the empty ones too.  The choice is one device's, as
+    :func:`device_default`'s is: a step partitioned over a mesh names a
+    backend in its scope (``Engine(mesh=)``), and folding the layer
+    into the bank's expert axis, which the mesh partitions, would move
+    the bank between devices."""
+    return platform == "tpu" and dtype == "float32" and stacked
+
+
+def default_grouped_in_place(**plan_kw) -> bool:
+    """:func:`device_grouped_in_place` of ``jax.default_backend()`` —
+    False inside a ``use_backend`` scope, which pins the run it names."""
+    if getattr(_STATE, "stack", None):
+        return False
+    return device_grouped_in_place(jax.default_backend(), **plan_kw)
 
 
 def default_backend(**plan_kw) -> str:

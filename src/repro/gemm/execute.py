@@ -8,8 +8,11 @@ The weight operand may be:
     stacked pack (every array leading ``[L, ...]``, the layer scan's
     weights) runs with ``layer=``: a float32 plan on a backend that
     reads in place (``pallas``, ``interpret``) reads that layer's panels
-    where they lie; for every other run (``xla``, quantized packs,
-    grouped banks) execute indexes the layer out of each array first;
+    where they lie; a grouped plan that reads in place (``p.in_place``,
+    a float32 bank on a single TPU) hands the grouped run the whole
+    stack with the layer folded into the group axis; for every other
+    run (``xla``, quantized packs, grouped banks elsewhere) execute
+    indexes the layer out of each array first;
   * a ``QuantizedPackedWeight`` (repro.quant — quantized AND packed at
     load): the plan carries its ``weight_format`` and the backend's
     dequant-fused ``run_quant`` entry streams codes + scales;
@@ -112,7 +115,7 @@ def execute(p: GemmPlan, x: jax.Array, w, *, bias=None, residual=None,
         layer = None
     if p.groups:
         run = _execute_grouped
-        kw = {"group_sizes": group_sizes}
+        kw = {"group_sizes": group_sizes, "layer": layer}
     else:
         run = _execute_impl
         kw = {"bias": bias, "residual": residual, "layer": layer}
@@ -138,10 +141,12 @@ def execute(p: GemmPlan, x: jax.Array, w, *, bias=None, residual=None,
 
 def _reads_in_place(p: GemmPlan) -> bool:
     """Whether ``p``'s run reads one layer of a stacked pack where it
-    lies (``execute(..., layer=)``): a float32, ungrouped plan on a
-    backend registered ``in_place``."""
-    return (not p.groups and not p.quantized
-            and _backends.get_backend(p.backend).in_place)
+    lies (``execute(..., layer=)``): a grouped plan resolved
+    ``in_place``, or a float32, ungrouped plan on a backend registered
+    ``in_place``."""
+    if p.groups:
+        return p.in_place
+    return not p.quantized and _backends.get_backend(p.backend).in_place
 
 
 def _execute_impl(p: GemmPlan, x: jax.Array, w, *, bias=None,
@@ -285,7 +290,7 @@ def _execute_impl(p: GemmPlan, x: jax.Array, w, *, bias=None,
 
 
 def _execute_grouped(p: GemmPlan, x: jax.Array, w, *, group_sizes,
-                     out_dtype=None) -> jax.Array:
+                     out_dtype=None, layer=None) -> jax.Array:
     backend = _backends.get_backend(p.backend)
     _check(isinstance(w, packing.PackedWeight) and w.groups == p.groups,
            f"grouped plan needs a grouped pack of {p.groups} "
@@ -303,10 +308,21 @@ def _execute_grouped(p: GemmPlan, x: jax.Array, w, *, group_sizes,
     x2 = x.reshape(-1, p.k)
     m = x2.shape[0]
     _check(m == p.m, f"operand M={m} vs plan M={p.m}")
-    k_pad = w.data.shape[-2]
+    bank = w.data
+    if layer is not None:
+        # the layer folded into the group axis: a bitcast of the stack,
+        # and group sizes that are zero outside layer ``layer``'s groups
+        _check(bank.ndim == 4, f"layer= reads a stacked bank "
+                               f"({p.describe()})")
+        n_layers, g = bank.shape[:2]
+        bank = bank.reshape(n_layers * g, *bank.shape[2:])
+        group_sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((n_layers * g,), jnp.int32),
+            group_sizes.astype(jnp.int32), (layer * g,))
+    k_pad = bank.shape[-2]
     if k_pad != p.k:
         x2 = _pad_cols(x2, k_pad)
-    y = backend.run_grouped(x2, w.data, group_sizes, out_dtype=out_dtype,
+    y = backend.run_grouped(x2, bank, group_sizes, out_dtype=out_dtype,
                             epilogue=p.epilogue)
     return y[:, :p.n_out].reshape(*lead, p.n_out)
 

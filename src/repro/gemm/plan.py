@@ -81,6 +81,10 @@ class GemmPlan:
     to ``m`` rows sorted into groups whose sizes arrive per call
     (``execute(..., group_sizes=)``): the held experts of a MoE layer.
     Plan-keyed with the groups, K, N and the row bucket ``m``.
+    ``in_place`` (grouped plans only) marks a run that reads a stacked
+    bank ``[L, groups, K_pad, N_pad]`` in place: execute() folds the
+    layer into the group axis instead of slicing the layer's bank out
+    (``backends.device_grouped_in_place``: a single TPU's choice).
 
     Sparse-ternary field: ``density_bucket`` is ``-1`` on the dense arm
     and the pack's zero-group-fraction decile (0..9, see
@@ -113,6 +117,7 @@ class GemmPlan:
     decode: bool = False
     density_bucket: int = -1
     groups: int = 0
+    in_place: bool = False
 
     # ----------------------------------------------------------- geometry
     @property
@@ -188,6 +193,8 @@ class GemmPlan:
             epi += ", vmem_clamped"
         if self.groups:
             epi += f", groups={self.groups}"
+        if self.in_place:
+            epi += ", in_place"
         return (f"GemmPlan[{self.m}x{self.n}x{self.k} {self.dtype} "
                 f"-> {self.backend}, blocks=({self.block_m},{self.block_n},"
                 f"{self.block_k}), lever={self.lever}, pack={self.pack}"

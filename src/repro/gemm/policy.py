@@ -70,7 +70,7 @@ CacheInfo = collections.namedtuple(
 
 _cache: "collections.OrderedDict[tuple, GemmPlan]" = collections.OrderedDict()
 _cache_lock = threading.Lock()
-_grouped: dict = {}      # (groups, base plan) -> grouped-arm plan
+_grouped: dict = {}      # (groups, in_place, base plan) -> grouped plan
 _hits = 0
 _misses = 0
 # per-key in-flight resolutions (bugfix: two threads missing on one key
@@ -759,13 +759,19 @@ def plan_for_packed(m: int, pw: packing.PackedWeight, *,
     produces (codes are not an operand dtype).  ``decode=None`` reads
     the ambient :func:`decode_lane` scope (as :func:`plan` does).
     A ``SparseTernaryPackedWeight`` carries its ``density_bucket`` onto
-    the plan, selecting the sparse arm."""
+    the plan, selecting the sparse arm.  A grouped pack resolves the
+    grouped arm; for a stacked bank the plan also records whether the run
+    reads it in place (``GemmPlan.in_place``)."""
     fmt = getattr(pw, "fmt", "fp32")
     dtype = "float32" if fmt != "fp32" else pw.dtype
     if getattr(pw, "groups", 0):
         # the grouped arm: the bank's blocks and the row bucket resolve as
         # for one weight of the bank, and the plan carries the groups; it
-        # runs on a backend that has a grouped run
+        # runs on a backend that has a grouped run.  A stacked bank is
+        # read in place where the device default says so (and no scope
+        # or argument names the backend)
+        in_place = backend is None and _backends.default_grouped_in_place(
+            dtype=_dtype_name(dtype), stacked=pw.data.ndim == 4)
         backend = _backends.resolve_backend(backend)
         if _backends.get_backend(backend).run_grouped is None:
             backend = "xla"
@@ -774,12 +780,12 @@ def plan_for_packed(m: int, pw: packing.PackedWeight, *,
                     block_k=pw.block_k, pack=PACK_PREPACKED,
                     sharding=_packed_sharding(pw), epilogue=epilogue,
                     fused_n_splits=pw.n_splits, decode=decode)
-        key = (pw.groups, base)
+        key = (pw.groups, in_place, base)
         with _cache_lock:
             p = _grouped.get(key)
             if p is None:
-                p = _grouped[key] = dataclasses.replace(base,
-                                                        groups=pw.groups)
+                p = _grouped[key] = dataclasses.replace(
+                    base, groups=pw.groups, in_place=in_place)
         return p
     return plan(m, pw.n, pw.k, dtype=dtype, backend=backend,
                 num_cores=num_cores, block_n=pw.block_n,

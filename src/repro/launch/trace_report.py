@@ -18,8 +18,9 @@ GEMM's device time is in a profiler trace (``launch/serve
 Per step manifest it prints the share of the layer scan's projections
 whose weights were read in place from their stacked pack
 (``obs.stacked_in_place_share``): 100 % where every one ran a kernel
-that reads the layer's panels where they lie, 0 % where each layer's
-weight was sliced out first (the ``xla`` backend).
+that reads the layer's panels where they lie (or, for an expert bank,
+the whole stack with the layer folded into the group axis), 0 % where
+each layer's weight was sliced out first (the ``xla`` backend).
 
 Also prints a span census (event counts and total self time by span
 name) with ``--spans``, and writes the table as JSON with ``--json``.

@@ -235,7 +235,9 @@ def on_traced(p, m: int, *, stacked: bool = False,
     per-dispatch cost at run time.  ``stacked``: the weight came as a
     stacked pack and a layer index (the layer scan's projections);
     ``in_place``: the backend read that layer's panels where they lie,
-    with no per-layer slice or copy (``stacked_in_place_share``)."""
+    or a grouped run read the whole stack with the layer folded into
+    its group axis, with no per-layer slice or copy
+    (``stacked_in_place_share``)."""
     mkey = getattr(_STATE, "mkey", None)
     if mkey is not None:
         rec = _plan_record(p, m)

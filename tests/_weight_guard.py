@@ -3,7 +3,9 @@ from their compiled HLO.
 
 The layer scan hands each projection GEMM its whole stacked float32 pack
 ``[L, K_pad, N_pad]`` and the layer's index, and the panel kernel reads
-that layer's panels where they lie, rounding them to bf16 in VMEM.
+that layer's panels where they lie, rounding them to bf16 in VMEM.  An
+expert bank ``[L, G, K_pad, N_pad]`` goes down whole the same way, and
+XLA's grouped kernel reads it with the layer folded into its group axis.
 ``weight_copies`` lists every instruction that makes an array of a
 stack's shape or of one layer's ``[K_pad, N_pad]`` slice of it (leading
 unit dims aside): a
@@ -29,16 +31,18 @@ _KERNEL = re.compile(r'kernel_name = "(panel_gemm\w*)"|'
                      r"^\s*(?:ROOT\s+)?%(panel_gemm[\w.\-]*) = ")
 
 
-def stack_shapes(params) -> set:
+def stack_shapes(params, *, grouped: bool = False) -> set:
     """Shapes of every stacked float32 projection pack the layer scans
-    read in place, and of one layer of each."""
+    read in place, and of one layer of each; with ``grouped``, of every
+    stacked expert bank ``[L, G, K_pad, N_pad]`` and of one layer's
+    ``[G, K_pad, N_pad]`` instead."""
     import jax
     from repro.core.packing import PackedWeight
     out = set()
     for pw in jax.tree.leaves(params,
                               is_leaf=lambda a: isinstance(a, PackedWeight)):
-        if (type(pw) is PackedWeight and not pw.groups
-                and pw.data.ndim == 3):
+        if (type(pw) is PackedWeight and bool(pw.groups) == grouped
+                and pw.data.ndim == (4 if grouped else 3)):
             out.add(tuple(pw.data.shape))
             out.add(tuple(pw.data.shape[1:]))
     return out

@@ -4,6 +4,7 @@ plan-cache hit/miss/eviction behavior, bit-exactness of execute vs
 kernels/ref in interpret mode, the retired-shim contract, and the
 backend registry hook.  Deliberately hypothesis-free — this module must
 run on a bare container."""
+import dataclasses
 import time
 import warnings
 
@@ -280,23 +281,167 @@ for mesh in (None, make_host_mesh()):
 """
 
 
-def test_a_multi_device_engine_keeps_the_kernel_off_its_plans():
-    """The panel kernel is one device's custom call, which XLA cannot
-    partition: with the TPU's default in force, an engine on one device
-    runs its layer projections on ``pallas`` and one whose mesh spans
-    two devices pins ``xla`` (and its step runs; on the kernel the CPU
-    could not even lower it)."""
+def _on_two_devices(script: str) -> list[str]:
+    """Run ``script`` on a CPU host of two devices; its output lines."""
     import os
     import subprocess
     import sys
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env = dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=2")
-    r = subprocess.run([sys.executable, "-c", _MESH_SCRIPT], env=env,
+    r = subprocess.run([sys.executable, "-c", script], env=env,
                        capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, r.stderr[-3000:]
-    assert r.stdout.splitlines() == [
+    return r.stdout.splitlines()
+
+
+def test_a_multi_device_engine_keeps_the_kernel_off_its_plans():
+    """The panel kernel is one device's custom call, which XLA cannot
+    partition: with the TPU's default in force, an engine on one device
+    runs its layer projections on ``pallas`` and one whose mesh spans
+    two devices pins ``xla`` (and its step runs; on the kernel the CPU
+    could not even lower it)."""
+    assert _on_two_devices(_MESH_SCRIPT) == [
         "False None ['pallas']", "2 xla ['xla']", "ran"]
+
+
+# ------------------------------------- stacked expert banks read in place
+_BANK = (4, 4, 64, 48)           # layers, groups, K, N
+
+
+def _bank(glu: bool) -> packing.PackedWeight:
+    """A stacked grouped pack [L, G, K, N]: gate and up fused for the glu
+    epilogue, or one part (the down bank); unit-scale outputs."""
+    rng = np.random.default_rng(5)
+    return packing.pack_grouped(
+        [jnp.asarray((rng.standard_normal(_BANK) / 8).astype(np.float32))
+         for _ in range(2 if glu else 1)])
+
+
+def _single_tpu(monkeypatch):
+    """Resolve the grouped plans as a single TPU would, on this CPU host
+    (as tests/test_tpu_compile.py forces the device default)."""
+    chip = G.backends.device_grouped_in_place
+    monkeypatch.setattr(G.backends, "device_grouped_in_place",
+                        lambda _platform, **kw: chip("tpu", **kw))
+
+
+@pytest.mark.parametrize("platform,dtype,stacked,want", [
+    ("tpu", "float32", True, True),
+    ("tpu", "float32", False, False),      # one layer's bank [G, K, N]
+    ("tpu", "bfloat16", True, False),
+    ("cpu", "float32", True, False),
+])
+def test_device_grouped_in_place(platform, dtype, stacked, want):
+    """Only a single TPU folds, and only a float32 stacked bank."""
+    assert G.backends.device_grouped_in_place(
+        platform, dtype=dtype, stacked=stacked) is want
+
+
+def test_a_stacked_bank_keeps_the_slice_where_no_single_tpu_folds(
+        monkeypatch):
+    """The CPU's default slices the layer's bank out; with the TPU's
+    choice in force a scope (what a multi-device ``Engine(mesh=)``
+    opens) or an explicit backend still slices, and so does a bank with
+    no layer axis."""
+    pw = _bank(False)
+    assert not G.plan_for_packed(40, pw).in_place
+    _single_tpu(monkeypatch)
+    p = G.plan_for_packed(40, pw)
+    assert p.in_place and p.describe().endswith(", in_place]")
+    assert p is G.plan_for_packed(40, pw)
+    assert not G.plan_for_packed(40, pw, backend="xla").in_place
+    with G.use_backend("xla"):
+        assert not G.plan_for_packed(40, pw).in_place
+    one_layer = packing.PackedWeight(
+        data=pw.data[0], n=pw.n, k=pw.k, block_n=pw.block_n,
+        block_k=pw.block_k, groups=pw.groups)
+    assert not G.plan_for_packed(40, one_layer).in_place
+
+
+@pytest.mark.parametrize("sizes", [(10, 0, 20, 5), (0, 0, 0, 40),
+                                   (3, 4, 5, 6)],
+                         ids=["zeros", "one_group", "below_m"])
+@pytest.mark.parametrize("layer", [0, 1, 3])
+@pytest.mark.parametrize("glu", [True, False], ids=["gate_up", "down"])
+def test_a_folded_bank_computes_what_the_slice_computes(monkeypatch, glu,
+                                                        layer, sizes):
+    """On a single TPU the layer folds into the group axis; the live rows
+    equal the sliced run's at the CPU's deviation (XLA:CPU's ragged dot
+    sums in another order over the folded bank: 1.5e-7 at most at unit
+    scale), the rows past the sizes' sum come back exactly zero, and the
+    output keeps its shape."""
+    _single_tpu(monkeypatch)
+    pw = _bank(glu)
+    m = 40
+    fold = G.plan_for_packed(
+        m, pw, epilogue=G.EpilogueSpec(glu="silu") if glu else None)
+    sliced = dataclasses.replace(fold, in_place=False)
+    x = _rand((m, pw.k))
+    gs = jnp.asarray(sizes, jnp.int32)
+    li = jnp.int32(layer)
+    want = G.execute(sliced, x, pw, group_sizes=gs, layer=li)
+    got = jax.jit(lambda x, w, gs, li: G.execute(
+        fold, x, w, group_sizes=gs, layer=li))(x, pw, gs, li)
+    live = sum(sizes)
+    assert fold.in_place and got.shape == want.shape == (m, _BANK[-1])
+    np.testing.assert_allclose(got[:live], want[:live], rtol=1e-6,
+                               atol=1e-6)
+    assert not np.any(np.asarray(got[live:]))
+
+
+@pytest.mark.parametrize("tpu", [False, True], ids=["cpu", "single_tpu"])
+def test_the_recorder_marks_a_folded_bank_in_place(monkeypatch, tpu):
+    """A traced grouped dispatch of a stacked bank is ``stacked``, and
+    ``in_place`` exactly where the layer folds."""
+    from repro import obs
+    if tpu:
+        _single_tpu(monkeypatch)
+    pw = _bank(False)
+
+    def step(x, w, gs, li):
+        with obs.manifest_scope("grouped_fold"):
+            p = G.plan_for_packed(x.shape[0], w)
+            return G.execute(p, x, w, group_sizes=gs, layer=li)
+    jax.eval_shape(step, _rand((40, pw.k)), pw,
+                   jnp.asarray((10, 0, 20, 5), jnp.int32), jnp.int32(2))
+    (rec,) = obs.manifests().pop("grouped_fold")
+    assert rec["stacked"] and rec["in_place"] is tpu
+    assert obs.stacked_in_place_share([rec]) == float(tpu)
+
+
+_MESH_MOE_SCRIPT = """
+import jax, jax.numpy as jnp
+from repro import obs
+from repro.gemm import backends
+from repro.launch.mesh import make_host_mesh
+from repro.models import model_zoo
+from repro.runtime.serve_loop import Engine
+fold = backends.device_grouped_in_place
+backends.device_grouped_in_place = lambda _platform, **kw: fold("tpu", **kw)
+cfg = model_zoo.reduced_config(model_zoo.get_config("deepseek-v2-lite-ep8"))
+params = model_zoo.build(cfg)
+tokens = jnp.zeros((2, 8), jnp.int32)
+for mesh in (None, make_host_mesh()):
+    eng = Engine(cfg, params, mesh=mesh, max_len=32, packed=True)
+    jax.eval_shape(eng._prefill, eng.params, tokens)
+    recs = obs.manifests().pop("prefill_m16")
+    print(mesh is not None and mesh.size,
+          sorted({r["in_place"] for r in recs if "groups=" in r["plan"]}))
+    if mesh is not None:
+        eng.prefill(tokens)[0].block_until_ready()
+        print("ran")
+"""
+
+
+def test_a_multi_device_engine_slices_the_expert_banks():
+    """The mesh partitions the banks' expert axis, so folding the layer
+    into it would move the bank between devices: with the single TPU's
+    choice in force an engine on one device folds every grouped GEMM,
+    and one whose mesh spans two devices slices the layer's bank out
+    (and its step runs)."""
+    assert _on_two_devices(_MESH_MOE_SCRIPT) == [
+        "False [True]", "2 [False]", "ran"]
 
 
 # ------------------------------------------- epilogue / fusion bitexact

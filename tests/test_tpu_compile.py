@@ -314,6 +314,9 @@ def cell_steps(one_chip):
                 chip = backends.device_default
                 mp.setattr(backends, "device_default",
                            lambda _platform, **kw: chip("tpu", **kw))
+                fold = backends.device_grouped_in_place
+                mp.setattr(backends, "device_grouped_in_place",
+                           lambda _platform, **kw: fold("tpu", **kw))
                 texts = _kv_guard.step_texts(
                     eng, slots=slots, chunk=chunk, page_size=CELL_PAGE,
                     num_pages=num_pages, sharding=one_chip)
@@ -358,6 +361,19 @@ def test_latent_steps_run_grouped_expert_gemms(cell_steps):
         assert "moe_route" in text and "mla_" in text, name
     assert "mla_expand" in texts["prefill"]
     assert "mla_absorb" in texts["decode"]
+
+
+def test_latent_steps_copy_no_expert_bank(cell_steps):
+    """The long-prompt cell's compiled prefill-chunk, decode and
+    megastep steps slice or copy no stacked expert bank (no layer's
+    ``[8, K_pad, N_pad]`` bank copied out of the ``[13, 8, K_pad,
+    N_pad]`` stack): the grouped kernel reads the stack folded to
+    ``[104, K_pad, N_pad]``, a bitcast of the parameter."""
+    eng, texts, _ = cell_steps("deepseek-v2-lite-14l")
+    shapes = _weight_guard.stack_shapes(eng.params, grouped=True)
+    assert {s[-3:] for s in shapes} == {(8, 2048, 2816), (8, 1408, 2048)}
+    for name, text in texts.items():
+        assert _weight_guard.weight_copies(text, shapes) == [], name
 
 
 @pytest.mark.parametrize("cell", sorted(CELLS))
